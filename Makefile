@@ -79,9 +79,8 @@ rebalance-smoke:
 		$(PY) -m mpi_grid_redistribute_tpu.bench.config4_drift --rebalance
 
 # resident chunked-stepping gate (ISSUE 10): eager(chunk=1) vs chunked
-# (chunk=16/64) ServiceDriver pps on the 8-vrank CPU mesh (4096 rows,
-# one device — the measurement re-executes itself in a subprocess with
-# any device forcing stripped), asserting the chunk=64 speedup floor
+# (chunk=16/64) ServiceDriver pps on the 8-vrank mesh (4096 rows, one
+# device, measured in-process), asserting the chunk=64 speedup floor
 # (SERVICE_SPEEDUP_MIN, default 1.5x) and chunk-vs-eager final
 # particle-set bit-identity. service_pps is regress-guarded against
 # committed captures on top.

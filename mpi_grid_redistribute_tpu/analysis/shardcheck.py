@@ -31,7 +31,7 @@ is monotone and scan/while carries reach a fixpoint. Transfer rules:
 * ``cond``: branch-output join plus the predicate's vary-set;
   ``scan``/``while``: union fixpoint over the carry (while also joins
   the cond-jaxpr predicate — a rank-varying trip count makes every
-  carry rank-varying); ``pjit``/call-like prims map through the body;
+  carry rank-varying); ``jit``/call-like prims map through the body;
   unknown prims with sub-jaxprs conservatively poison their outputs to
   every in-scope axis;
 * everything elementwise/default: union of the inputs.
@@ -99,10 +99,12 @@ S_RULE_IDS = ("S001", "S002", "S003", "S004")
 # collective vocabulary (shared with rules_jaxpr, which re-exports it)
 # ---------------------------------------------------------------------
 
-# Cross-device communication primitives (jax 0.4.x jaxpr names).
+# Cross-device communication primitives (jaxpr names; jax 0.9 writes
+# ``psum``/``pmean`` inside shard_map as ``psum_invariant``).
 COLLECTIVE_PRIMS = frozenset(
     {
         "psum",
+        "psum_invariant",
         "pmax",
         "pmin",
         "pmean",
@@ -118,7 +120,9 @@ COLLECTIVE_PRIMS = frozenset(
 )
 
 # Full reductions: outputs identical on every rank of the reduced axes.
-REDUCTION_PRIMS = frozenset({"psum", "pmax", "pmin", "pmean"})
+REDUCTION_PRIMS = frozenset(
+    {"psum", "psum_invariant", "pmax", "pmin", "pmean"}
+)
 
 # Collectives whose OUTPUT is identical on every rank of the reduced
 # axes — the ancestry that makes a cond predicate "globally agreed".
@@ -132,10 +136,10 @@ VARYING_PRIMS = frozenset(
      "reduce_scatter"}
 )
 
-# Call-like HOFs whose body invars map 1:1 onto eqn invars.
+# Call-like HOFs whose body invars map 1:1 onto eqn invars (jax 0.9
+# names: a nested ``jax.jit`` is ``jit``, ``jax.checkpoint`` is ``remat2``).
 CALL_PRIMS = frozenset(
-    {"pjit", "closed_call", "core_call", "xla_call", "remat", "remat2",
-     "checkpoint", "custom_jvp_call", "custom_vjp_call", "custom_vmap_call"}
+    {"jit", "closed_call", "remat2", "custom_jvp_call", "custom_vjp_call"}
 )
 
 
@@ -454,17 +458,15 @@ class _VaryInterp:
     def _shard_map(self, eqn, ins: List[VarySet]) -> List[VarySet]:
         body = jaxpr_of(eqn.params["jaxpr"])
         mesh = eqn.params["mesh"]
-        in_names = eqn.params["in_names"]
-        out_names = eqn.params["out_names"]
+        in_specs = eqn.params["in_specs"]
+        out_specs = eqn.params["out_specs"]
         if len(body.invars) != len(eqn.invars):
             return self._opaque(eqn)
         axis_names = tuple(str(a) for a in mesh.axis_names)
         sizes = {str(k): int(v) for k, v in dict(mesh.shape).items()}
         body_in = []
-        for spec, s in zip(in_names, ins):
-            partitioned = frozenset(
-                str(a) for axs in spec.values() for a in axs
-            )
+        for spec, s in zip(in_specs, ins):
+            partitioned = _spec_axes(spec)
             # a partitioned dim makes the shard rank-dependent; an empty
             # spec (P()) is a replicated broadcast — the operand's own
             # taint rides along either way
@@ -475,12 +477,10 @@ class _VaryInterp:
         body_out = self._jaxpr(body, body_in)
         self._scope, self._axis_sizes = saved
         outs: List[VarySet] = []
-        for i, (spec, s) in enumerate(zip(out_names, body_out)):
-            partitioned = frozenset(
-                str(a) for axs in spec.values() for a in axs
-            )
+        for i, (spec, s) in enumerate(zip(out_specs, body_out)):
+            partitioned = _spec_axes(spec)
             resid = s - partitioned
-            if not spec and s:
+            if not partitioned and s:
                 # declared fully replicated (P()) but provably varying:
                 # S001's program point. Reported here, so the residual
                 # taint does not double-fire downstream rules.
@@ -490,6 +490,17 @@ class _VaryInterp:
                 resid = frozenset()
             outs.append(resid)
         return outs
+
+
+def _spec_axes(spec) -> VarySet:
+    """Mesh axes a ``PartitionSpec`` partitions over; each entry is
+    ``None``, one axis name or a tuple of them."""
+    out = set()
+    for entry in spec:
+        if entry is None:
+            continue
+        out.update(entry if isinstance(entry, tuple) else (entry,))
+    return frozenset(str(a) for a in out)
 
 
 def analyze(closed) -> ShardReport:

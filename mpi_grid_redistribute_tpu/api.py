@@ -691,6 +691,7 @@ class GridRedistribute:
         self.capacity_factor = float(capacity_factor)
         self.out_capacity = out_capacity
         self._mesh = mesh
+        self._vranks_noted = False
         if backend == "jax" and mesh is not None:
             mesh_lib.validate_mesh_for_grid(mesh, self.grid)
 
@@ -719,7 +720,18 @@ class GridRedistribute:
         node)."""
         if self.backend != "jax" or self._mesh is not None:
             return False
-        return len(jax.devices()) < self.nranks
+        devs = jax.devices()
+        if len(devs) >= self.nranks:
+            return False
+        if len(devs) > 1 and not self._vranks_noted:
+            self._vranks_noted = True
+            warnings.warn(
+                f"GridRedistribute: {self.nranks} ranks run as virtual "
+                f"ranks on 1 of {len(devs)} visible devices ({devs[0]}); "
+                "pass mesh= to place ranks on more of them",
+                stacklevel=3,
+            )
+        return True
 
     def _capacities(self, n_local: int) -> Tuple[int, int]:
         cap = self.capacity

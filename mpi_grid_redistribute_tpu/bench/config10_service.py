@@ -11,7 +11,7 @@ This capture measures both legs through the SAME public driver — only
 ``cfg.chunk`` differs — so the ratio is the price of per-step host
 syncs, nothing else.
 
-Shape: the 8-vrank CPU mesh — all eight ranks resident on ONE CPU
+Shape: the 8-vrank mesh — all eight ranks resident on ONE
 device (``GridRedistribute``'s vrank path, no device forcing), 4096
 rows on the host (``DriverConfig.n_local = 512`` per vrank), slab
 decomposition, neighbor engine. This is deliberately the service
@@ -20,11 +20,11 @@ engine compute scales with rows, the eager loop's sync tax does not.
 On fatter per-rank populations the step goes compute-bound and the
 ratio tends to 1 — that regime is config 8's job, not this one's.
 
-The measurement runs in a **subprocess** with any
-``xla_force_host_platform_device_count`` forcing stripped from
-``XLA_FLAGS``: the repo's bench/test harnesses force 8 CPU devices,
-which would silently swap the vrank path for the shard_map mesh path
-and time a different program.
+The measurement runs in the calling process, on the devices it sees,
+and never in a child: a child could not reach a chip its parent holds.
+With one device the eight ranks run as vranks on it; where more devices
+are visible the driver takes the shard_map mesh path, a different
+program, and the ``--gate`` check refuses the capture.
 
 Headline: ``service_pps`` (chunk=64 service throughput), guarded by
 ``bench-check`` like any other capture (auto-armed: history captures
@@ -66,16 +66,12 @@ must be a multiple of every measured chunk), ``BENCH_SERVICE_CHUNKS``.
 
 from __future__ import annotations
 
-import json
 import math
 import os
-import subprocess
 import sys
 import time
 
 from mpi_grid_redistribute_tpu.bench import common
-
-_CHILD_FLAG = "--child"
 
 
 def _knobs() -> dict:
@@ -242,10 +238,9 @@ def _bit_identity(kn) -> bool:
     return all(s == states[0] for s in states[1:])
 
 
-def _child_main() -> int:
-    """The measurement body — runs on whatever devices THIS process
-    sees (the parent launched us with the device forcing stripped, so:
-    one CPU device, eight vranks)."""
+def run() -> dict:
+    """One service capture, measured in this process on the devices it
+    sees (one device: the eight ranks run as vranks on it)."""
     import jax
 
     kn = _knobs()
@@ -298,40 +293,6 @@ def _child_main() -> int:
         "probe_events": probe["events"],
         "bit_identical": _bit_identity(kn),
     }
-    print(json.dumps(out), flush=True)
-    return 0
-
-
-def run() -> dict:
-    """One service capture, measured in a clean-topology subprocess."""
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    flags = [
-        f
-        for f in env.get("XLA_FLAGS", "").split()
-        if "xla_force_host_platform_device_count" not in f
-    ]
-    if flags:
-        env["XLA_FLAGS"] = " ".join(flags)
-    else:
-        env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [
-            sys.executable,
-            "-m",
-            "mpi_grid_redistribute_tpu.bench.config10_service",
-            _CHILD_FLAG,
-        ],
-        env=env,
-        capture_output=True,
-        text=True,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"config10 child failed (exit {proc.returncode}):\n"
-            + proc.stderr[-2000:]
-        )
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
     common.log(
         f"config10: service {out['value']:.3e} pps at chunk="
         f"{out['chunk']} ({out['ms_per_step']:.2f} ms/step) vs eager "
@@ -382,18 +343,13 @@ def _service_gate(
         )
     if out["n_devices"] != 1:
         failures.append(
-            f"child saw {out['n_devices']} devices — the vrank path was "
-            "not measured (device forcing leaked into the subprocess)"
+            f"{out['n_devices']} devices visible — the vrank path was "
+            "not measured (run with one device)"
         )
     return failures
 
 
 def main(argv=None) -> int:
-    if argv is None:
-        argv = sys.argv[1:]
-    if _CHILD_FLAG in argv:
-        return _child_main()
-
     import argparse
 
     p = argparse.ArgumentParser(prog="config10_service")

@@ -55,7 +55,7 @@ def run(n_total: int = None, reps: int = 3) -> dict:
             max(1 << 13, int(scale * n)) for n in (1 << 18, 1 << 19, 1 << 20)
         ]
         outs = [_run_one(n, reps) for n in sizes]
-        best = max(outs, key=lambda o: o["bw_util"])
+        best = max(outs, key=lambda o: o["exchange_gb_per_sec"])
         best = dict(best)
         best["sweep"] = [
             {
@@ -155,9 +155,12 @@ def _run_one(n_total: int, reps: int = 3) -> dict:
         n_chips=1,
     )
     moved_frac = report["stats"]["moved_fraction"]
+    bw = report["bw_util"]  # "not measured" off the chip
+    if not isinstance(bw, str):
+        bw = round(bw, 6)
     out = {
         "metric": "config7_stress_bw_util",
-        "value": round(report["bw_util"], 6),
+        "value": bw,
         "unit": "fraction_of_hbm_peak",
         "engine": "planar",
         "rows": vR * n_live,
@@ -173,15 +176,15 @@ def _run_one(n_total: int, reps: int = 3) -> dict:
         "moved_bytes_per_step": report["moved_bytes_per_step"],
         "exchange_bytes_per_sec": report["exchange_bytes_per_sec"],
         "exchange_gb_per_sec": round(report["exchange_gb_per_sec"], 3),
-        "bw_util": round(report["bw_util"], 6),
+        "bw_util": bw,
         "exchange_domain": report["exchange_domain"],
     }
     common.log(
         f"config7: full reshuffle {moved_frac*100:.1f}% rows/step, "
         f"{detail['min']*1e3:.2f} ms/step "
         f"(spread {detail['spread']*100:.1f}%), "
-        f"{report['exchange_gb_per_sec']:.2f} GB/s = "
-        f"{report['bw_util']*100:.2f}% of HBM roof"
+        f"{report['exchange_gb_per_sec']:.2f} GB/s, HBM roof share "
+        f"{bw if isinstance(bw, str) else f'{bw * 100:.2f}%'}"
     )
     return out
 

@@ -56,17 +56,13 @@ import numpy as np
 from mpi_grid_redistribute_tpu.bench import common
 
 
-def _grid_and_backend():
-    """The canonical grid on enough devices, else a numpy-backend soak —
-    the service loop is the thing under test, not the mesh."""
-    import jax
-
-    grid = tuple(
+def _grid():
+    """The canonical grid. The soak always drives the jax backend: with
+    fewer devices than ranks ``ServiceDriver`` runs them as vranks on
+    one device."""
+    return tuple(
         int(x) for x in os.environ.get("BENCH_GRID", "2,2,2").split(",")
     )
-    if len(jax.devices()) >= math.prod(grid):
-        return grid, "jax"
-    return grid, "numpy"
 
 
 def _make_driver(grid, backend, n_local, steps, snapshot_every, snap_dir,
@@ -105,7 +101,7 @@ def run(n_local: int = None, reps: int = None) -> dict:
     )
     from mpi_grid_redistribute_tpu.telemetry import StepRecorder, regress
 
-    grid, backend = _grid_and_backend()
+    grid, backend = _grid(), "jax"
     R = math.prod(grid)
     if n_local is None:
         scale = float(os.environ.get("BENCH_SCALE", 1.0))

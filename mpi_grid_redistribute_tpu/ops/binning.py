@@ -661,19 +661,21 @@ def bounds_dense(keys_sorted, n_edges: int, stride: int = 1,
     return ds[:n_edges]
 
 
-def match_vma(x, ref):
-    """Promote ``x`` to ``ref``'s varying mesh axes (no-op outside
-    shard_map or when already aligned).
+def match_vma(tree, ref):
+    """Promote every leaf of ``tree`` to ``ref``'s varying mesh axes
+    (no-op outside shard_map or when already aligned).
 
     Pallas kernels under shard_map want every input carrying the same
     varying-axes set; a mismatched scalar-prep array can make tracing
-    insert ``pvary`` inside the kernel jaxpr, which Mosaic rejects."""
-    from mpi_grid_redistribute_tpu import compat
+    insert ``pvary`` inside the kernel jaxpr, which Mosaic rejects. The
+    branches of a ``lax.cond`` must agree on it too."""
+    axes = jax.typeof(ref).vma
 
-    want = tuple(
-        a for a in compat.typeof(ref).vma if a not in compat.typeof(x).vma
-    )
-    return compat.pvary(x, want) if want else x
+    def one(x):
+        want = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        return jax.lax.pcast(x, want, to="varying") if want else x
+
+    return jax.tree.map(one, tree)
 
 
 def dest_histogram(dest, nranks: int, valid=None):

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from mpi_grid_redistribute_tpu.compat import shard_map
+from jax import shard_map
 
 from mpi_grid_redistribute_tpu.domain import Domain, ProcessGrid
 from mpi_grid_redistribute_tpu.ops import binning

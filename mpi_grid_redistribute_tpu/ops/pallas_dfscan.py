@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpi_grid_redistribute_tpu import compat
 
 R_BLOCK = 256  # tile-rows per grid block ([256, 256] f32 = 256 KB/buf)
 
@@ -91,10 +90,10 @@ def tile_df_cumsum_rows(x, interpret=False):
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            compat.shape_dtype_struct((r_pad, tile), x.dtype,
-                                      vma=compat.typeof(x).vma),
-            compat.shape_dtype_struct((r_pad, tile), x.dtype,
-                                      vma=compat.typeof(x).vma),
+            jax.ShapeDtypeStruct((r_pad, tile), x.dtype,
+                                      vma=jax.typeof(x).vma),
+            jax.ShapeDtypeStruct((r_pad, tile), x.dtype,
+                                      vma=jax.typeof(x).vma),
         ],
         interpret=interpret,
     )(xp)

@@ -42,7 +42,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpi_grid_redistribute_tpu import compat
 
 from mpi_grid_redistribute_tpu.domain import Domain, ProcessGrid
 from mpi_grid_redistribute_tpu.ops import binning
@@ -135,7 +134,7 @@ def _driftbin_call(flat, *, V, n, w, K, D, dt, consts, periodic, shape,
         shape=shape, strides=strides, R_total=R_total,
     )
     nblk = n // w
-    vma = compat.typeof(flat).vma
+    vma = jax.typeof(flat).vma
     return pl.pallas_call(
         kernel,
         grid=(nblk, V),
@@ -154,8 +153,8 @@ def _driftbin_call(flat, *, V, n, w, K, D, dt, consts, periodic, shape,
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            compat.shape_dtype_struct((K, V * n), flat.dtype, vma=vma),
-            compat.shape_dtype_struct((V, n), jnp.int32, vma=vma),
+            jax.ShapeDtypeStruct((K, V * n), flat.dtype, vma=vma),
+            jax.ShapeDtypeStruct((V, n), jnp.int32, vma=vma),
         ],
         # the pre-drift state is dead once streamed: update in place
         input_output_aliases={0: 0},

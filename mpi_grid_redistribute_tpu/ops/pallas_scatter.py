@@ -50,7 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpi_grid_redistribute_tpu import compat
 
 from mpi_grid_redistribute_tpu.ops import binning
 
@@ -131,7 +130,7 @@ def _scatter_sorted(flat, starts, rows_t, tgt_t, interpret=False):
             pltpu.VMEM((RMAX, 8), jnp.int32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=None if interpret else compat.tpu_compiler_params(
+        compiler_params=None if interpret else pltpu.CompilerParams(
             # (BLOCK, 7) f32 blocks lane-pad to (BLOCK, 128): 2 buffers
             # x (in + out) exceed the default 16 MB scoped-VMEM budget at
             # useful block sizes; raise the cap (v5e VMEM is far larger)

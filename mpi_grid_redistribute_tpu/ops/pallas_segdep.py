@@ -54,7 +54,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from mpi_grid_redistribute_tpu import compat
 
 from mpi_grid_redistribute_tpu.ops import binning
 
@@ -217,8 +216,8 @@ def _segsum_tpu(keys, rel, mass, n_cells, vblock, d, interpret=False):
         grid=(nblocks,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=compat.shape_dtype_struct(
-            (nch, s_pad), jnp.float32, vma=compat.typeof(rel_p).vma
+        out_shape=jax.ShapeDtypeStruct(
+            (nch, s_pad), jnp.float32, vma=jax.typeof(rel_p).vma
         ),
         scratch_shapes=[
             pltpu.VMEM((nch, CH), jnp.float32),

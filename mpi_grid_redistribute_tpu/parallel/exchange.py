@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from mpi_grid_redistribute_tpu.compat import shard_map
+from jax import shard_map
 
 from mpi_grid_redistribute_tpu.domain import Domain, ProcessGrid
 from mpi_grid_redistribute_tpu.ops import binning, pack

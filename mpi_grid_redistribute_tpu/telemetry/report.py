@@ -64,6 +64,7 @@ def exchange_report(
     engine_wire_cols: Optional[int] = None,
     dense_wire_cols: Optional[int] = None,
     wire_shards: Optional[int] = None,
+    device_kind: Optional[str] = None,
 ) -> Dict[str, object]:
     """Merged metrics dict for one exchange workload.
 
@@ -122,7 +123,14 @@ def exchange_report(
         bps = wire_bytes / step_seconds
         out["exchange_bytes_per_sec"] = bps
         out["exchange_gb_per_sec"] = bps / 1e9
-        out["bw_util"] = profiling.exchange_bw_util(bps, domain, n_chips)
+        # a utilization exists only for a rate timed on a known chip:
+        # "not measured" on any other device, unless the caller names
+        # the chip kind the seconds were measured on
+        out["bw_util"] = (
+            profiling.measured_bw_util(bps, domain, n_chips)
+            if device_kind is None
+            else profiling.exchange_bw_util(bps, domain, n_chips, device_kind)
+        )
     # per-link refinement (telemetry.flow): mean per-step flow matrix ->
     # hottest pairs with per-link moved bytes and bw_util against ONE
     # link's roof. Aggregate-only stats (a hand-built MigrateStats with
@@ -135,6 +143,9 @@ def exchange_report(
         out["links"] = flow_lib.link_report(
             mean_matrix, row_bytes, step_seconds=step_seconds, domain=domain
         )
+        if isinstance(out["bw_util"], str):
+            for link in out["links"]["links"]:
+                link["bw_util"] = out["bw_util"]
     # sparse fast-path hit rate (ISSUE 4): present whenever the stats
     # came from a sparse-capable loop (fast_path leaf is a [S, R] 1/0
     # guard trace; dense-only loops carry None and omit the field).
@@ -187,11 +198,12 @@ def format_report(report: Dict[str, object]) -> str:
     """One human line from an :func:`exchange_report` dict."""
     bw = report.get("bw_util")
     gbs = report.get("exchange_gb_per_sec")
-    rate = (
-        "rate: pass step_seconds"
-        if gbs is None
-        else f"{gbs:.2f} GB/s ({bw*100:.2f}% of {report['exchange_domain']})"
-    )
+    if gbs is None:
+        rate = "rate: pass step_seconds"
+    elif isinstance(bw, str):
+        rate = f"{gbs:.2f} GB/s (utilization {bw})"
+    else:
+        rate = f"{gbs:.2f} GB/s ({bw*100:.2f}% of {report['exchange_domain']})"
     ev = report.get("events") or {}
     grows = ev.get("capacity_grow", 0) + ev.get("halo_grow", 0)
     return (

@@ -8,7 +8,10 @@ oracle and host-side tooling. pybind11 is not in this image, so the C ABI
 
 Building the .so is opt-in: call :func:`build` explicitly (bench drivers
 and tests do), or set ``MPI_GRID_NATIVE_BUILD=1`` to allow a g++ build on
-first use. Every entry point has a NumPy fallback so the package works
+first use. Only a library built from the committed files is loaded: a
+stamp next to the .so holds the hash of the source and ``build.sh`` (its
+flags), and a .so whose stamp does not match is rebuilt, or ignored where
+no build is allowed. Every entry point has a NumPy fallback so the package works
 without a toolchain; the first silent fallback on a native-requested call
 is logged so users know which path produced their numbers (``available()``
 reports which path is live).
@@ -17,6 +20,7 @@ reports which path is live).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -26,6 +30,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 _LIB_NAME = "libgrid_redistribute_native.so"
+_SOURCES = ("grid_redistribute_native.cpp", "build.sh")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
@@ -38,6 +43,40 @@ def _native_dir() -> str:
         os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
         "native",
     )
+
+
+def _source_hash() -> str:
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_native_dir(), name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read())
+    return h.hexdigest()
+
+
+def _stamp_path() -> str:
+    return os.path.join(_native_dir(), _LIB_NAME + ".sha256")
+
+
+def _built_from_sources() -> bool:
+    """True when the .so on disk was built from the committed files."""
+    try:
+        with open(_stamp_path()) as f:
+            stamp = f.read().strip()
+    except OSError:
+        return False
+    return os.path.exists(os.path.join(_native_dir(), _LIB_NAME)) and (
+        stamp == _source_hash()
+    )
+
+
+def _run_build(timeout: float) -> None:
+    """native/build.sh, then the stamp; raises on failure."""
+    subprocess.run(
+        [os.path.join(_native_dir(), "build.sh")], check=True,
+        capture_output=True, timeout=timeout,
+    )
+    with open(_stamp_path(), "w") as f:
+        f.write(_source_hash() + "\n")
 
 
 def build(timeout: float = 120) -> bool:
@@ -56,9 +95,7 @@ def build(timeout: float = 120) -> bool:
         _log.warning("native build script missing: %s", script)
         return False
     try:
-        subprocess.run(
-            [script], check=True, capture_output=True, timeout=timeout
-        )
+        _run_build(timeout)
     except (subprocess.SubprocessError, OSError) as e:
         _log.warning("native build failed (%s); using NumPy fallback", e)
         return False
@@ -107,20 +144,13 @@ def _probe_and_load() -> Optional[ctypes.CDLL]:
     if os.environ.get("MPI_GRID_NO_NATIVE"):
         return None
     path = os.path.join(_native_dir(), _LIB_NAME)
-    if not os.path.exists(path) and os.environ.get(
-        "MPI_GRID_NATIVE_BUILD"
-    ):
-        build_script = os.path.join(_native_dir(), "build.sh")
-        if os.path.exists(build_script):
-            try:
-                subprocess.run(
-                    [build_script], check=True, capture_output=True,
-                    timeout=120,
-                )
-            except (subprocess.SubprocessError, OSError):
-                return None
-    if not os.path.exists(path):
-        return None
+    if not _built_from_sources():
+        if not os.environ.get("MPI_GRID_NATIVE_BUILD"):
+            return None  # missing or stale: build() rebuilds it
+        try:
+            _run_build(120)
+        except (subprocess.SubprocessError, OSError):
+            return None
     try:
         lib = ctypes.CDLL(path)
     except OSError:

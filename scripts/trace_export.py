@@ -102,10 +102,6 @@ def load_phases(path: str):
 
 def demo_recorder(steps: int = 16):
     """Run a small drift loop and return its populated journal."""
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import numpy as np
 
     from mpi_grid_redistribute_tpu import telemetry

@@ -26,7 +26,7 @@ def test_config1_oracle():
     # the merged telemetry surface rides the bench JSON
     rep = out["api_report"]
     assert rep["kind"] == "redistribute"
-    assert rep["bw_util"] is not None and rep["bw_util"] > 0
+    assert rep["bw_util"] == "not measured"  # timed on the CPU
     assert rep["unresolved_windows"] is False
 
 
@@ -37,7 +37,7 @@ def test_config7_stress():
     # full-reshuffle regime: destinations are uniform, so ~(R-1)/R of
     # rows change owner every step — far above any drift config
     assert out["migration_fraction"] > 0.5
-    assert out["bw_util"] > 0
+    assert out["bw_util"] == "not measured"  # timed on the CPU
     assert out["exchange_bytes_per_step"] > 0
     assert out["timing_spread"] >= 0
     assert out["exchange_domain"] == "hbm"

@@ -366,7 +366,7 @@ def test_device_planar_deposit_sharded_oracle(rng, _devices):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from mpi_grid_redistribute_tpu.compat import shard_map
+    from jax import shard_map
     from mpi_grid_redistribute_tpu.ops import deposit as dep
     from mpi_grid_redistribute_tpu.bench import common
 
@@ -403,7 +403,7 @@ def test_planar_deposit_conserves_and_places(rng, _devices):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from mpi_grid_redistribute_tpu.compat import shard_map
+    from jax import shard_map
     from mpi_grid_redistribute_tpu.domain import Domain, ProcessGrid
     from mpi_grid_redistribute_tpu.ops import deposit as dep
     from mpi_grid_redistribute_tpu.parallel import mesh as mesh_lib
@@ -909,7 +909,7 @@ def test_slab_mxu_fast_path_engages(rng, _devices, monkeypatch):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from mpi_grid_redistribute_tpu.compat import shard_map
+    from jax import shard_map
     from mpi_grid_redistribute_tpu.ops import deposit as dep
     from mpi_grid_redistribute_tpu.parallel import mesh as mesh_lib
 

@@ -48,7 +48,7 @@ _G001_PREAMBLE = """
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import Mesh
-    from mpi_grid_redistribute_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(jax.devices(), axis_names=("shards",))
 """

@@ -68,7 +68,7 @@ def test_initialize_distributed_single_process():
         "import os;"
         "os.environ['XLA_FLAGS']=os.environ.get('XLA_FLAGS','')"
         "+' --xla_force_host_platform_device_count=8';"
-        "import jax; jax.config.update('jax_platforms', 'cpu');"
+        "import jax;"
         "from mpi_grid_redistribute_tpu.parallel import mesh as m;"
         "m.initialize_distributed(coordinator_address='localhost:12399',"
         "num_processes=1, process_id=0);"

@@ -700,10 +700,17 @@ def test_schema_drift_gate():
 # ------------------------------------------------ noise-aware classifier
 
 
+# the parsed numbers of the five pre-PR-1 captures (BENCH_r01..r05,
+# jax 0.4.37): the classifier's fixed test history
+BENCH_HISTORY = os.path.join(
+    REPO_ROOT, "tests", "fixtures", "bench_history", "BENCH_r*.json"
+)
+
+
 def _bench_history():
     caps = []
     for i in range(1, 6):
-        with open(os.path.join(REPO_ROOT, f"BENCH_r{i:02d}.json")) as fh:
+        with open(BENCH_HISTORY.replace("*", f"{i:02d}")) as fh:
             caps.append(json.load(fh))
     return caps
 
@@ -779,9 +786,10 @@ def test_progprofile_hash_drift_notes():
 
 def test_bench_check_cli_passes_on_committed_history():
     """Satellite wiring: `make bench-check` runs the classifier and a
-    WOBBLE-grade delta (the committed r04→r05 history) must exit 0."""
+    WOBBLE-grade delta (the fixture's r04→r05 history) must exit 0."""
     out = subprocess.run(
-        [sys.executable, os.path.join("scripts", "bench_check.py")],
+        [sys.executable, os.path.join("scripts", "bench_check.py"),
+         "--history", BENCH_HISTORY],
         capture_output=True, text=True, cwd=REPO_ROOT, timeout=120,
     )
     assert out.returncode == 0, out.stdout + out.stderr

@@ -67,3 +67,16 @@ def test_oracle_uses_native_and_matches_jax(rng, _devices):
     res_np = gr.GridRedistribute(backend="numpy", **kw).redistribute(pos)
     assert np.asarray(res.positions).tobytes() == res_np.positions.tobytes()
     assert np.asarray(res.count).tobytes() == res_np.count.tobytes()
+
+
+def test_only_a_library_built_from_the_committed_files_loads(monkeypatch):
+    # the module-level build() stamped the .so with the sources' hash
+    assert native._built_from_sources()
+    # an edited source or build flag makes the same .so stale: it is
+    # neither loaded nor trusted until build() rebuilds it
+    monkeypatch.setattr(native, "_source_hash", lambda: "0" * 64)
+    assert not native._built_from_sources()
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.delenv("MPI_GRID_NATIVE_BUILD", raising=False)
+    assert native._load() is None

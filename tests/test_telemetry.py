@@ -20,6 +20,7 @@ from mpi_grid_redistribute_tpu.telemetry import (
     record_migrate_steps,
     row_bytes_of,
 )
+from mpi_grid_redistribute_tpu.telemetry.report import format_report
 from mpi_grid_redistribute_tpu.utils import profiling
 
 
@@ -111,7 +112,7 @@ def test_recorder_events_from_real_grow_path():
         assert rep["exchange_bytes_per_step"] > 0
         assert rep["bw_util"] is None  # no step_seconds supplied
         rep2 = rd.report(step_seconds=1e-3)
-        assert rep2["bw_util"] > 0
+        assert rep2["bw_util"] == "not measured"  # a CPU rate
         assert rep2["events"]["capacity_grow"] == counts["capacity_grow"]
         assert rep2["unresolved_windows"] is False
 
@@ -157,7 +158,10 @@ def _stats_2rank():
 def test_exchange_report_hand_math_hbm():
     stats = _stats_2rank()
     row_bytes = 28
-    rep = exchange_report(stats, row_bytes, step_seconds=0.01, domain="hbm")
+    rep = exchange_report(
+        stats, row_bytes, step_seconds=0.01, domain="hbm",
+        device_kind="TPU v5 lite",
+    )
     # total = 10 rows, moved (off-diagonal) = 3 rows
     assert rep["exchange_bytes_per_step"] == 10 * row_bytes
     assert rep["moved_bytes_per_step"] == 3 * row_bytes
@@ -176,7 +180,8 @@ def test_exchange_report_hand_math_ici():
     stats = _stats_2rank()
     row_bytes = 28
     rep = exchange_report(
-        stats, row_bytes, step_seconds=0.01, domain="ici", n_chips=2
+        stats, row_bytes, step_seconds=0.01, domain="ici", n_chips=2,
+        device_kind="TPU v5 lite",
     )
     # ICI wire carries only the moved rows, and the roof is per chip
     expected_bps = 3 * row_bytes / 0.01
@@ -185,6 +190,22 @@ def test_exchange_report_hand_math_ici():
         profiling.ICI_LINK_BYTES_PER_SEC * profiling.ICI_LINKS_PER_CHIP
     )
     assert rep["bw_util"] == pytest.approx(expected_bps / 2 / roof)
+
+
+def test_exchange_report_cpu_rate_has_no_utilization():
+    # a rate timed on the CPU is never divided by a chip's roof
+    rep = exchange_report(_stats_2rank(), 28, step_seconds=0.01)
+    assert rep["exchange_bytes_per_sec"] == pytest.approx(280 / 0.01)
+    assert rep["bw_util"] == "not measured"
+    assert "not measured" in format_report(rep)
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    assert profiling.chip_peaks("TPU v5 lite").hbm_bytes_per_sec == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        profiling.chip_peaks("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no published peaks"):
+        profiling.exchange_bw_util(1e9, "hbm", device_kind="cpu")
 
 
 def test_exchange_report_without_step_seconds():

@@ -1,5 +1,7 @@
 """utils/: checkpoint round-trips, stats summaries, scan timing."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -236,9 +238,9 @@ def test_exchange_bw_util():
     # hbm domain: fraction of the 819 GB/s v5e HBM roof
     util = profiling.exchange_bw_util(819e9 / 2, "hbm")
     assert abs(util - 0.5) < 1e-12
-    # ici domain: per-chip aggregate vs 4 summed 45 GB/s links
+    # ici domain: per-chip aggregate vs the published 1,600 Gbit/s
     peak = profiling.exchange_peak_bytes_per_sec("ici")
-    assert peak == 4 * 45e9
+    assert peak == 1600e9 / 8
     util = profiling.exchange_bw_util(8 * peak * 0.25, "ici", n_chips=8)
     assert abs(util - 0.25) < 1e-12
     with pytest.raises(ValueError):
@@ -355,3 +357,25 @@ def test_checkpoint_mid_drift_resume_bitlevel(tmp_path, rng, _devices):
         np.testing.assert_array_equal(
             shard_rows(pr, vr, ar, r), shard_rows(p6, v6, a6, r)
         )
+
+
+def test_compile_cache_dir(monkeypatch):
+    import jax
+
+    from mpi_grid_redistribute_tpu.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        # set: JAX reads the env var itself; nothing is set in code
+        monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+        assert compile_cache.enable() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        # unset: the fixed <checkout>/.jax_cache, the same on every call
+        monkeypatch.delenv(compile_cache.ENV)
+        path = compile_cache.enable()
+        assert path == compile_cache.DEFAULT_DIR == os.path.join(
+            compile_cache.CHECKOUT, ".jax_cache"
+        )
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
