@@ -1,0 +1,7 @@
+"""On-chip benchmark of mpi_grid_redistribute_tpu.
+
+``python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s>
+--trace <0|1>`` runs one cell of ``BENCHMARK.json``. Everything that
+belongs to one configuration, traffic mix or metric is a file of its
+own, found by name (see :mod:`benchmark.manifest`).
+"""
