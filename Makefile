@@ -3,7 +3,7 @@ PY ?= python
 .PHONY: test lint lint-json baseline bench-check observe serve-metrics \
 	soak soak-smoke rebalance-smoke service-bench progcheck \
 	progcheck-baseline shardcheck shardcheck-baseline check \
-	attribution attribution-check racecheck racecheck-baseline \
+	racecheck racecheck-baseline \
 	kernelcheck kernelcheck-baseline incident-demo storecheck \
 	grid-top history
 
@@ -90,34 +90,20 @@ service-bench:
 
 # every analyzer family in --check text mode, driven off the single
 # ANALYZERS registry in scripts/check_all.py (gridlint G, progcheck J,
-# shardcheck S, attribution, racecheck T, kernelcheck K, incident-demo
-# I, storecheck ST) — adding a family is one registry row, not a
+# shardcheck S, racecheck T, kernelcheck K, incident-demo I,
+# storecheck ST) — adding a family is one registry row, not a
 # Makefile edit. Exit 0 = clean or
 # fully baselined; 1 = new findings or stale baseline entries; 2 =
 # usage/parse error. See mpi_grid_redistribute_tpu/analysis/.
 lint:
 	$(PY) scripts/check_all.py --lint
 
-# one-shot CI umbrella: the same eight analyzers/gates, SARIF runs merged
+# one-shot CI umbrella: the same seven analyzers/gates, SARIF runs merged
 # into a single analysis_merged.sarif for one code-scanning upload.
 # Per-analyzer wall-time is printed so lint growth stays visible;
 # `--analyzers NAME[,NAME]` subsets the registry for fast local loops.
 check:
 	$(PY) scripts/check_all.py
-
-# roofline observatory (ISSUE 14): re-measure the knockout phase tables
-# (both engines, both committed shapes) + the XLA cost-model roofline
-# report, rewrite telemetry/attribution_baseline.json, and re-render
-# the BENCH_CONFIGS.md CPU tables from it. Minutes of CPU.
-attribution:
-	$(PY) scripts/attribution.py --update-baseline --render
-
-# attribution drift gate (also inside `make check`): structural only —
-# snapshot exists, phase names/counts match the live knockout
-# definitions, roofline covers every registered program, rendered
-# markdown matches the snapshot. Never re-measures.
-attribution-check:
-	$(PY) scripts/attribution.py --check
 
 # progcheck alone: trace every registered SPMD program on the virtual
 # 8-device CPU mesh and gate J001-J004 plus the static wire/footprint

@@ -377,11 +377,6 @@ def main() -> None:
                 # an intentional wire/footprint change from one that
                 # doesn't (see classify_capture's drift note)
                 "progprofile_hash": baseline_lib.progprofile_hash(),
-                # attribution snapshot hash (ISSUE 14): same idea for
-                # the committed phase-table/roofline snapshot — a perf
-                # delta that lands with a refreshed attribution is a
-                # re-measured pipeline, not silent drift
-                "attribution_hash": baseline_lib.attribution_hash(),
             }
         )
     )

@@ -312,92 +312,6 @@ def write_kernelcheck_baseline(
         fh.write("\n")
 
 
-# ---------------------------------------------------------------------
-# attribution's phase/roofline snapshot (telemetry/attribution_baseline
-# .json). Same section-merged document discipline as the progprofile
-# baseline, but it lives next to the telemetry code whose tables it
-# feeds: ``phase_tables`` holds the knockout rows scripts/attribution.py
-# measured (the machine-readable source of the BENCH_CONFIGS.md CPU
-# tables), ``roofline`` holds the cost-model report rows. These helpers
-# stay jax-free so bench.py can embed ``attribution_hash()`` in captures
-# and ``--check`` can validate structure without compiling anything.
-# ---------------------------------------------------------------------
-
-_ATTRIBUTION_NAME = "attribution_baseline.json"
-
-_ATTRIBUTION_COMMENT = (
-    "attribution baseline: the committed phase-knockout tables "
-    "(phase_tables: measured CPU knockout rows per engine/shape, the "
-    "source the BENCH_CONFIGS.md CPU tables are rendered from) and "
-    "the XLA cost-model roofline report (roofline: per-program flops/"
-    "bytes/bound-by). Timings are host-dependent snapshots, so only "
-    "STRUCTURE is gated (`scripts/attribution.py --check`): phase "
-    "names/counts must match the live knockout definitions and the "
-    "roofline section must cover every registered program. Refresh "
-    "with `python scripts/attribution.py --update-baseline` (then "
-    "--render for the markdown) and justify the delta in the commit "
-    "message."
-)
-
-
-def attribution_baseline_path() -> str:
-    return os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "telemetry",
-        _ATTRIBUTION_NAME,
-    )
-
-
-def load_attribution_baseline(
-    path: Optional[str] = None,
-) -> Optional[dict]:
-    """The full attribution snapshot (``phase_tables`` + ``roofline``
-    sections), or ``None`` when it doesn't exist yet — the --check gate
-    then fails with a pointer to --update-baseline rather than
-    crashing."""
-    path = path or attribution_baseline_path()
-    if not os.path.exists(path):
-        return None
-    doc = _read_profile_doc(path)
-    if "phase_tables" not in doc and "roofline" not in doc:
-        raise SystemExit(
-            f"attribution: malformed baseline {path}: expected a "
-            "'phase_tables' and/or 'roofline' section — regenerate with "
-            "scripts/attribution.py --update-baseline"
-        )
-    return doc
-
-
-def write_attribution_baseline(
-    path: Optional[str],
-    phase_tables: Optional[dict] = None,
-    roofline: Optional[dict] = None,
-) -> None:
-    """Section-merge ``phase_tables`` / ``roofline`` into the snapshot
-    (a ``None`` section is left untouched, progprofile-style)."""
-    path = path or attribution_baseline_path()
-    doc = _read_profile_doc(path)
-    doc["comment"] = _ATTRIBUTION_COMMENT
-    if phase_tables is not None:
-        doc["phase_tables"] = {
-            k: phase_tables[k] for k in sorted(phase_tables)
-        }
-    if roofline is not None:
-        doc["roofline"] = {k: roofline[k] for k in sorted(roofline)}
-    _write_profile_doc(path, doc)
-
-
-def attribution_hash(path: Optional[str] = None) -> Optional[str]:
-    """Short content hash of the committed attribution snapshot (None
-    when absent). Captured by bench.py next to ``progprofile_hash`` so
-    regress can correlate a perf delta with a phase-table refresh."""
-    path = path or attribution_baseline_path()
-    if not os.path.exists(path):
-        return None
-    with open(path, "rb") as fh:
-        return hashlib.sha256(fh.read()).hexdigest()[:16]
-
-
 def progprofile_hash(path: Optional[str] = None) -> Optional[str]:
     """Short content hash of the committed profile baseline (None when
     absent). Captured by bench.py so regress can flag 'the static wire

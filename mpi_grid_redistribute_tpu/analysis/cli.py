@@ -45,8 +45,8 @@ _RULE_DOCS = {
     "non-literals) inside resident-path-marked functions (chunk "
     "interior stays on device)",
     "G010": "fastpath-engine/resident-path-marked functions must "
-    "contain at least one named_scope/traced_span (profiler and "
-    "knockout attribution coverage)",
+    "contain at least one named_scope/traced_span (layer attribution "
+    "of profiler traces)",
 }
 
 

@@ -1,7 +1,7 @@
 """racecheck core: host-thread topology model, T-rule registry, CLI.
 
-The fifth analyzer family member (gridlint G / progcheck J / shardcheck
-S / attribution A / racecheck T) covers the one surface the others
+An analyzer family member (gridlint G / progcheck J / shardcheck S /
+racecheck T) that covers the one surface the others
 ignore: the HOST threads of the service control plane. The package
 spawns real ``threading.Thread``s (the driver's async snapshot writer,
 ``scripts/metrics_serve.py --demo``'s drive loop) and serves HTTP from

@@ -1,14 +1,15 @@
 """G010 — marked hot paths must carry at least one named span.
 
-The attribution stack (``telemetry.phases`` knockouts, the roofline
-observatory, ``scripts/trace_export.py``) reads XLA op metadata to map
-profile time back to engine phases: ``jax.named_scope`` (wrapped as
+Layer attribution reads XLA op metadata to map a profiler trace's
+device time back to engine layers: ``jax.named_scope`` (wrapped as
 ``telemetry.phases.traced_span``) stamps every op traced inside it, so
 a profiler session over a marked engine shows ``mig:pack`` /
-``mig:unpack`` lanes instead of op soup. That coverage erodes
-silently — a refactor that drops the span, or a new engine that never
-gained one, costs nothing in any correctness suite; the next chip
-trace just comes back unattributable.
+``mig:unpack`` lanes instead of op soup, and the benchmark's trace
+reduction (``benchmark/xplane.py``) puts each op's time down to the
+layer its scope names. That coverage erodes silently — a refactor
+that drops the span, or a new engine that never gained one, costs
+nothing in any correctness suite; the next chip trace just comes back
+unattributable.
 
 This rule makes span coverage a lint invariant: every function marked
 ``# gridlint: fastpath-engine`` (G006's cost-contract marker) or
@@ -82,9 +83,9 @@ def check_spans(project: Project) -> List[Finding]:
                     fi.node.lineno,
                     fi.node.col_offset,
                     "marked hot path contains no named_scope span — "
-                    "profiler/knockout attribution loses this "
-                    "function; add a telemetry.phases.traced_span "
-                    "around its hot region",
+                    "a profiler trace cannot put this function's "
+                    "device time down to a layer; add a "
+                    "telemetry.phases.traced_span around its hot region",
                     fi.qualname,
                 )
             )

@@ -37,6 +37,7 @@ from mpi_grid_redistribute_tpu.telemetry import context as context_lib
 from mpi_grid_redistribute_tpu.telemetry import flow as flow_lib
 from mpi_grid_redistribute_tpu.telemetry import health as health_lib
 from mpi_grid_redistribute_tpu.telemetry import metrics as metrics_lib
+from mpi_grid_redistribute_tpu.telemetry.phases import span
 from mpi_grid_redistribute_tpu.telemetry import recorder as telemetry_lib
 from mpi_grid_redistribute_tpu.telemetry import report as report_lib
 from mpi_grid_redistribute_tpu.telemetry import traceview as traceview_lib
@@ -953,6 +954,13 @@ class GridRedistribute:
                 counts_out,
                 exchange.RedistributeStats(**stats),
             )
+        # dispatching the engine moves host inputs to the device
+        with span("host:to_device"):
+            return self._run_engine(positions, fields, count, cap, out_cap)
+
+    def _run_engine(
+        self, positions, fields, count, cap: int, out_cap: int
+    ) -> RedistributeResult:
         specs = None
         if self.engine in (
             "auto", "planar", "sparse", "neighbor", "hierarchical"
@@ -1266,9 +1274,10 @@ class GridRedistribute:
         bucket, and re-running on the unchanged inputs — no particle is
         ever lost and steady workloads recompile only on bucket crossings.
         """
-        positions, fields, n_local, count = self._check_inputs(
-            positions, fields, count
-        )
+        with span("host:input_check"):
+            positions, fields, n_local, count = self._check_inputs(
+                positions, fields, count
+            )
         self._call_index += 1
         self._last_row_bytes = report_lib.row_bytes_of(positions, *fields)
         # call-scoped step context: every event this call journals
@@ -1931,9 +1940,8 @@ class GridRedistribute:
         """Export this instance's journal as Chrome-trace/Perfetto JSON
         (:mod:`~.telemetry.traceview`). With ``path`` the JSON is
         written there (returns the event count); without it the trace
-        dict is returned. Extra kwargs (``phase_timings``,
-        ``step_seconds``) pass through to
-        :func:`~.telemetry.traceview.to_chrome_trace`."""
+        dict is returned. Extra kwargs (``step_seconds``) pass through
+        to :func:`~.telemetry.traceview.to_chrome_trace`."""
         if path is not None:
             return traceview_lib.write_trace(
                 path, self.telemetry, **kwargs

@@ -33,6 +33,7 @@ from mpi_grid_redistribute_tpu.ops import (
     pallas_driftbin,
 )
 from mpi_grid_redistribute_tpu.parallel import exchange, migrate, mesh as mesh_lib
+from mpi_grid_redistribute_tpu.telemetry.phases import traced_span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -440,6 +441,14 @@ def make_migrate_loop(
         return dep_fn(pv, jnp.ones(pv.shape[:-1], pv.dtype), valid)
 
     def shard_loop(pos_flat, vel_flat, alive):
+        # scan requires carry leaves already marked device-varying (some
+        # init_state outputs are iota-derived and start unvaried)
+        def _vary(x):
+            missing = tuple(
+                a for a in axes if a not in jax.typeof(x).vma
+            )
+            return lax.pcast(x, missing, to="varying") if missing else x
+
         # inputs cross the shard_map boundary as PLANAR flat arrays
         # (component-major [D * n]): a 1-D parameter converts compactly
         # and the reshape to [D, n] splits the MAJOR axis — no row-major
@@ -450,28 +459,25 @@ def make_migrate_loop(
         # < 2^23 — measured on-chip, round 4), integer lanes don't; the
         # drift below views position/velocity rows as f32 for the
         # arithmetic only (migrate.fuse_fields).
-        fused = jnp.concatenate(
-            [
-                lax.bitcast_convert_type(
-                    pos_flat.reshape(D, -1), jnp.int32
-                ),
-                lax.bitcast_convert_type(
-                    vel_flat.reshape(D, -1), jnp.int32
-                ),
-                alive.astype(jnp.int32)[None, :],
-            ],
-            axis=0,
-        )
-        state = migrate.init_state(fused, vranks=V, batched=vgrid is not None)
-        # scan requires carry leaves already marked device-varying (some
-        # init_state outputs are iota-derived and start unvaried)
-        def _vary(x):
-            missing = tuple(
-                a for a in axes if a not in jax.typeof(x).vma
+        # mig:enter / mig:exit scope the once-per-call work outside the
+        # scan: the planar fuse and free-stack argsort, the final split.
+        with traced_span("mig:enter"):
+            fused = jnp.concatenate(
+                [
+                    lax.bitcast_convert_type(
+                        pos_flat.reshape(D, -1), jnp.int32
+                    ),
+                    lax.bitcast_convert_type(
+                        vel_flat.reshape(D, -1), jnp.int32
+                    ),
+                    alive.astype(jnp.int32)[None, :],
+                ],
+                axis=0,
             )
-            return lax.pcast(x, missing, to="varying") if missing else x
-
-        state = jax.tree.map(_vary, state)
+            state = migrate.init_state(
+                fused, vranks=V, batched=vgrid is not None
+            )
+            state = jax.tree.map(_vary, state)
 
         def body(carry, _):
             state = carry[0]
@@ -481,22 +487,24 @@ def make_migrate_loop(
                 # key (ops/pallas_driftbin.py; bit-identical to the XLA
                 # chain below by test; 6-7x its measured cost — the XLA
                 # chain runs ~9x its bandwidth roofline)
-                f, dest_key = pallas_driftbin.drift_wrap_bin(
-                    f, float(cfg.dt), cfg.domain, full_grid,
-                    V, V,
-                )
+                with traced_span("mig:drift"):
+                    f, dest_key = pallas_driftbin.drift_wrap_bin(
+                        f, float(cfg.dt), cfg.domain, full_grid,
+                        V, V,
+                    )
                 state, stats = mig(state._replace(fused=f), dest_key)
             else:
-                pf = lax.bitcast_convert_type(f[:D, :], jnp.float32)
-                vf = lax.bitcast_convert_type(
-                    f[D : 2 * D, :], jnp.float32
-                )
-                p = pf + vf * jnp.asarray(cfg.dt, pf.dtype)
-                p = binning.wrap_periodic_planar(p, cfg.domain)
-                f = jnp.concatenate(
-                    [lax.bitcast_convert_type(p, jnp.int32), f[D:, :]],
-                    axis=0,
-                )
+                with traced_span("mig:drift"):
+                    pf = lax.bitcast_convert_type(f[:D, :], jnp.float32)
+                    vf = lax.bitcast_convert_type(
+                        f[D : 2 * D, :], jnp.float32
+                    )
+                    p = pf + vf * jnp.asarray(cfg.dt, pf.dtype)
+                    p = binning.wrap_periodic_planar(p, cfg.domain)
+                    f = jnp.concatenate(
+                        [lax.bitcast_convert_type(p, jnp.int32), f[D:, :]],
+                        axis=0,
+                    )
                 state, stats = mig(state._replace(fused=f))
             new_carry = (state,)
             if deposit_each_step:
@@ -530,12 +538,15 @@ def make_migrate_loop(
         state = carry[0]
         # planar exit: row-slices of the fused matrix, flattened
         # component-major — again no [n, D] buffer materializes
-        f = state.fused
-        pos_f = lax.bitcast_convert_type(f[:D, :], jnp.float32).reshape(-1)
-        vel_f = lax.bitcast_convert_type(
-            f[D : 2 * D, :], jnp.float32
-        ).reshape(-1)
-        alive_f = f[-1, :] > 0
+        with traced_span("mig:exit"):
+            f = state.fused
+            pos_f = lax.bitcast_convert_type(
+                f[:D, :], jnp.float32
+            ).reshape(-1)
+            vel_f = lax.bitcast_convert_type(
+                f[D : 2 * D, :], jnp.float32
+            ).reshape(-1)
+            alive_f = f[-1, :] > 0
         if dep_fn is None:
             return pos_f, vel_f, alive_f, stats
         rho = carry[1] if deposit_each_step else _deposit(state.fused)

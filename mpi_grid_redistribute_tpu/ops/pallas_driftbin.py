@@ -3,7 +3,7 @@
 THE WALL. The migrate loop's phase 0-1 (drift the planar state, wrap,
 bin to destination keys) is pure elementwise arithmetic, yet measures
 ~9x its bandwidth roofline under XLA (6.3 ms at 8.4M rows, 68 ms at the
-64M north-star — scripts/knockout_stages.py): the chain materializes
+64M north-star — the round-4 phase knockout): the chain materializes
 several narrow ``[D, m]`` intermediates (2.67x sublane-padded in the
 T(8,128) layout) and the scan-carry concatenate rewrites the whole
 ``[K, m]`` state once more. Both measured XLA reformulations (DUS drift,

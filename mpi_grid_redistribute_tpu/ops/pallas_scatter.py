@@ -15,8 +15,8 @@ layout + in-kernel tile transposes), and (BLOCK, 7) f32 blocks lane-pad
 to (BLOCK, 128) in VMEM (hence vmem_limit_bytes).
 
 XLA's row scatter costs ~120-150 ns per scattered row on TPU regardless
-of row width (measured, scripts/profile_stages.py and
-scripts/knockout_stages.py) and dominates the migrate step (~27 ms of 53
+of row width (measured, scripts/profile_stages.py and the round-4
+phase knockout) and dominates the migrate step (~27 ms of 53
 at 196k rows). This kernel reformulates the scatter as a streamed
 overlay:
 

@@ -31,9 +31,8 @@ from mpi_grid_redistribute_tpu.domain import Domain, ProcessGrid
 from mpi_grid_redistribute_tpu.ops import binning, pack
 # rd:bin / rd:pack / rd:exchange / rd:unpack labels on the engine phases:
 # a jax.named_scope lands in XLA op metadata, so Perfetto/XProf traces and
-# HLO dumps group the pipeline by phase instead of op soup (telemetry
-# tentpole; scan-differenced phase COSTS come from telemetry.phases.
-# attribute_phases — these scopes are for trace/HLO readability).
+# HLO dumps group the pipeline by phase instead of op soup, and a trace
+# reduction reads each phase's device time from the op's scope.
 from mpi_grid_redistribute_tpu.telemetry.phases import traced_span
 
 

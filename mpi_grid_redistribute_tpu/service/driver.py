@@ -53,6 +53,7 @@ from mpi_grid_redistribute_tpu.service.faults import FaultPlan, StallError
 from mpi_grid_redistribute_tpu.telemetry import StepRecorder
 from mpi_grid_redistribute_tpu.telemetry import context as context_lib
 from mpi_grid_redistribute_tpu.telemetry.health import HealthMonitor
+from mpi_grid_redistribute_tpu.telemetry.phases import span
 from mpi_grid_redistribute_tpu.telemetry.probes import (
     ProbeConfig,
     record_probe_steps,
@@ -585,7 +586,7 @@ class ServiceDriver:
         )
 
         def write() -> None:
-            with context_lib.use(wctx):
+            with context_lib.use(wctx), span("host:snapshot_write"):
                 try:
                     checkpoint.save(
                         path, arrays, nranks=self.nranks, step=step,
@@ -1007,8 +1008,10 @@ class ServiceDriver:
         try:
             self._state_health_gate()
             if cfg.snapshot_every and self.step % cfg.snapshot_every == 0:
-                self._materialize_state()
-                path = self.snapshot()
+                # the state's device-to-host copy and the write's enqueue
+                with span("host:snapshot"):
+                    self._materialize_state()
+                    path = self.snapshot()
                 self.faults.after_snapshot(self, path)
                 self._health_check()
             elif cfg.health_every and self.step % cfg.health_every == 0:
@@ -1020,7 +1023,8 @@ class ServiceDriver:
             # even when the check raised SLOBreachError — the breach
             # evidence must be on disk before the restart tears us down
             if self._store is not None:
-                self._store.drain(self.recorder)
+                with span("host:journal_drain"):
+                    self._store.drain(self.recorder)
 
     def _run_chunk_eager(self, n: int, fire_faults: bool = True) -> None:
         """Advance ``n`` steps through the eager per-step engine path

@@ -289,17 +289,19 @@ def make_pipelined_chunk_fn(rd, dt, chunk, positions, *fields, unroll=8,
 
     # gridlint: resident-path
     def macro(pos, vel, ids, count):
-        fused_p = api._fuse_planar(
-            pos, (vel, ids), V, n, specs, stacked=False
-        )
-        gcol = jnp.arange(V * n, dtype=jnp.int32)
-        alive0 = ((gcol % n) < count[gcol // n]).astype(jnp.int32)
-        work = jnp.concatenate([fused_p, alive0[None]], axis=0)
-        st = migrate.init_state(work, vranks=V, batched=True)
-        live0 = jnp.sum(count).astype(jnp.int32)
-        # prologue: step 1's drift + issue (nothing in flight yet)
-        T = _drift(st.fused)
-        plan, arr, ys1, feas = _issue_tail(T, st.n_free)
+        with traced_span("pipe:enter"):
+            fused_p = api._fuse_planar(
+                pos, (vel, ids), V, n, specs, stacked=False
+            )
+            gcol = jnp.arange(V * n, dtype=jnp.int32)
+            alive0 = ((gcol % n) < count[gcol // n]).astype(jnp.int32)
+            work = jnp.concatenate([fused_p, alive0[None]], axis=0)
+            st = migrate.init_state(work, vranks=V, batched=True)
+            live0 = jnp.sum(count).astype(jnp.int32)
+            # prologue: step 1's drift (nothing in flight yet)
+            T = _drift(st.fused)
+        with traced_span("pipe:issue"):
+            plan, arr, ys1, feas = _issue_tail(T, st.n_free)
         cum0 = jnp.int32(0)
         if armed:
             cum0 = statehealth.step_dropped(
@@ -322,7 +324,7 @@ def make_pipelined_chunk_fn(rd, dt, chunk, positions, *fields, unroll=8,
             with traced_span("pipe:issue"):
                 plan2 = tp.issue(key2, nf2)
                 arr2 = pack.gather_plan_cols(T2, plan2.arr_plan)
-            ys, feas2 = _step_ys(plan2, nf2)
+                ys, feas2 = _step_ys(plan2, nf2)
             carry2 = (
                 T2, stack2, nf2, arr2,
                 plan2.vacated, plan2.n_sent, plan2.n_in, feas2,
@@ -345,34 +347,35 @@ def make_pipelined_chunk_fn(rd, dt, chunk, positions, *fields, unroll=8,
         carry, ys_rest = lax.scan(
             body, carry, None, length=chunk - 1, unroll=1
         )
-        ys = jax.tree.map(
-            lambda a, b: jnp.concatenate([a[None], b], axis=0),
-            ys1,
-            ys_rest,
-        )
-        # epilogue: land step `chunk` (already drifted at issue time —
-        # no further drift) and compact the resident slots once
-        T, stack, nf, arr, vac, ns, ni = carry[:7]
-        Tf, _, _, _ = tp.land(T, stack, nf, arr, vac, ns, ni)
-        alive = (Tf[-1] > 0).reshape(V, n)
-        perm = jnp.argsort(
-            jnp.where(alive, jnp.int32(0), jnp.int32(1)),
-            axis=1,
-            stable=True,
-        ).astype(jnp.int32)
-        gidx = (
-            jnp.arange(V, dtype=jnp.int32)[:, None] * n + perm
-        ).reshape(-1)
-        compact = jnp.take(Tf, gidx, axis=1)
-        count_f = jnp.sum(alive, axis=1).astype(jnp.int32)
-        pad = (
-            jnp.arange(n, dtype=jnp.int32)[None, :] < count_f[:, None]
-        ).reshape(-1)
-        compact = jnp.where(pad[None, :], compact, 0)
-        pos_f, fields_f = api._unfuse_planar(
-            compact[:KP], specs, V, n, stacked=False
-        )
-        vel_f, ids_f = fields_f
+        with traced_span("pipe:exit"):
+            ys = jax.tree.map(
+                lambda a, b: jnp.concatenate([a[None], b], axis=0),
+                ys1,
+                ys_rest,
+            )
+            # epilogue: land step `chunk` (already drifted at issue time —
+            # no further drift) and compact the resident slots once
+            T, stack, nf, arr, vac, ns, ni = carry[:7]
+            Tf, _, _, _ = tp.land(T, stack, nf, arr, vac, ns, ni)
+            alive = (Tf[-1] > 0).reshape(V, n)
+            perm = jnp.argsort(
+                jnp.where(alive, jnp.int32(0), jnp.int32(1)),
+                axis=1,
+                stable=True,
+            ).astype(jnp.int32)
+            gidx = (
+                jnp.arange(V, dtype=jnp.int32)[:, None] * n + perm
+            ).reshape(-1)
+            compact = jnp.take(Tf, gidx, axis=1)
+            count_f = jnp.sum(alive, axis=1).astype(jnp.int32)
+            pad = (
+                jnp.arange(n, dtype=jnp.int32)[None, :] < count_f[:, None]
+            ).reshape(-1)
+            compact = jnp.where(pad[None, :], compact, 0)
+            pos_f, fields_f = api._unfuse_planar(
+                compact[:KP], specs, V, n, stacked=False
+            )
+            vel_f, ids_f = fields_f
         return (pos_f, vel_f, ids_f, count_f), ys
 
     # progcheck walks this program via the registry entry; both markers

@@ -2,18 +2,17 @@
 
 Turns the scattered instruments that grew around the engines — the
 scan-differencing timers in :mod:`..utils.profiling`, the stats summaries
-in :mod:`..utils.stats`, the per-op knockout scripts — into one subsystem
-with four pieces:
+in :mod:`..utils.stats` — into one subsystem with four pieces:
 
 * :mod:`.recorder` — a bounded host-side ring buffer of structured events
   (capacity growth, overflow window scheduling/resolution, halo cap
   growth, per-step exchange counters) with JSONL export. Every
   :class:`~..api.GridRedistribute` owns one as ``rd.telemetry``.
-* :mod:`.phases` — reusable phase attribution: ``attribute_phases()``
-  wraps the knockout/scan-differencing technique behind one API, and
-  ``span()``/``traced_span()`` label host regions (Perfetto
-  ``TraceAnnotation``) and traced regions (``jax.named_scope`` → XLA op
-  metadata) so profiles read as bin/pack/exchange/unpack, not op soup.
+* :mod:`.phases` — ``span()``/``traced_span()`` label host regions
+  (``jax.profiler.TraceAnnotation``: ``host:*`` spans on the service and
+  API paths) and traced regions (``jax.named_scope`` → XLA op metadata:
+  ``mig:*``, ``rd:*``, ``svc:*``, ``pipe:*``), so a profiler trace puts
+  every device op of a step down to a layer, not op soup.
 * :mod:`.report` — the metrics surface: one merged dict (stats summary,
   exchange bytes/step, achieved GB/s, ``bw_util`` against the HBM/ICI
   roofs in :mod:`..utils.profiling`, growth/overflow event counts),
@@ -34,9 +33,9 @@ The grid observatory (PR 3) adds three layers on that substrate:
   evaluating declarative rules (backlog growth, dropped rows, grow
   frequency, imbalance, step-time spikes) over the journal; findings
   fire callbacks and land as ``alert`` events in the same ring.
-* :mod:`.traceview` — Perfetto/Chrome-trace JSON export of the journal,
-  phase attributions and migrate counter tracks
-  (``scripts/trace_export.py``; ``rd.to_perfetto()``).
+* :mod:`.traceview` — Perfetto/Chrome-trace JSON export of the journal
+  and migrate counter tracks (``scripts/trace_export.py``;
+  ``rd.to_perfetto()``).
 
 The metrics plane (ISSUE 5) makes the journal scrapable pod-wide:
 
@@ -55,18 +54,13 @@ The metrics plane (ISSUE 5) makes the journal scrapable pod-wide:
   (``classify_capture`` — WOBBLE/WARN/REGRESSION against the captures'
   own min-of-k spreads) and ``env_fingerprint()``.
 
-The roofline observatory (ISSUE 14) closes the predicted-vs-achieved
-loop:
+Device time is read from profiler traces:
 
-* :mod:`.roofline` — per-program analytic rooflines from XLA's own cost
-  model (``Compiled.cost_analysis()`` FLOPs / bytes over the chip roofs
-  in :mod:`..utils.profiling`), cross-checked against the J004/S004
-  static wire model with discrepancies journaled as ``roofline`` events
-  (``scripts/attribution.py`` is the CLI).
 * :mod:`.profiler` — :class:`~.profiler.ProfilerSession`, the gated
   programmatic ``jax.profiler`` trace wrapper (``GRID_PROFILE_DIR`` /
   ``DriverConfig.profile_dir``), journaled as ``profile_session``
-  events.
+  events. Its trace holds the device ops under their layer scopes and
+  the host spans of :mod:`.phases` on one clock.
 
 The incident observatory (ISSUE 17) makes the journal causal and the
 alerts actionable:
@@ -134,9 +128,6 @@ from mpi_grid_redistribute_tpu.telemetry.recorder import (  # noqa: F401
     record_migrate_steps,
 )
 from mpi_grid_redistribute_tpu.telemetry.phases import (  # noqa: F401
-    PhaseTiming,
-    attribute_phases,
-    format_phase_table,
     span,
     traced_span,
 )
@@ -198,10 +189,6 @@ from mpi_grid_redistribute_tpu.telemetry.incident import (  # noqa: F401
 from mpi_grid_redistribute_tpu.telemetry.traceview import (  # noqa: F401
     to_chrome_trace,
     write_trace,
-)
-from mpi_grid_redistribute_tpu.telemetry.roofline import (  # noqa: F401
-    format_roofline_table,
-    roofline_report,
 )
 from mpi_grid_redistribute_tpu.telemetry.profiler import (  # noqa: F401
     ProfilerSession,

@@ -449,8 +449,6 @@ def from_journal(
     * ``step_latency_seconds`` / ``dropped_rows`` — pow2 histograms of
       the service driver's ``step_latency`` events (the SLO surface);
     * ``snapshot_corrupt_total`` — corrupt snapshots skipped at restore;
-    * ``roofline_achieved_fraction{program,phase}`` — latest analytic
-      predicted/measured fraction per ``roofline`` event;
     * ``profile_sessions_total`` — ``profile_session`` events (profiler
       captures attempted);
     * ``state_nan_total{field}`` / ``state_oob_total`` — corrupt-row
@@ -556,12 +554,6 @@ def from_journal(
         "Live rows per vrank (latest flow_snapshot population leaf)",
         ("vrank",),
     )
-    roofline_g = reg.gauge(
-        f"{p}_roofline_achieved_fraction",
-        "Analytic-roofline predicted/measured step-time fraction per"
-        " program (latest roofline event; 1.0 = at the roof)",
-        ("program", "phase"),
-    )
     profile_c = reg.counter(
         f"{p}_profile_sessions",
         "Profiler trace sessions attempted (profile_session events;"
@@ -589,7 +581,7 @@ def from_journal(
         " latest probed step; nonzero = unaccounted row loss/creation",
     )
 
-    saw_migrate = saw_flow = saw_roofline = saw_state = False
+    saw_migrate = saw_flow = saw_state = False
     for kind, data in events:
         if kind == "migrate_step":
             saw_migrate = True
@@ -642,13 +634,6 @@ def from_journal(
                 flow_pop._children.clear()
                 for vr, rows_live in enumerate(data["population"]):
                     flow_pop.labels(vrank=vr).set(int(rows_live))
-        elif kind == "roofline":
-            if data.get("achieved_fraction") is not None:
-                saw_roofline = True
-                roofline_g.labels(
-                    program=data.get("program", "unknown"),
-                    phase=data.get("phase", "total"),
-                ).set(float(data["achieved_fraction"]))
         elif kind == "profile_session":
             profile_c.labels().inc()
         elif kind == "state_health":
@@ -681,8 +666,6 @@ def from_journal(
     if not saw_flow:
         for fam in (flow_moved, flow_imb, flow_pop):
             fam._children.clear()
-    if not saw_roofline:
-        roofline_g._children.clear()
     if not saw_state:
         for fam in (state_live, state_res):
             fam._children.clear()

@@ -1,9 +1,8 @@
 """Programmatic profiler sessions (ISSUE 14).
 
-``utils/profiling.trace`` already wraps ``jax.profiler.trace`` for
-hand-run chip sessions; this module makes the capture a SERVICE
-feature: :class:`ProfilerSession` is a context manager any driver or
-CLI can hold around its hot region, gated by configuration
+The one wrapper around ``jax.profiler``'s trace capture: it makes the
+capture a SERVICE feature. :class:`ProfilerSession` is a context manager
+any driver or CLI can hold around its hot region, gated by configuration
 (``DriverConfig.profile_dir`` / the ``GRID_PROFILE_DIR`` env knob) so a
 chip session captures traces without code edits, and journaled as a
 ``profile_session`` event so the capture is discoverable from the

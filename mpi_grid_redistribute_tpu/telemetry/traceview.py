@@ -5,7 +5,7 @@ module emits loads in Perfetto (ui.perfetto.dev) or ``chrome://tracing``
 — the standard Trace Event Format (``{"traceEvents": [...]}``, each
 event carrying ``ph``/``ts``/``pid``/``tid``/``name``).
 
-Three track families:
+Two track families:
 
 * **Journal instants** (pid 0): every retained
   :class:`~.recorder.StepRecorder` event becomes an instant event
@@ -18,10 +18,6 @@ Three track families:
   each ``alert`` / ``restart`` / ``incident`` instant is linked back to
   the latest preceding same-trace cause event, so the UI draws the
   arrow from the step that burned the budget to the alert it tripped.
-* **Phase spans** (pid 1): :class:`~.phases.PhaseTiming` rows (the
-  knockout / ``attribute_phases`` output) become duration events
-  (``ph="X"``) laid end to end — each phase's span length is its
-  attributed ``delta_s``, so the lane reads as one step's time budget.
 * **Migrate counters** (pid 2): ``migrate_step`` journal events become
   counter tracks (``ph="C"``) for population, backlog, sent — the
   timeline view of the drift workload unbalancing. When the journal
@@ -38,11 +34,10 @@ Three track families:
 from __future__ import annotations
 
 import json
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional
 
 _TRACK_FAMILIES = {
     0: "journal (instant events per kind)",
-    1: "phase attribution (duration events)",
     2: "migrate steps (counter tracks)",
 }
 
@@ -71,9 +66,7 @@ def _json_safe(v):
 
 def to_chrome_trace(
     recorder=None,
-    phase_timings: Optional[Sequence] = None,
     step_seconds: Optional[float] = None,
-    annotations: Optional[Dict[str, dict]] = None,
 ) -> Dict[str, object]:
     """Build one Trace Event Format dict from telemetry sources.
 
@@ -81,15 +74,8 @@ def to_chrome_trace(
       recorder: a :class:`~.recorder.StepRecorder`; its retained events
         become instant events (pid 0) and its ``migrate_step`` events
         additionally feed the counter tracks (pid 2).
-      phase_timings: :class:`~.phases.PhaseTiming` rows
-        (``attribute_phases`` output) for the duration lane (pid 1).
       step_seconds: honest per-step seconds for the counter track's
         synthetic time axis (default 1 ms per step).
-      annotations: optional ``{phase_name: {key: value}}`` cost context
-        (roofline flops/bytes/bound-by — see ``telemetry.roofline``)
-        merged into the matching pid-1 duration event's ``args`` so the
-        Perfetto tooltip shows what the phase SHOULD cost next to what
-        it measured. Keys never overwrite the measured columns.
 
     Returns a JSON-serializable dict; every event carries the required
     ``ph``/``ts``/``pid`` keys (schema-checked in ``tests/test_flow.py``).
@@ -159,38 +145,6 @@ def to_chrome_trace(
             elif e.kind != "callback_error":
                 last_by_trace[trace] = i
 
-    # --- pid 1: phase-attribution duration lane -----------------------
-    if phase_timings:
-        events.append(_meta(1, 0, "thread_name", "phases"))
-        cursor = 0.0
-        for row in phase_timings:
-            dur = max(float(row.delta_s), 0.0) * 1e6
-            args: Dict[str, object] = {
-                "cumulative_s": float(row.cumulative_s),
-                "delta_s": float(row.delta_s),
-            }
-            if getattr(row, "logical_bytes", None) is not None:
-                args["logical_bytes"] = int(row.logical_bytes)
-            x = getattr(row, "x_roofline", None)
-            if x is not None:
-                args["x_roofline"] = float(x)
-            extra = (annotations or {}).get(str(row.phase))
-            if extra:
-                for k, v in extra.items():
-                    args.setdefault(str(k), _json_safe(v))
-            events.append(
-                {
-                    "name": str(row.phase),
-                    "ph": "X",
-                    "ts": cursor,
-                    "dur": dur,
-                    "pid": 1,
-                    "tid": 0,
-                    "args": args,
-                }
-            )
-            cursor += dur
-
     # --- pid 2: migrate-step counter tracks ---------------------------
     if recorder is not None:
         dt_us = (step_seconds if step_seconds else 1e-3) * 1e6
@@ -231,18 +185,11 @@ def to_chrome_trace(
 def write_trace(
     path: str,
     recorder=None,
-    phase_timings: Optional[Sequence] = None,
     step_seconds: Optional[float] = None,
-    annotations: Optional[Dict[str, dict]] = None,
 ) -> int:
     """Write :func:`to_chrome_trace` JSON to ``path``; returns the number
     of trace events written (metadata included)."""
-    trace = to_chrome_trace(
-        recorder,
-        phase_timings=phase_timings,
-        step_seconds=step_seconds,
-        annotations=annotations,
-    )
+    trace = to_chrome_trace(recorder, step_seconds=step_seconds)
     with open(path, "w") as f:
         json.dump(trace, f)
     return len(trace["traceEvents"])

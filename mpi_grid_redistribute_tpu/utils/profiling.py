@@ -1,4 +1,4 @@
-"""Timing + tracing helpers (SURVEY.md §5.1).
+"""Timing helpers (SURVEY.md §5.1).
 
 The driver metric is "particles redistributed/sec/chip; ICI all_to_all BW
 utilization". Getting honest numbers on TPU needs care:
@@ -16,7 +16,6 @@ configurations.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Callable, Tuple
@@ -119,17 +118,6 @@ def _scan_time_impl(make_loop, args, s1, s2, reps):
     samples = [(t2 - t1) / (s2 - s1) for t2 in times2]
     per_step = min(samples)
     return per_step, t1 - per_step * s1, out2, samples
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """``jax.profiler.trace`` wrapper producing a Perfetto/XProf trace.
-
-    Remember to end the traced region with a :func:`fetch_barrier` so the
-    device timeline is complete before the trace closes.
-    """
-    with jax.profiler.trace(log_dir):
-        yield
 
 
 # Published per-chip peaks, keyed by JAX's ``device_kind``. Source: Google

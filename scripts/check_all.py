@@ -54,11 +54,6 @@ ANALYZERS = (
         "mpi_grid_redistribute_tpu/analysis/progprofile_baseline.json",
     ),
     Analyzer(
-        "attribution",
-        ["scripts/attribution.py", "--check"],
-        "mpi_grid_redistribute_tpu/telemetry/attribution_baseline.json",
-    ),
-    Analyzer(
         "racecheck",
         ["scripts/racecheck.py", "--check"],
         "mpi_grid_redistribute_tpu/analysis/racecheck_baseline.json",

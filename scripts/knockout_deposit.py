@@ -1,6 +1,6 @@
 """Knockout profiling of the PLANAR scan deposit at the 64M north-star
 shape (config 5's non-migrate cost): time the deposit truncated after each
-phase, scan-length-differenced like scripts/knockout_stages.py.
+phase, scan-length-differenced.
 
 The fused config-5 step at 64M measures 1931 ms while the migrate step
 alone is ~261 ms — the deposit is ~1670 ms and has never had its own
@@ -14,10 +14,10 @@ attribution. Phases of ``ops.deposit.cic_deposit_vranks_planar``:
   5. boundary gathers + differencing -> per_cell [8, V*n_cells]
   6. placement: reshape + corner pads + vrank assembly + ghost fold
 
-MAINTENANCE: phases are a DELIBERATE copy of the deposit core (same
-reason as knockout_stages.py — a truncating profiler cannot share the
-un-truncatable original). Phase 6 must match the standalone deposit cost
-inferred from bench/config5_deposit.py minus the migrate step.
+MAINTENANCE: phases are a DELIBERATE copy of the deposit core (a
+truncating profiler cannot share the un-truncatable original). Phase 6
+must match the standalone deposit cost inferred from
+bench/config5_deposit.py minus the migrate step.
 
 Usage: python scripts/knockout_deposit.py [n_per_vrank]
        KNOCKOUT_GRID=4,4,4 python scripts/knockout_deposit.py 1048576

@@ -2,9 +2,9 @@
 the per-pass stages — selection predicate, packed-order sort, column
 gather, or the roll/append tail — dominates the 36.8 ns/ghost cost.
 
-Truncated variants (cumulative, scan-differenced like
-scripts/knockout_stages.py; zero recv is fed to later axes for truncated
-variants, so deltas are directional — the full variant is the engine):
+Truncated variants (cumulative, scan-differenced; zero recv is fed to
+later axes for truncated variants, so deltas are directional — the
+full variant is the engine):
 
   A  predicate + counts per pass
   B  A + packed one-word order sort (pack._stable_order)

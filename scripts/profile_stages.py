@@ -7,8 +7,10 @@ compile and dispatch cancel. Each stage's scan carries a data
 dependency through the timed op so XLA cannot hoist or DCE it.
 
 In-context attribution (the sum here can differ from the real step —
-isolated microbenches measured 2x off for the vmapped scatter) lives in
-scripts/knockout_stages.py; this script is the per-op sanity check.
+isolated microbenches measured 2x off for the vmapped scatter) comes
+from the device trace of the real step, whose ops carry layer scopes
+(``mig:*``; see ``benchmark/``); this script is the per-op sanity
+check.
 
 Usage:  python scripts/profile_stages.py [n_local] [capacity]
 """

@@ -2,14 +2,11 @@
 """Export telemetry to a Perfetto/Chrome-trace JSON (`make observe`).
 
 Thin CLI over :mod:`mpi_grid_redistribute_tpu.telemetry.traceview`.
-Three input sources, combinable:
+Two input sources:
 
 * ``--journal FILE`` — a JSON Lines journal written by
   ``StepRecorder.to_jsonl`` (or ``GridRedistribute.telemetry``); events
   are re-hydrated and become the instant + counter tracks.
-* ``--phases FILE`` — a JSON list of phase rows as dumped by
-  ``KNOCKOUT_JSON=file scripts/knockout_stages.py`` (the
-  ``attribute_phases`` output); rows become the duration lane.
 * ``--demo`` — no artifacts handy: run a small in-process drift loop on
   whatever devices exist and trace that journal.
 
@@ -17,10 +14,6 @@ Examples:
 
   # journal from a bench run -> trace
   python scripts/trace_export.py --journal run.jsonl --out trace.json
-
-  # knockout attribution -> duration lane (same trace file)
-  KNOCKOUT_JSON=phases.json python scripts/knockout_stages.py
-  python scripts/trace_export.py --phases phases.json --out trace.json
 
   # self-contained demo
   python scripts/trace_export.py --demo --out trace.json
@@ -70,36 +63,6 @@ def load_journal(path: str):
     return rec
 
 
-def load_phases(path: str):
-    """Load phase rows dumped as JSON into PhaseTiming tuples."""
-    from mpi_grid_redistribute_tpu.telemetry import phases as phases_lib
-
-    with open(path) as f:
-        rows = json.load(f)
-    if not isinstance(rows, list):
-        raise SystemExit(f"{path}: expected a JSON list of phase rows")
-    out = []
-    for r in rows:
-        out.append(
-            phases_lib.PhaseTiming(
-                phase=r["phase"],
-                cumulative_s=float(r["cumulative_s"]),
-                delta_s=float(r["delta_s"]),
-                logical_bytes=(
-                    None
-                    if r.get("logical_bytes") is None
-                    else int(r["logical_bytes"])
-                ),
-                roofline_s=(
-                    None
-                    if r.get("roofline_s") is None
-                    else float(r["roofline_s"])
-                ),
-            )
-        )
-    return out
-
-
 def demo_recorder(steps: int = 16):
     """Run a small drift loop and return its populated journal."""
     import numpy as np
@@ -140,9 +103,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--journal", type=str, default=None,
                     help="StepRecorder JSONL export to re-hydrate")
-    ap.add_argument("--phases", type=str, default=None,
-                    help="JSON list of attribute_phases rows "
-                         "(KNOCKOUT_JSON=file scripts/knockout_stages.py)")
     ap.add_argument("--demo", action="store_true",
                     help="run a small drift loop in-process and trace it")
     ap.add_argument("--steps", type=int, default=16,
@@ -150,19 +110,12 @@ def main(argv=None) -> int:
     ap.add_argument("--step-seconds", type=float, default=None,
                     help="measured per-step seconds for the counter "
                          "track's synthetic time axis (default 1 ms)")
-    ap.add_argument("--roofline", type=str, default=None,
-                    metavar="PROGRAM",
-                    help="annotate the --phases duration lane with "
-                         "PROGRAM's committed cost-model row (flops, "
-                         "bytes, bound-by — from telemetry/"
-                         "attribution_baseline.json; see "
-                         "scripts/attribution.py)")
     ap.add_argument("--out", type=str, required=True,
                     help="output trace JSON path")
     args = ap.parse_args(argv)
 
-    if not (args.journal or args.phases or args.demo):
-        ap.error("nothing to export: give --journal, --phases, or --demo")
+    if not (args.journal or args.demo):
+        ap.error("nothing to export: give --journal or --demo")
 
     from mpi_grid_redistribute_tpu.telemetry import traceview
 
@@ -171,40 +124,8 @@ def main(argv=None) -> int:
         rec = load_journal(args.journal)
     elif args.demo:
         rec = demo_recorder(steps=args.steps)
-    timings = load_phases(args.phases) if args.phases else None
-
-    annotations = None
-    if args.roofline:
-        if not timings:
-            ap.error("--roofline annotates the phase lane: give --phases")
-        from mpi_grid_redistribute_tpu.analysis.baseline import (
-            load_attribution_baseline,
-        )
-
-        doc = load_attribution_baseline()
-        row = ((doc or {}).get("roofline") or {}).get(args.roofline)
-        if row is None:
-            raise SystemExit(
-                f"--roofline: program {args.roofline!r} is not in the "
-                "committed attribution snapshot — see "
-                "scripts/attribution.py --update-baseline"
-            )
-        cost = {
-            k: row.get(k)
-            for k in (
-                "flops",
-                "bytes_accessed",
-                "t_predicted_s",
-                "bound_by",
-                "bytes_ratio",
-            )
-        }
-        annotations = {str(t.phase): cost for t in timings}
-
     n_ev = traceview.write_trace(
-        args.out, rec, phase_timings=timings,
-        step_seconds=args.step_seconds,
-        annotations=annotations,
+        args.out, rec, step_seconds=args.step_seconds
     )
     print(f"wrote {args.out} ({n_ev} trace events) — open at "
           f"https://ui.perfetto.dev")

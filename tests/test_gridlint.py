@@ -934,8 +934,8 @@ def test_g010_quiet_with_span_even_in_nested_body(tmp_path):
 
 def test_g010_repo_gate_marked_hot_paths_all_carry_spans():
     # every fastpath-engine/resident-path-marked function in the
-    # package names at least one profiler scope — the knockout and
-    # ProfilerSession attribution surface has no blind spots
+    # package names at least one profiler scope — the ProfilerSession
+    # trace's layer attribution has no blind spots
     findings = run_gridlint([PACKAGE], root=REPO_ROOT, rules=["G010"])
     assert findings == [], findings
 

@@ -1,6 +1,6 @@
-"""Telemetry package: recorder, report math, phase attribution, regress
-gate. All CPU-runnable (tier 1); device work uses the 8 virtual CPU
-devices from conftest.py."""
+"""Telemetry package: recorder, report math, profiler sessions, trace
+export, regress gate. All CPU-runnable (tier 1); device work uses the 8
+virtual CPU devices from conftest.py."""
 
 import json
 
@@ -11,14 +11,17 @@ from mpi_grid_redistribute_tpu.parallel.exchange import RedistributeStats
 from mpi_grid_redistribute_tpu.parallel.migrate import MigrateStats
 from mpi_grid_redistribute_tpu.telemetry import (
     StepRecorder,
-    attribute_phases,
     check_capture,
     exchange_report,
     extract_metrics,
-    format_phase_table,
     min_of_k,
     record_migrate_steps,
     row_bytes_of,
+)
+from mpi_grid_redistribute_tpu.telemetry import metrics, traceview
+from mpi_grid_redistribute_tpu.telemetry.profiler import (
+    PROFILE_DIR_ENV,
+    ProfilerSession,
 )
 from mpi_grid_redistribute_tpu.telemetry.report import format_report
 from mpi_grid_redistribute_tpu.utils import profiling
@@ -231,47 +234,128 @@ def test_exchange_report_migrate_stats():
     assert rep["moved_bytes_per_step"] == rep["exchange_bytes_per_step"]
 
 
-# ------------------------------------------------------- phase attribution
+# -------------------------------------------------- profiler sessions
 
 
-def test_attribute_phases_orders_and_rooflines():
+def test_profiler_session_disabled_is_a_true_noop(monkeypatch):
+    monkeypatch.delenv(PROFILE_DIR_ENV, raising=False)
+    rec = StepRecorder()
+    with ProfilerSession(None, recorder=rec) as s:
+        assert not s.enabled
+    assert rec.events("profile_session") == []
+
+
+def test_profiler_session_env_knob_arms_it(tmp_path, monkeypatch):
+    calls = []
     import jax
-    import jax.numpy as jnp
-    from jax import lax
 
-    # phase tokens = number of extra multiply passes; cumulative time
-    # must be returned per phase with deltas and roofline columns filled
-    def loop_builder(phase, S):
-        @jax.jit
-        def loop(x):
-            def body(c, _):
-                for _i in range(phase):
-                    c = c * 1.000001 + 1e-9
-                return c, ()
-
-            c, _ = lax.scan(body, x, None, length=S)
-            return c
-
-        return loop
-
-    x = jnp.ones((64, 64), jnp.float32)
-    pb = {1: 1000, 2: 2000}
-    rows = attribute_phases(
-        loop_builder, (x,), [1, 2], s1=2, s2=6, reps=1, phase_bytes=pb
+    monkeypatch.setattr(
+        jax.profiler, "start_trace", lambda d: calls.append(("start", d))
     )
-    assert [r.phase for r in rows] == [1, 2]
-    assert rows[0].delta_s == rows[0].cumulative_s
-    assert rows[1].delta_s == pytest.approx(
-        rows[1].cumulative_s - rows[0].cumulative_s
+    monkeypatch.setattr(
+        jax.profiler, "stop_trace", lambda: calls.append(("stop",))
     )
-    assert rows[0].logical_bytes == 1000
-    assert rows[0].roofline_s == pytest.approx(
-        1000 / profiling.HBM_PEAK_BYTES_PER_SEC
+    monkeypatch.setenv(PROFILE_DIR_ENV, str(tmp_path))
+    rec = StepRecorder()
+    with ProfilerSession(recorder=rec, label="knob") as s:
+        assert s.enabled and s.armed
+    assert calls == [("start", str(tmp_path)), ("stop",)]
+    (ev,) = rec.events("profile_session")
+    assert ev.data["trace_dir"] == str(tmp_path)
+    assert ev.data["label"] == "knob"
+    assert ev.data["armed"] is True
+    assert ev.data["error"] is None
+    assert ev.data["duration_s"] >= 0.0
+    # the metrics plane counts the session
+    text = metrics.from_journal(rec).render_openmetrics()
+    assert "grid_profile_sessions_total 1" in text
+
+
+def test_profiler_session_broken_profiler_degrades(tmp_path, monkeypatch):
+    import jax
+
+    def _boom(d):
+        raise RuntimeError("profiler says no")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", _boom)
+    rec = StepRecorder()
+    with ProfilerSession(str(tmp_path), recorder=rec):
+        pass  # must not raise
+    (ev,) = rec.events("profile_session")
+    assert ev.data["armed"] is False
+    assert "RuntimeError" in ev.data["error"]
+
+
+HOST_SPANS = ("host:snapshot", "host:snapshot_write", "host:journal_drain",
+              "host:input_check", "host:to_device")
+
+
+@pytest.fixture(scope="module")
+def host_span_names(tmp_path_factory):
+    """Names of the host spans in one profiler trace of a tiny service
+    run that snapshots and drains its journal, plus one redistribute
+    call through the jax engine."""
+    from jax.profiler import ProfileData
+
+    from mpi_grid_redistribute_tpu import api
+    from mpi_grid_redistribute_tpu.domain import ProcessGrid
+    from mpi_grid_redistribute_tpu.service import DriverConfig, ServiceDriver
+
+    root = tmp_path_factory.mktemp("host_spans")
+    cfg = DriverConfig(
+        grid_shape=(2, 2, 2), n_local=64, steps=4, seed=7,
+        backend="numpy", snapshot_every=2,
+        snapshot_dir=str(root / "snaps"), store_dir=str(root / "store"),
     )
-    table = format_phase_table(rows)
-    assert table.splitlines()[0].startswith("| phase (cumulative)")
-    assert len(table.splitlines()) == 2 + len(rows)
-    assert "(first)" in table.splitlines()[2]
+    rd = api.GridRedistribute(
+        grid=ProcessGrid((2, 2, 2)), lo=(0.0,) * 3, hi=(1.0,) * 3,
+        periodic=(True,) * 3,
+    )
+    rng = np.random.default_rng(3)
+    pos = rng.random((8 * 32, 3), dtype=np.float32)
+    ids = np.arange(8 * 32, dtype=np.int32)
+    rd.redistribute(pos, ids)  # compiles outside the trace
+    trace_dir = str(root / "trace")
+    with ProfilerSession(trace_dir, recorder=StepRecorder()) as sess:
+        ServiceDriver(cfg).run()
+        rd.redistribute(pos, ids)
+    assert sess.armed, sess.error
+    (path,) = list((root / "trace").rglob("*.xplane.pb"))
+    data = ProfileData.from_file(str(path))
+    return {
+        ev.name
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+
+
+@pytest.mark.parametrize("name", HOST_SPANS)
+def test_host_spans_land_in_the_profiler_trace(host_span_names, name):
+    assert name in host_span_names
+
+
+# ------------------------------------------------------------- traceview
+
+
+def test_traceview_instant_args_are_json_safe():
+    """Each journal event's payload rides in its instant's ``args``
+    (values JSON cannot hold become strings), and the document holds
+    the journal and counter lanes only."""
+    rec = StepRecorder()
+    rec.record("capacity_grow", old=64, new=128, which=("send", 3))
+    rec.record("profile_session", trace_dir="/tmp/x", label="s",
+               duration_s=0.1, armed=True, error=None)
+    doc = traceview.to_chrome_trace(rec)
+    inst = {e["name"]: e["args"] for e in doc["traceEvents"]
+            if e["ph"] == "i"}
+    assert inst["capacity_grow"]["new"] == 128
+    assert inst["capacity_grow"]["which"] == str(("send", 3))
+    assert inst["profile_session"]["armed"] is True
+    assert inst["profile_session"]["error"] is None
+    assert {e["pid"] for e in doc["traceEvents"]} == {0, 2}
+    assert not any(e["ph"] == "X" for e in doc["traceEvents"])
+    json.dumps(doc)  # stays serializable
 
 
 # ----------------------------------------------------------------- regress
