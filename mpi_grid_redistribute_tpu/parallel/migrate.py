@@ -455,12 +455,12 @@ def _stack_push_pop(free_stack, n_free, n_pop, n_push, vacated, n_in):
     read-modify-write of one contiguous window (never a scatter).
 
     ``vacated`` has static length P; the window is ``min(P, n)`` entries
-    whose start is clamped in bounds. Returns ``(free_stack, n_free)``.
+    whose start is clamped in bounds, so the update costs O(P) however
+    many slots the stack holds. Returns ``(free_stack, n_free)``.
 
-    Used by the vmapped vranks landing only: :func:`_land_arrivals` (and
-    the two-phase landing it feeds, ISSUE 12) now inlines the equivalent
-    full-width where-blend into the landing kernel itself, sharing the
-    plan quantities the scatter already materialized.
+    Shared by every landing of a sequential step: the flat engine's
+    :func:`_land_arrivals` and the vmapped vranks landings (dense and
+    mover-sparse).
     """
     n = free_stack.shape[0]
     P = vacated.shape[0]
@@ -508,73 +508,68 @@ def _land_arrivals(
     hole markers and the alive row together. Returns
     ``(fused, free_stack, n_free, n_in, dropped_recv)``.
     """
-    n = fused.shape[1]
-    C = capacity
-    n_dest = send_counts.shape[0]
-    n_src = recv_counts.shape[0]
-    P = max(n_src, n_dest) * C  # write-plan length
-    n_sent = jnp.sum(send_counts).astype(jnp.int32)
-    n_in = jnp.sum(recv_counts).astype(jnp.int32)
+    with traced_span("mig:unpack"):
+        n = fused.shape[1]
+        C = capacity
+        n_dest = send_counts.shape[0]
+        n_src = recv_counts.shape[0]
+        P = max(n_src, n_dest) * C  # write-plan length
+        n_sent = jnp.sum(send_counts).astype(jnp.int32)
+        n_in = jnp.sum(recv_counts).astype(jnp.int32)
 
-    cum_send = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(send_counts)]
-    )
-    cum_recv = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(recv_counts)]
-    )
-    k_idx = jnp.arange(P, dtype=jnp.int32)
-    d_of_k = _segment_of_auto(k_idx, cum_send)
-    vacated = gather_idx[
-        jnp.clip(d_of_k * C + (k_idx - cum_send[d_of_k]), 0, n_dest * C - 1)
-    ]  # first n_sent entries: vacated slot ids
-    s_of_k = _segment_of_auto(k_idx, cum_recv)
-    arrivals = jnp.take(
-        recv,
-        jnp.clip(s_of_k * C + (k_idx - cum_recv[s_of_k]), 0, n_src * C - 1),
-        axis=1,
-    )  # first n_in columns: real arrivals (alive row already 1)
+        cum_send = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(send_counts)]
+        )
+        cum_recv = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(recv_counts)]
+        )
+        k_idx = jnp.arange(P, dtype=jnp.int32)
+        d_of_k = _segment_of_auto(k_idx, cum_send)
+        vacated = gather_idx[
+            jnp.clip(
+                d_of_k * C + (k_idx - cum_send[d_of_k]), 0, n_dest * C - 1
+            )
+        ]  # first n_sent entries: vacated slot ids
+        s_of_k = _segment_of_auto(k_idx, cum_recv)
+        arrivals = jnp.take(
+            recv,
+            jnp.clip(
+                s_of_k * C + (k_idx - cum_recv[s_of_k]), 0, n_src * C - 1
+            ),
+            axis=1,
+        )  # first n_in columns: real arrivals (alive row already 1)
 
-    # Write plan for slot j in [P]:
-    #   j < min(n_in, n_sent): arrival j -> vacated[j]
-    #   n_sent <= j < n_in:    arrival j -> popped free slot
-    #   n_in <= j < n_sent:    hole marker -> vacated[j]
-    # Receiver overflow: arrivals beyond n_sent + n_free drop (counted).
-    n_pop = jnp.clip(n_in - n_sent, 0, n_free)
-    dropped_recv = jnp.maximum(n_in - n_sent - n_free, 0).astype(jnp.int32)
-    pop_idx = jnp.clip(n_free - 1 - (k_idx - n_sent), 0, n - 1)
-    target = jnp.where(
-        k_idx < jnp.minimum(n_in, n_sent),
-        vacated,
-        jnp.where(
-            (k_idx >= n_sent) & (k_idx < n_sent + n_pop),
-            free_stack[pop_idx],
-            jnp.where((k_idx >= n_in) & (k_idx < n_sent), vacated, n),
-        ),
-    )
-    cols = jnp.where((k_idx < n_in)[None, :], arrivals, 0)
-    # THE scatter: payload + alive flag + hole markers in one pass.
-    fused = _land_scatter(fused, target, cols, scatter_impl)
+        # Write plan for slot j in [P]:
+        #   j < min(n_in, n_sent): arrival j -> vacated[j]
+        #   n_sent <= j < n_in:    arrival j -> popped free slot
+        #   n_in <= j < n_sent:    hole marker -> vacated[j]
+        # Receiver overflow: arrivals beyond n_sent + n_free drop (counted).
+        n_pop = jnp.clip(n_in - n_sent, 0, n_free)
+        dropped_recv = jnp.maximum(n_in - n_sent - n_free, 0).astype(
+            jnp.int32
+        )
+        pop_idx = jnp.clip(n_free - 1 - (k_idx - n_sent), 0, n - 1)
+        target = jnp.where(
+            k_idx < jnp.minimum(n_in, n_sent),
+            vacated,
+            jnp.where(
+                (k_idx >= n_sent) & (k_idx < n_sent + n_pop),
+                free_stack[pop_idx],
+                jnp.where((k_idx >= n_in) & (k_idx < n_sent), vacated, n),
+            ),
+        )
+        cols = jnp.where((k_idx < n_in)[None, :], arrivals, 0)
+        # THE scatter: payload + alive flag + hole markers in one pass.
+        fused = _land_scatter(fused, target, cols, scatter_impl)
 
-    # Free-stack update FUSED into the landing kernel (ISSUE 12): net
-    # excess departures (n_sent - n_in when positive) were written as
-    # holes at vacated[n_in : n_sent]; push them with a full-width
-    # where-blend over the SAME plan quantities the scatter just
-    # consumed (k-window arithmetic, ``vacated``) instead of the old
-    # separate :func:`_stack_push_pop` windowed read-modify-write pass —
-    # one fewer dynamic_slice/dynamic_update_slice pair per step, and
-    # XLA fuses the blend into the landing fusion. ``n_pop`` and
-    # ``n_push`` are mutually exclusive (one is the positive part of
-    # ``n_in - n_sent``, the other of its negation), so the push base
-    # ``n_free - n_pop`` equals ``n_free`` whenever pushes exist —
-    # bit-identical stack contents to the windowed update.
-    n_push = jnp.maximum(n_sent - n_in, 0)
-    base = n_free - n_pop
-    s_idx = jnp.arange(n, dtype=jnp.int32)
-    push_vals = vacated[jnp.clip(n_in + s_idx - base, 0, P - 1)]
-    free_stack = jnp.where(
-        (s_idx >= base) & (s_idx < base + n_push), push_vals, free_stack
-    )
-    new_n_free = base + n_push
+    # Net excess departures (n_sent - n_in when positive) were written as
+    # holes at vacated[n_in : n_sent]: push them onto the stack. The pops
+    # above read the stack before this update.
+    with traced_span("mig:stack"):
+        n_push = jnp.maximum(n_sent - n_in, 0)
+        free_stack, new_n_free = _stack_push_pop(
+            free_stack, n_free, n_pop, n_push, vacated, n_in
+        )
     return fused, free_stack, new_n_free, n_in, dropped_recv
 
 
@@ -723,15 +718,15 @@ def shard_migrate_fused_fn(
         )
 
     def complete(state: MigrateState, inflight: InflightExchange):
-        """COMPLETE half (ISSUE 12): land the exchanged rows (free-stack
-        update fused into the landing kernel) and assemble stats."""
+        """COMPLETE half (ISSUE 12): land the exchanged rows (scatter
+        under ``mig:unpack``, free-stack update under ``mig:stack``) and
+        assemble stats."""
         fused, free_stack, n_free = state
-        with traced_span("mig:unpack"):
-            fused, free_stack, n_free, n_in, dropped_recv = _land_arrivals(
-                fused, free_stack, n_free, inflight.recv,
-                inflight.recv_counts, inflight.send_counts,
-                inflight.gather_idx, C, impl,
-            )
+        fused, free_stack, n_free, n_in, dropped_recv = _land_arrivals(
+            fused, free_stack, n_free, inflight.recv,
+            inflight.recv_counts, inflight.send_counts,
+            inflight.gather_idx, C, impl,
+        )
         with traced_span("mig:stack"):
             population = jnp.sum((fused[-1, :] > 0).astype(jnp.int32))
             stats = MigrateStats(
@@ -916,7 +911,9 @@ def vrank_exchange_two_phase_fn(
         fused = _land_scatter(
             fused, gtarget.reshape(-1), cols.reshape(Kx, V * n), impl
         )
-        # free-stack update fused into the landing (see _land_arrivals)
+        # free-stack pushes as a full-width blend over the same plan:
+        # n_pop and n_push are never both non-zero, so the push base
+        # n_free - n_pop is n_free whenever pushes exist
         n_push = jnp.maximum(n_sent - n_in, 0)
         base = n_free - n_pop
         s_idx = jnp.arange(n, dtype=jnp.int32)[None, :]

@@ -50,29 +50,30 @@ def _np_drift_reference(domain, grid, pos, vel, alive, dt, n_steps):
     return shard_sets
 
 
-@pytest.mark.parametrize("shape", [(2, 2, 2), (4, 2, 1)])
-def test_migrate_matches_reference_sets(shape, rng, _devices):
+def _check_flat_loop(rng, shape, n_local, capacity, dt, vel_scale,
+                     dead_share, n_steps):
+    """Run the flat engine's drift loop from a legal random start and
+    hold it to the NumPy reference: conservation, no backlog or drops,
+    ownership and the exact per-shard row sets."""
     grid = ProcessGrid(shape)
     R = grid.nranks
     domain = Domain(0.0, 1.0, periodic=True)
-    n_local = 64
     n = R * n_local
     mesh = mesh_lib.make_mesh(grid)
 
     pos = rng.random((n, 3), dtype=np.float32)
-    vel = (0.6 * (rng.random((n, 3), dtype=np.float32) - 0.5)).astype(
+    vel = (vel_scale * (rng.random((n, 3), dtype=np.float32) - 0.5)).astype(
         np.float32
     )
-    # start with some holes: ~1/8 of slots dead
-    alive = rng.random(n) > 0.125
+    # start with some holes
+    alive = rng.random(n) > dead_share
     # place live rows on their owning shard so the starting state is legal
     dest = binning.rank_of_position(pos, domain, grid, xp=np)
     slot_shard = np.repeat(np.arange(R), n_local)
     alive &= dest == slot_shard
 
-    n_steps = 5
     cfg = nbody.DriftConfig(
-        domain=domain, grid=grid, dt=0.07, capacity=n_local, n_local=n_local
+        domain=domain, grid=grid, dt=dt, capacity=capacity, n_local=n_local
     )
     loop = nbody.make_migrate_loop(cfg, mesh, n_steps)
     pos_f, vel_f, alive_f, stats = jax.tree.map(
@@ -81,6 +82,7 @@ def test_migrate_matches_reference_sets(shape, rng, _devices):
     pos_f = nbody.planar_to_rows(pos_f, 3, mesh.size)
     vel_f = nbody.planar_to_rows(vel_f, 3, mesh.size)
 
+    assert stats.sent.sum() > 0
     assert stats.backlog.sum() == 0
     assert stats.dropped_recv.sum() == 0
     assert alive_f.sum() == alive.sum()
@@ -92,12 +94,27 @@ def test_migrate_matches_reference_sets(shape, rng, _devices):
     assert (dest_f[alive_f] == slot_shard[alive_f]).all()
 
     want = _np_drift_reference(
-        domain, grid, pos, vel, alive, np.float32(0.07), n_steps
+        domain, grid, pos, vel, alive, np.float32(dt), n_steps
     )
     for r in range(R):
         sl = slice(r * n_local, (r + 1) * n_local)
         got = _rows_set(pos_f[sl], vel_f[sl], alive_f[sl])
         assert got == want[r], f"shard {r} row set mismatch"
+
+
+@pytest.mark.parametrize("shape", [(2, 2, 2), (4, 2, 1)])
+def test_migrate_matches_reference_sets(shape, rng, _devices):
+    _check_flat_loop(rng, shape, n_local=64, capacity=64, dt=0.07,
+                     vel_scale=0.6, dead_share=0.125, n_steps=5)
+
+
+@pytest.mark.parametrize("capacity", [16, 256])
+def test_migrate_flat_4dev_matches_reference_sets(capacity, rng, _devices):
+    """The flat engine on four devices, one rank each (the four-chip
+    cell's layout): with the write plan (4 * capacity) shorter than the
+    256 slots of a shard and longer."""
+    _check_flat_loop(rng, (2, 2, 1), n_local=256, capacity=capacity,
+                     dt=0.05, vel_scale=0.2, dead_share=0.1, n_steps=6)
 
 
 def test_migrate_step_stats_and_idempotence(rng, _devices):
@@ -738,6 +755,105 @@ def test_stack_push_pop_window_matches_gather(rng):
                     fs_ref[win_start + w] = vacated[idx]
         assert int(nf2) == n_free - n_pop + n_push
         assert np.array_equal(np.asarray(fs2), fs_ref), trial
+
+
+def _blend_stack_reference(free_stack, n_free, vacated, n_in, n_sent):
+    """The flat landing's former free-stack update, a full-width blend
+    over all ``n`` stack entries: entry ``s`` in ``[base, base +
+    n_push)`` takes ``vacated[n_in + s - base]``."""
+    n, P = free_stack.shape[0], vacated.shape[0]
+    n_pop = int(np.clip(n_in - n_sent, 0, n_free))
+    n_push = max(n_sent - n_in, 0)
+    base = n_free - n_pop
+    s_idx = np.arange(n)
+    push_vals = vacated[np.clip(n_in + s_idx - base, 0, P - 1)]
+    fs = np.where(
+        (s_idx >= base) & (s_idx < base + n_push), push_vals, free_stack
+    )
+    return fs, base + n_push
+
+
+# (slots n, capacity C, ranks) and how the sent / received counts and
+# the free count are drawn; P = ranks * C is the write-plan length
+_LAND_CASES = {
+    "push": (64, 4, 4, "push"),
+    "pop": (64, 4, 4, "pop"),
+    "balanced": (64, 4, 4, "balanced"),
+    "clamp": (64, 4, 4, "clamp"),  # n_free near n: window start clamps
+    "p_ge_n": (16, 8, 4, "mixed"),  # P = 32 >= n: window is all n
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LAND_CASES))
+def test_land_arrivals_stack_matches_full_width_blend(case, rng):
+    """The flat landing's windowed stack update (:func:`_stack_push_pop`
+    under ``mig:stack``) leaves the free stack bit-identical to the
+    full-width blend it replaced, on random consistent plans."""
+    import jax.numpy as jnp
+    from mpi_grid_redistribute_tpu.parallel import migrate
+
+    n, C, R, mode = _LAND_CASES[case]
+    P, K = R * C, 4
+    for trial in range(12):
+        # n_sent <= live = n - n_free; n_in <= P; per-pair counts <= C
+        if mode == "clamp":
+            n_free = int(rng.integers(n - P + 1, n - 1))
+        else:
+            n_free = int(rng.integers(0, n - 1))
+        live = n - n_free
+        top = min(live, P)
+        if mode in ("push", "clamp"):  # n_in < n_sent
+            n_sent = int(rng.integers(1, top + 1))
+            n_in = int(rng.integers(0, n_sent))
+        elif mode == "pop":  # n_in > n_sent, drops when past n_free
+            n_sent = int(rng.integers(0, top))
+            n_in = int(rng.integers(n_sent + 1, P + 1))
+        elif mode == "balanced":
+            n_sent = n_in = int(rng.integers(0, top + 1))
+        else:
+            n_sent = int(rng.integers(0, top + 1))
+            n_in = int(rng.integers(0, P + 1))
+
+        def split(total):
+            c = np.zeros(R, np.int32)
+            for _ in range(total):
+                c[rng.choice(np.flatnonzero(c < C))] += 1
+            return c
+
+        send_counts, recv_counts = split(n_sent), split(n_in)
+        alive = np.zeros(n, bool)
+        alive[rng.choice(n, live, replace=False)] = True
+        holes, lives = np.flatnonzero(~alive), np.flatnonzero(alive)
+        free_stack = np.concatenate(
+            [rng.permutation(holes), rng.permutation(lives)]
+        ).astype(np.int32)
+        # each destination's granted prefix: distinct live slots
+        leavers = rng.permutation(lives)[:n_sent]
+        gather_idx = rng.integers(0, n, R * C).astype(np.int32)
+        cum = np.concatenate([[0], np.cumsum(send_counts)])
+        for d in range(R):
+            gather_idx[d * C:d * C + send_counts[d]] = leavers[
+                cum[d]:cum[d + 1]
+            ]
+        vacated = np.zeros(P, np.int32)
+        vacated[:n_sent] = leavers
+        fused = rng.random((K, n), dtype=np.float32)
+        fused[-1] = alive
+        recv = rng.random((K, R * C), dtype=np.float32)
+        recv[-1] = 1.0
+
+        _, fs, nf, n_in_got, dropped = migrate._land_arrivals(
+            jnp.asarray(fused), jnp.asarray(free_stack), jnp.int32(n_free),
+            jnp.asarray(recv), jnp.asarray(recv_counts),
+            jnp.asarray(send_counts), jnp.asarray(gather_idx), C,
+        )
+        fs_ref, nf_ref = _blend_stack_reference(
+            free_stack, n_free, vacated, n_in, n_sent
+        )
+        assert int(n_in_got) == n_in
+        assert int(dropped) == max(n_in - n_sent - n_free, 0)
+        assert int(nf) == nf_ref, (case, trial)
+        assert np.array_equal(np.asarray(fs), fs_ref), (case, trial)
 
 
 def test_sorted_dest_counts_packed_fallback_boundary(rng):

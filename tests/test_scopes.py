@@ -152,9 +152,10 @@ def bare_costly(text):
 # ------------------------------------------------------------ programs
 
 
-def _drift_loop(dev_shape, grid_shape, n_local=512):
+def _drift_loop(dev_shape, grid_shape, n_local=512, capacity=None):
     """The benchmark's drift loop (``drivers/drift_loop.py``) at a tiny
-    size: 90% fill, ~2% of the live rows crossing a face a step."""
+    size: 90% fill, ~2% of the live rows crossing a face a step.
+    ``capacity`` defaults to a quarter of the slots."""
     vshape = tuple(g // d for g, d in zip(grid_shape, dev_shape))
     n_dev = math.prod(dev_shape)
     dgrid = ProcessGrid(dev_shape)
@@ -162,7 +163,7 @@ def _drift_loop(dev_shape, grid_shape, n_local=512):
     R = math.prod(grid_shape)
     cfg = nbody.DriftConfig(
         domain=Domain(0.0, 1.0, periodic=True), grid=dgrid, dt=1.0,
-        capacity=n_local // 4, n_local=n_local,
+        capacity=capacity or n_local // 4, n_local=n_local,
         local_budget=n_local // 2, engine="auto",
     )
     vgrid = ProcessGrid(grid_shape) if math.prod(vshape) > 1 else None
@@ -204,7 +205,9 @@ def _service_chunk(builder):
 
 PROGRAMS = {
     "drift_loop_8v_1dev": lambda: _drift_loop((1, 1, 1), (2, 2, 2)),
-    "drift_loop_4dev": lambda: _drift_loop((2, 2, 1), (2, 2, 1), 2048),
+    # a write plan of 4 * 256 = 1024 entries against 2048 slots a device,
+    # so an op as long as the slots is no plan-sized op (as on the chip)
+    "drift_loop_4dev": lambda: _drift_loop((2, 2, 1), (2, 2, 1), 2048, 256),
     "resident_chunk": lambda: _service_chunk(resident.make_chunk_fn),
     "pipelined_chunk": lambda: _service_chunk(
         pipeline.make_pipelined_chunk_fn
@@ -248,13 +251,10 @@ LANDMARKS = {
     "mig:drift": lambda op, n, f: "add" in f and _in_loop(n),
     # the grant fixpoint's greedy allocation, in every step
     "mig:grant": lambda op, n, f: "_greedy_alloc" in n and _in_loop(n),
-    # one device: _stack_push_pop's vmapped window write (a scatter on
-    # the CPU); four: the flat engine updates the stack inside the
-    # landing, and the count of landed live rows is what stays out of it
-    "mig:stack": lambda op, n, f: _in_loop(n) and (
-        ({op} | f) & {"scatter", "dynamic-update-slice"} and "vmap" in n
-        or {"compare", "convert"} <= f and "vmap" not in n
-    ),
+    # _stack_push_pop's window write: vmapped on one device (a scatter
+    # on the CPU), the flat engine's own on four
+    "mig:stack": lambda op, n, f: _in_loop(n)
+    and bool(({op} | f) & {"scatter", "dynamic-update-slice"}),
 }
 LAYOUTS = ("drift_loop_8v_1dev", "drift_loop_4dev")
 METRIC_SCOPES = {"mig:select", "mig:pack", "mig:exchange", "mig:unpack",
@@ -273,3 +273,38 @@ def test_each_drift_loop_scope_holds_its_landmark(scope, layout):
     # a new scope holds no op of the scopes the metrics already read
     for _, _, n, _ in under:
         assert not METRIC_SCOPES & set(n.split("/")), n
+
+
+_SHAPE = re.compile(r"^[a-z]\w*\[([\d,]*)\]")
+
+
+def _gather_lengths(text, scopes):
+    """``(name, op_name, dims)`` of every gather the program runs under
+    one of ``scopes``, standalone or inside a fusion, with the dims of
+    the gather's own output."""
+    comps, _ = _computations(text)
+    line = {nm: rest for body in comps.values() for nm, _, rest in body}
+    out = []
+    for name, op, op_name, _ in executed_instructions(text):
+        if not set(scopes) & set(op_name.split("/")):
+            continue
+        fused = _FUSED.search(line[name])
+        if fused:
+            rests = [r for _, o, r in comps.get(fused.group(1), [])
+                     if o == "gather"]
+        else:
+            rests = [line[name]] if op == "gather" else []
+        for r in rests:
+            dims = _SHAPE.match(r).group(1).split(",")
+            out.append((name, op_name, tuple(int(d) for d in dims if d)))
+    return out
+
+
+def test_four_device_landing_has_no_slot_long_gather():
+    """The flat engine's landing and stack update gather over the write
+    plan, never over every slot: no gather under ``mig:unpack`` or
+    ``mig:stack`` has an output as long as the device's 2048 slots."""
+    text = hlo("drift_loop_4dev")
+    gathers = _gather_lengths(text, ("mig:unpack", "mig:stack"))
+    assert gathers, "the landing's plan gathers are missing"
+    assert [g for g in gathers if 2048 in g[2]] == []
