@@ -21,9 +21,10 @@ G002      jit-boundary hygiene: no ``.item()``, ``jax.device_get``,
 G003      dynamic-shape escapes: ``jnp.nonzero``/``jnp.unique``/
           ``jnp.argwhere``/``jnp.flatnonzero`` and 1-arg ``jnp.where``
           without ``size=``, and boolean-mask indexing, in jitted code.
-G004      planar-engine 32-bit row contract: ``fuse_fields`` /
+G004      planar-engine 32-bit word contract: ``fuse_fields`` /
           ``_fuse_planar`` call sites must be guarded by an
-          ``.itemsize`` check like ``api.py``'s ``_planar_specs``.
+          ``.itemsize`` check like ``api.py``'s ``_planar_specs``
+          (4-byte values, or 8-byte ones split into two words).
 G005      Pallas kernel lint: every ``pl.pallas_call`` passes explicit
           ``grid`` and ``BlockSpec``s; kernels using ``pl.program_id``
           must bound-check derived indices.

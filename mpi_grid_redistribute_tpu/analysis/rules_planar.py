@@ -1,12 +1,14 @@
-"""G004 — planar-engine 32-bit row contract.
+"""G004 — planar-engine 32-bit word contract.
 
 The planar halo/exchange engines move rows as fused 32-bit words:
 ``fuse_fields`` packs an (n, k) field block into one ``uint32`` word
 stream via ``lax.bitcast_convert_type``, and the planar one-hot kernels
-scatter those words as half-planes. The whole scheme is only sound for
-4-byte element types — a float64 row silently truncates, an int16 row
-reads past its lane. ``api._planar_specs`` is the canonical guard: it
-refuses the planar path whenever ``dtype.itemsize != 4``.
+scatter those words as half-planes. The scheme is sound for 4-byte
+element types, and for 8-byte ones that the fuse splits into two words
+each (``api._fuse_planar``); a 2-byte row reads past its lane, and an
+8-byte one bitcast as if it were 4 bytes truncates. ``api._planar_specs``
+(through ``api._planar_refusal``) is the canonical guard: it refuses the
+planar path unless every ``dtype.itemsize`` is 4 or 8 (positions 4).
 
 G004 flags:
 
@@ -147,7 +149,8 @@ def check_planar_contract(project: Project) -> List[Finding]:
                         f"{tail}(...) packs rows as 32-bit words but no "
                         f".itemsize check guards this call path; gate it "
                         f"like api._planar_specs (refuse when "
-                        f"dtype.itemsize != 4)",
+                        f"dtype.itemsize not in (4, 8), an 8-byte value "
+                        f"split into two words)",
                         enclosing.qualname if enclosing else "<module>",
                     )
                 )

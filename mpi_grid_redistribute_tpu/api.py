@@ -14,7 +14,11 @@ Global data layout (both backends):
     ``[r*n_local, (r+1)*n_local)``; only the first ``count[r]`` are valid.
   * ``count``: ``[R]`` int32 valid-row counts (``None`` = all rows valid).
   * fields:    any number of ``[R * n_local, ...]`` arrays riding the same
-    permutation (SURVEY.md C7).
+    permutation (SURVEY.md C7). A field wider than 32 bits (``int64``,
+    ``uint64``, ``float64``, ...) rides as its 32-bit words and comes back
+    on the jax backend as ``int32 [R * out_capacity, ..., w]`` words, low
+    word first; :meth:`RedistributeResult.host_field` joins them into the
+    caller's dtype. No field is ever narrowed.
 """
 
 from __future__ import annotations
@@ -37,19 +41,84 @@ from mpi_grid_redistribute_tpu.telemetry import context as context_lib
 from mpi_grid_redistribute_tpu.telemetry import flow as flow_lib
 from mpi_grid_redistribute_tpu.telemetry import health as health_lib
 from mpi_grid_redistribute_tpu.telemetry import metrics as metrics_lib
-from mpi_grid_redistribute_tpu.telemetry.phases import span
+from mpi_grid_redistribute_tpu.telemetry.phases import span, traced_span
 from mpi_grid_redistribute_tpu.telemetry import recorder as telemetry_lib
 from mpi_grid_redistribute_tpu.telemetry import report as report_lib
 from mpi_grid_redistribute_tpu.telemetry import traceview as traceview_lib
 
 
 class RedistributeResult(NamedTuple):
-    """Outcome of one redistribute: padded arrays + counts + stats."""
+    """Outcome of one redistribute: padded arrays + counts + stats.
+
+    ``field_dtypes`` names the dtype each field was passed in. The numpy
+    backend returns every field in that dtype. The jax backend returns a
+    host field wider than 32 bits (``int64`` ids, a ``float64`` column)
+    as its 32-bit words: an ``int32 [R * out_capacity, ..., w]`` device
+    array, ``w = itemsize // 4``, low word first; with ``jax_enable_x64``
+    a device field of 8-byte dtype comes back in its own dtype.
+    :meth:`host_field` gives any field on the host in its caller's dtype.
+    Positions are binned at float32 on both backends (a float64 position
+    array is rounded to float32 first).
+    """
 
     positions: object
     fields: Tuple
     count: object
     stats: object
+    field_dtypes: Tuple[str, ...] = ()
+
+    def host_field(self, i: int) -> np.ndarray:
+        """Field ``i`` as a host NumPy array in the dtype it was passed in,
+        its 32-bit words joined where it rode as words."""
+        out = np.asarray(self.fields[i])
+        if i < len(self.field_dtypes):
+            dtype = np.dtype(self.field_dtypes[i])
+            if out.dtype != dtype:
+                out = join_words(out, dtype)
+        return out
+
+
+def host_words(a):
+    """A host NumPy array wider than 32 bits as its 32-bit words: ``int32
+    [..., itemsize // 4]``, low word first, a view where ``a`` is
+    contiguous and little-endian. Other arrays (device arrays, 32-bit and
+    narrower dtypes) pass unchanged. The jax backend carries such fields
+    this way; :func:`join_words` is the inverse."""
+    if (
+        isinstance(a, np.ndarray)
+        and a.dtype.itemsize > 4
+        and a.dtype.itemsize % 4 == 0
+    ):
+        a = np.ascontiguousarray(a, a.dtype.newbyteorder("<"))
+        return a.view("<i4").reshape(a.shape + (a.dtype.itemsize // 4,))
+    return a
+
+
+def join_words(words, dtype) -> np.ndarray:
+    """Inverse of :func:`host_words`: ``int32 [..., itemsize // 4]`` words
+    of ``dtype`` values, low word first, joined on the host."""
+    dtype = np.dtype(dtype)
+    w = np.ascontiguousarray(np.asarray(words), "<i4")
+    if w.shape[-1:] != (dtype.itemsize // 4,):
+        raise ValueError(
+            f"{dtype} values are {dtype.itemsize // 4} words each; got "
+            f"words of shape {w.shape}"
+        )
+    out = w.view(dtype.newbyteorder("<")).reshape(w.shape[:-1])
+    return out.astype(dtype, copy=False)
+
+
+def _payload_words(positions, fields) -> dict:
+    """32-bit words one row carries, and how many of them come from
+    fields wider than 32 bits (journaled with ``engine_resolved`` and in
+    :meth:`GridRedistribute.report`)."""
+    words = wide = 0
+    for a in (positions,) + tuple(fields):
+        n = -(-math.prod(a.shape[1:]) * np.dtype(a.dtype).itemsize // 4)
+        words += n
+        if np.dtype(a.dtype).itemsize > 4:
+            wide += n
+    return {"payload_words": words, "payload_words_64": wide}
 
 
 def _next_pow2(n: int) -> int:
@@ -106,20 +175,39 @@ class MoverCapacity:
         return True
 
 
+def _planar_refusal(positions, fields) -> Optional[str]:
+    """Why these arrays cannot ride the planar fused state, or ``None``
+    when they can. The state moves rows as int32 words
+    (``migrate.fuse_fields`` semantics): positions must be 32-bit (they
+    are binned as float32), a field 4 or 8 bytes a value — an 8-byte
+    device array (``jax_enable_x64``) is split into its two words inside
+    the program. 8- and 16-bit fields fall back to the row-major engine;
+    host fields wider than 32 bits arrive here already as words
+    (:func:`host_words`)."""
+    if positions.dtype.itemsize != 4:
+        return f"positions are {positions.dtype} (planar bins 32-bit)"
+    for i, a in enumerate(fields):
+        size = a.dtype.itemsize
+        if size not in (4, 8) or (size == 8 and a.dtype.kind not in "iuf"):
+            return f"field {i} is {a.dtype} ({size}-byte)"
+    return None
+
+
 def _planar_specs(positions, fields):
-    """Per-array (trailing_shape, dtype, n_rows) specs for the planar
-    engines, or ``None`` when any array is not 32-bit (the planar fused
-    state bitcasts everything to int32 rows — ``migrate.fuse_fields``
-    semantics; 8/16/64-bit fields fall back to the row-major engine)."""
-    specs = []
-    for a in (positions,) + tuple(fields):
-        if a.dtype.itemsize != 4:
-            return None
-        k = 1
-        for s in a.shape[1:]:
-            k *= int(s)
-        specs.append((tuple(a.shape[1:]), np.dtype(a.dtype), k))
-    return tuple(specs)
+    """Per-array ``(trailing_shape, dtype, words)`` specs for the planar
+    engines — ``words`` is the int32 rows the array takes in the fused
+    state, two per value for an 8-byte dtype — or ``None`` when
+    :func:`_planar_refusal` refuses the arrays."""
+    if _planar_refusal(positions, fields) is not None:
+        return None
+    return tuple(
+        (
+            tuple(a.shape[1:]),
+            np.dtype(a.dtype),
+            math.prod(a.shape[1:]) * (a.dtype.itemsize // 4),
+        )
+        for a in (positions,) + tuple(fields)
+    )
 
 
 def _fuse_planar(positions, fields, R: int, n_local: int, specs,
@@ -130,7 +218,8 @@ def _fuse_planar(positions, fields, R: int, n_local: int, specs,
     ``[K, R*n]`` lane-sharded (mesh engine). One gather per call at the
     API boundary (~3.2 ms per transpose pair at 8.4M rows, measured —
     scripts/microbench_layout.py); inside the engine no narrow-minor
-    ``[n, 3]`` buffer ever exists.
+    ``[n, 3]`` buffer ever exists. An 8-byte array's values split into
+    two int32 rows each, low word first (``lax.bitcast_convert_type``).
 
     The fused matrix is built INT32 (everything bitcast): TPU float
     vector copies flush denormal f32 bit patterns — any bitcast int32
@@ -142,9 +231,14 @@ def _fuse_planar(positions, fields, R: int, n_local: int, specs,
     """
     parts = []
     for a, (_, dtype, k) in zip((positions,) + tuple(fields), specs):
-        flat = jnp.asarray(a).reshape(R, n_local, k)
-        if flat.dtype != jnp.int32:
+        if dtype.itemsize == 8:
+            flat = jnp.asarray(a).reshape(R, n_local, k // 2)
             flat = jax.lax.bitcast_convert_type(flat, jnp.int32)
+            flat = flat.reshape(R, n_local, k)
+        else:
+            flat = jnp.asarray(a).reshape(R, n_local, k)
+            if flat.dtype != jnp.int32:
+                flat = jax.lax.bitcast_convert_type(flat, jnp.int32)
         parts.append(jnp.transpose(flat, (0, 2, 1)))  # [R, k, n]
     fused = jnp.concatenate(parts, axis=1)  # [R, K, n] int32
     if not stacked:
@@ -162,7 +256,10 @@ def _unfuse_planar(fused, specs, R: int, out_cap: int, stacked: bool):
     row = 0
     for shape, dtype, k in specs:
         block = jnp.transpose(fused[:, row : row + k, :], (0, 2, 1))
-        if dtype != np.dtype(np.int32):
+        if dtype.itemsize == 8:
+            block = block.reshape(R, out_cap, k // 2, 2)
+            block = jax.lax.bitcast_convert_type(block, dtype)
+        elif dtype != np.dtype(np.int32):
             block = jax.lax.bitcast_convert_type(block, dtype)
         outs.append(block.reshape((R * out_cap,) + tuple(shape)))
         row += k
@@ -206,28 +303,34 @@ def _zero_overflow_counters():
     }
 
 
+def _fused_call(engine, specs, R: int, out_cap: int, stacked: bool):
+    """One jitted program: boundary fuse (``rd:fuse``) -> ``engine`` ->
+    boundary unfuse (``rd:unfuse``), a single dispatch per call."""
+
+    def call(positions, count, *fields):
+        n_local = positions.shape[0] // R
+        with traced_span("rd:fuse"):
+            fused = _fuse_planar(positions, fields, R, n_local, specs,
+                                 stacked=stacked)
+        out, new_count, stats = engine(fused, count)
+        with traced_span("rd:unfuse"):
+            pos_out, fields_out = _unfuse_planar(out, specs, R, out_cap,
+                                                 stacked=stacked)
+        return pos_out, new_count, fields_out, stats
+
+    return jax.jit(call)
+
+
 @functools.lru_cache(maxsize=64)
 def _build_planar_vranks_call(
     domain: Domain, grid: ProcessGrid, cap: int, out_cap: int, specs,
     edges=None,
 ):
-    """One jitted program: boundary fuse -> planar vrank exchange ->
-    boundary unfuse (single dispatch per call)."""
-    V = grid.nranks
+    """The planar vrank exchange under the boundary fuse/unfuse."""
     engine = exchange.vrank_redistribute_planar_fn(
         domain, grid, cap, out_cap, domain.ndim, edges=edges
     )
-
-    def call(positions, count, *fields):
-        n_local = positions.shape[0] // V
-        fused = _fuse_planar(positions, fields, V, n_local, specs,
-                             stacked=True)
-        out, new_count, stats = engine(fused, count)
-        pos_out, fields_out = _unfuse_planar(out, specs, V, out_cap,
-                                             stacked=True)
-        return pos_out, new_count, fields_out, stats
-
-    return jax.jit(call)
+    return _fused_call(engine, specs, grid.nranks, out_cap, stacked=True)
 
 
 @functools.lru_cache(maxsize=64)
@@ -235,23 +338,11 @@ def _build_planar_mesh_call(
     mesh, domain: Domain, grid: ProcessGrid, cap: int, out_cap: int, specs,
     edges=None,
 ):
-    """One jitted program: boundary fuse -> shard_map planar exchange ->
-    boundary unfuse (single dispatch per call)."""
-    R = grid.nranks
+    """The shard_map planar exchange under the boundary fuse/unfuse."""
     sharded = exchange.shard_redistribute_planar_sharded(
         mesh, domain, grid, cap, out_cap, domain.ndim, edges=edges
     )
-
-    def call(positions, count, *fields):
-        n_local = positions.shape[0] // R
-        fused = _fuse_planar(positions, fields, R, n_local, specs,
-                             stacked=False)
-        out, new_count, stats = sharded(fused, count)
-        pos_out, fields_out = _unfuse_planar(out, specs, R, out_cap,
-                                             stacked=False)
-        return pos_out, new_count, fields_out, stats
-
-    return jax.jit(call)
+    return _fused_call(sharded, specs, grid.nranks, out_cap, stacked=False)
 
 
 @functools.lru_cache(maxsize=64)
@@ -259,9 +350,8 @@ def _build_count_driven_vranks_call(
     domain: Domain, grid: ProcessGrid, cap: int, out_cap: int,
     mover_cap: int, eng: str, specs, edges=None,
 ):
-    """One jitted program: boundary fuse -> count-driven (sparse/neighbor)
-    vrank exchange -> boundary unfuse (single dispatch per call)."""
-    V = grid.nranks
+    """The count-driven (sparse/neighbor) vrank exchange under the
+    boundary fuse/unfuse."""
     builder = (
         exchange.vrank_redistribute_sparse_fn
         if eng == "sparse"
@@ -270,17 +360,7 @@ def _build_count_driven_vranks_call(
     engine = builder(
         domain, grid, cap, out_cap, mover_cap, domain.ndim, edges=edges
     )
-
-    def call(positions, count, *fields):
-        n_local = positions.shape[0] // V
-        fused = _fuse_planar(positions, fields, V, n_local, specs,
-                             stacked=True)
-        out, new_count, stats = engine(fused, count)
-        pos_out, fields_out = _unfuse_planar(out, specs, V, out_cap,
-                                             stacked=True)
-        return pos_out, new_count, fields_out, stats
-
-    return jax.jit(call)
+    return _fused_call(engine, specs, grid.nranks, out_cap, stacked=True)
 
 
 @functools.lru_cache(maxsize=64)
@@ -288,24 +368,13 @@ def _build_count_driven_mesh_call(
     mesh, domain: Domain, grid: ProcessGrid, cap: int, out_cap: int,
     mover_cap: int, eng: str, specs, edges=None,
 ):
-    """One jitted program: boundary fuse -> shard_map count-driven
-    (sparse/neighbor) exchange -> boundary unfuse."""
-    R = grid.nranks
+    """The shard_map count-driven (sparse/neighbor) exchange under the
+    boundary fuse/unfuse."""
     sharded = exchange.shard_redistribute_count_driven_sharded(
         mesh, domain, grid, cap, out_cap, mover_cap, domain.ndim,
         edges=edges, engine=eng,
     )
-
-    def call(positions, count, *fields):
-        n_local = positions.shape[0] // R
-        fused = _fuse_planar(positions, fields, R, n_local, specs,
-                             stacked=False)
-        out, new_count, stats = sharded(fused, count)
-        pos_out, fields_out = _unfuse_planar(out, specs, R, out_cap,
-                                             stacked=False)
-        return pos_out, new_count, fields_out, stats
-
-    return jax.jit(call)
+    return _fused_call(sharded, specs, grid.nranks, out_cap, stacked=False)
 
 
 @functools.lru_cache(maxsize=64)
@@ -313,24 +382,13 @@ def _build_hierarchical_vranks_call(
     domain: Domain, grid: ProcessGrid, hier, cap: int, out_cap: int,
     mover_cap: int, cross_cap: int, specs, edges=None,
 ):
-    """One jitted program: boundary fuse -> hierarchical two-level vrank
-    exchange -> boundary unfuse (single dispatch per call)."""
-    V = grid.nranks
+    """The hierarchical two-level vrank exchange under the boundary
+    fuse/unfuse."""
     engine = exchange.vrank_redistribute_hierarchical_fn(
         domain, grid, hier, cap, out_cap, mover_cap, cross_cap,
         domain.ndim, edges=edges,
     )
-
-    def call(positions, count, *fields):
-        n_local = positions.shape[0] // V
-        fused = _fuse_planar(positions, fields, V, n_local, specs,
-                             stacked=True)
-        out, new_count, stats = engine(fused, count)
-        pos_out, fields_out = _unfuse_planar(out, specs, V, out_cap,
-                                             stacked=True)
-        return pos_out, new_count, fields_out, stats
-
-    return jax.jit(call)
+    return _fused_call(engine, specs, grid.nranks, out_cap, stacked=True)
 
 
 @functools.lru_cache(maxsize=64)
@@ -338,14 +396,13 @@ def _build_hierarchical_mesh_call(
     mesh, domain: Domain, grid: ProcessGrid, hier, cap: int, out_cap: int,
     mover_cap: int, cross_cap: int, specs, edges=None,
 ):
-    """One jitted program: boundary fuse -> shard_map hierarchical
-    two-level exchange on the EXPANDED mesh -> boundary unfuse.
+    """The shard_map hierarchical two-level exchange on the EXPANDED mesh
+    under the boundary fuse/unfuse.
 
     ``mesh`` is the instance's FLAT mesh; its device assignment is
     carried into ``hier.build_mesh`` so explicit user meshes keep their
     placement (the interleaved expanded axes preserve row-major flat
     index == grid rank, so the global layout is unchanged)."""
-    R = grid.nranks
     emesh = hier.build_mesh(
         None if mesh is None else list(np.asarray(mesh.devices).flat)
     )
@@ -353,17 +410,7 @@ def _build_hierarchical_mesh_call(
         emesh, domain, grid, hier, cap, out_cap, mover_cap, cross_cap,
         domain.ndim, edges=edges,
     )
-
-    def call(positions, count, *fields):
-        n_local = positions.shape[0] // R
-        fused = _fuse_planar(positions, fields, R, n_local, specs,
-                             stacked=False)
-        out, new_count, stats = sharded(fused, count)
-        pos_out, fields_out = _unfuse_planar(out, specs, R, out_cap,
-                                             stacked=False)
-        return pos_out, new_count, fields_out, stats
-
-    return jax.jit(call)
+    return _fused_call(sharded, specs, grid.nranks, out_cap, stacked=False)
 
 
 @functools.lru_cache(maxsize=64)
@@ -494,15 +541,17 @@ class GridRedistribute:
         (payload-carrying-sort compaction; 2.2x the row-major engine at
         4.2M rows — BENCH_CONFIGS.md config 1): no narrow-minor ``[n, 3]``
         buffer exists anywhere, avoiding TPU's T(8,128) tiled-layout
-        padding (42.7x for ``[n, 3]``). It requires every array to be
-        32-bit (fields ride bitcast to float32 rows).
+        padding (42.7x for ``[n, 3]``). It requires 32-bit positions
+        and fields of 32- or 64-bit values: the rows ride as int32
+        words, an 8-byte value as two (a host field wider than 32 bits
+        arrives as its words, :func:`host_words`).
         ``'sparse'`` is the COUNT-DRIVEN planar engine: the exchange
         pool shrinks from ``[K, R*C]`` to ``[K, R*mover_cap]``, so wire
         cost scales with the movers rather than the capacity
         provisioning; ``'neighbor'`` additionally replaces the dense
         ``all_to_all`` with a static 3x3x3-stencil ``lax.ppermute``
         shift schedule (<= 26 neighbor blocks). Both carry planar's
-        32-bit requirement, guard every step with a globally-agreed
+        word requirement, guard every step with a globally-agreed
         residence predicate, and fall back to the dense planar pool
         bit-identically when any shard's movers overflow ``mover_cap``
         (surfaced in ``stats.fallback``, billed at dense width in
@@ -513,9 +562,10 @@ class GridRedistribute:
         ``'auto'`` picks the hierarchical engine on multi-device
         multi-pod meshes, the count-driven sparse engine on flat
         multi-device meshes, planar on one device (no wire to shrink),
-        and falls back to row-major when the payload is not 32-bit;
+        and falls back to row-major for 8- and 16-bit fields (the
+        journaled reason names the field);
         ``'rowmajor'`` forces the round-2 layout (kept for comparison and
-        for non-32-bit payloads). All produce bit-identical results —
+        for 8- and 16-bit fields). All produce bit-identical results —
         same routing, same Alltoallv receive order, oracle-tested. Every
         routing decision is journaled as ``engine_resolved``.
       mover_cap: per-destination column count of the count-driven wire
@@ -681,6 +731,7 @@ class GridRedistribute:
         self.telemetry = telemetry_lib.StepRecorder()
         self._last_stats = None
         self._last_row_bytes = None
+        self._last_payload = None  # _payload_words of the last call
         # Grid observatory (telemetry/flow.py, health.py): the per-link
         # flow gauge and the always-on rule monitor share this instance's
         # journal. Both are host-side only — folding stats into the
@@ -826,13 +877,14 @@ class GridRedistribute:
         ``(P-1) * pod_size * cross_cap`` fanout pool over ICI, while
         DCN carries only the ``(P-1) * cross_cap`` condensed
         per-destination-pod blocks."""
-        if any(dt.itemsize != 4 for _shape, dt, _k in specs):
+        if any(dt.itemsize not in (4, 8) for _shape, dt, _k in specs):
             # callers hand us _planar_specs output, which already
-            # refused non-4-byte dtypes; re-check because the fused
-            # transport below bitcasts every row to int32 words
+            # refused other dtypes; re-check because the fused transport
+            # below moves every row as int32 words (two for an 8-byte
+            # value)
             raise TypeError(
-                "hierarchical engine requires 32-bit positions and "
-                "fields (planar fused transport)"
+                "hierarchical engine requires 32-bit positions and 32- "
+                "or 64-bit fields (planar fused transport)"
             )
         B = self._mover_cap_for(cap)
         if B >= cap:
@@ -849,6 +901,7 @@ class GridRedistribute:
                         f"dense"
                     ),
                     canonical=True,
+                    **(self._last_payload or {}),
                 )
             return None
         B2 = self._cross_cap_for(cap)
@@ -882,19 +935,17 @@ class GridRedistribute:
     def _check_inputs(self, pos, fields, count):
         R = self.nranks
         # Both backends bin at the same precision: JAX canonicalizes float64
-        # to float32 when x64 is off, and a particle within one float32 ulp
-        # of a cell edge would otherwise land on different ranks per backend,
-        # breaking the advertised bit-level comparability.
+        # positions to float32 when x64 is off, and a particle within one
+        # float32 ulp of a cell edge would otherwise land on different ranks
+        # per backend, breaking the advertised bit-level comparability.
+        # Fields keep their dtype on both backends: the jax backend carries
+        # a field wider than 32 bits as its words (_run_engine).
         if self.backend == "numpy":
             pos = np.asarray(pos)
             pos = pos.astype(
                 jax.dtypes.canonicalize_dtype(pos.dtype), copy=False
             )
             fields = tuple(np.asarray(f) for f in fields)
-            fields = tuple(
-                f.astype(jax.dtypes.canonicalize_dtype(f.dtype), copy=False)
-                for f in fields
-            )
         if pos.ndim != 2 or pos.shape[1] != self.domain.ndim:
             raise ValueError(
                 f"positions must be [R*n_local, {self.domain.ndim}], "
@@ -935,6 +986,7 @@ class GridRedistribute:
     def _run_once(
         self, positions, fields, count, cap: int, out_cap: int
     ) -> RedistributeResult:
+        dtypes = tuple(np.dtype(f.dtype).name for f in fields)
         if self.backend == "numpy":
             pos_out, counts_out, fields_out, stats = (
                 oracle.redistribute_oracle_padded(
@@ -953,27 +1005,36 @@ class GridRedistribute:
                 tuple(fields_out),
                 counts_out,
                 exchange.RedistributeStats(**stats),
+                dtypes,
             )
-        # dispatching the engine moves host inputs to the device
+        # dispatching the engine moves host inputs to the device; a host
+        # field wider than 32 bits goes as its words, never narrowed
         with span("host:to_device"):
-            return self._run_engine(positions, fields, count, cap, out_cap)
+            fields = tuple(host_words(f) for f in fields)
+            fn = self._engine_program(positions, fields, cap, out_cap)
+            pos_out, new_count, fields_out, stats = fn(
+                positions, count, *fields
+            )
+        return RedistributeResult(
+            pos_out, fields_out, new_count, stats, dtypes
+        )
 
-    def _run_engine(
-        self, positions, fields, count, cap: int, out_cap: int
-    ) -> RedistributeResult:
-        specs = None
-        if self.engine in (
-            "auto", "planar", "sparse", "neighbor", "hierarchical"
-        ):
-            specs = _planar_specs(positions, fields)
-            if specs is None and self.engine in (
-                "planar", "sparse", "neighbor", "hierarchical"
-            ):
+    def _engine_program(self, positions, fields, cap: int, out_cap: int):
+        """The single-dispatch program for arrays like these (host fields
+        wider than 32 bits already as words): resolve the engine, journal
+        ``engine_resolved`` when the routing inputs changed, set the
+        scheduled-wire model, build (cached) the program."""
+        specs = why = None
+        if self.engine != "rowmajor":
+            why = _planar_refusal(positions, fields)
+            if why is not None and self.engine != "auto":
                 raise TypeError(
                     f"engine={self.engine!r} requires 32-bit positions and "
-                    "fields (they ride bitcast to float32 rows); cast or "
-                    "use engine='auto'/'rowmajor'"
+                    f"32- or 64-bit fields (they ride as int32 words): "
+                    f"{why}; cast or use engine='auto'/'rowmajor'"
                 )
+            if why is None:
+                specs = _planar_specs(positions, fields)
         # ONE dispatch rule, shared with the migrate loop
         # (exchange.resolve_engine): multi-device 'auto' routes to the
         # count-driven sparse engine (wire cost scales with movers); the
@@ -989,21 +1050,16 @@ class GridRedistribute:
         resolved = exchange.resolve_engine(
             self.engine, vranks=self._vranks, n_devices=n_dev,
             planar_ok=specs is not None, canonical=True,
-            n_pods=self.n_pods, recorder=rec,
+            n_pods=self.n_pods, recorder=rec, planar_why=why,
+            detail=self._last_payload,
         )
         R = self.nranks
         dense_cols = R * cap
         if resolved == "hierarchical" and specs is not None:
             fn = self._hierarchical_fn(cap, out_cap, specs, rec)
-            if fn is None:
-                resolved = "planar"
-            else:
-                pos_out, new_count, fields_out, stats = fn(
-                    positions, count, *fields
-                )
-                return RedistributeResult(
-                    pos_out, fields_out, new_count, stats
-                )
+            if fn is not None:
+                return fn
+            resolved = "planar"
         if resolved in ("sparse", "neighbor") and specs is not None:
             B = self._mover_cap_for(cap)
             if B >= cap:
@@ -1022,6 +1078,7 @@ class GridRedistribute:
                             f"dense"
                         ),
                         canonical=True,
+                        **(self._last_payload or {}),
                     )
                 resolved = "planar"
             else:
@@ -1038,20 +1095,13 @@ class GridRedistribute:
                     "shards": R,
                 }
                 if self._vranks:
-                    fn = _build_count_driven_vranks_call(
+                    return _build_count_driven_vranks_call(
                         self.domain, self.grid, cap, out_cap, B, resolved,
                         specs, edges=self.edges,
                     )
-                else:
-                    fn = _build_count_driven_mesh_call(
-                        self.mesh, self.domain, self.grid, cap, out_cap,
-                        B, resolved, specs, edges=self.edges,
-                    )
-                pos_out, new_count, fields_out, stats = fn(
-                    positions, count, *fields
-                )
-                return RedistributeResult(
-                    pos_out, fields_out, new_count, stats
+                return _build_count_driven_mesh_call(
+                    self.mesh, self.domain, self.grid, cap, out_cap,
+                    B, resolved, specs, edges=self.edges,
                 )
         self._last_wire = {
             "engine": resolved,
@@ -1064,170 +1114,14 @@ class GridRedistribute:
             # (BENCH_CONFIGS.md config 1), bit-identical to the row-major
             # engines and the oracle.
             if self._vranks:
-                fn = _build_planar_vranks_call(
+                return _build_planar_vranks_call(
                     self.domain, self.grid, cap, out_cap, specs,
                     edges=self.edges,
                 )
-            else:
-                fn = _build_planar_mesh_call(
-                    self.mesh, self.domain, self.grid, cap, out_cap, specs,
-                    edges=self.edges,
-                )
-            pos_out, new_count, fields_out, stats = fn(
-                positions, count, *fields
+            return _build_planar_mesh_call(
+                self.mesh, self.domain, self.grid, cap, out_cap, specs,
+                edges=self.edges,
             )
-            return RedistributeResult(pos_out, fields_out, new_count, stats)
-        if self._vranks:
-            R = self.nranks
-            n_local = positions.shape[0] // R
-            fn = exchange.build_redistribute_vranks(
-                self.domain, self.grid, cap, out_cap, self.edges
-            )
-            out = fn(
-                positions.reshape(R, n_local, -1),
-                count,
-                *(f.reshape((R, n_local) + f.shape[1:]) for f in fields),
-            )
-            unstack = lambda a: a.reshape((R * out_cap,) + a.shape[2:])
-            return RedistributeResult(
-                unstack(out[0]),
-                tuple(unstack(f) for f in out[2:-1]),
-                out[1],
-                out[-1],
-            )
-        fn = exchange.build_redistribute(
-            self.mesh, self.domain, self.grid, cap, out_cap, len(fields),
-            self.edges,
-        )
-        out = fn(positions, count, *fields)
-        return RedistributeResult(
-            out[0], tuple(out[2:-1]), out[1], out[-1]
-        )
-
-    def engine_fn(self, positions, *fields):
-        """Hand out the resolved single-dispatch engine program.
-
-        Returns ``(fn, cap, out_cap)`` where
-        ``fn(positions, count, *fields) -> (positions, count, fields,
-        stats)`` is the SAME jitted engine :meth:`redistribute` would
-        dispatch for arrays of these shapes/dtypes — with no per-call
-        Python re-entry: no retry loop, no journal record, no stats
-        read. That makes it safe to invoke once per step inside a
-        ``lax.scan`` (the resident chunked service loop,
-        ``service/resident.py``). The overflow policy moves to the
-        CALLER's chunk boundary: read the scanned stats' drop counters
-        there, grow via :meth:`_grow` (a fresh ``engine_fn`` picks up
-        the grown capacities), and re-run the chunk on its unchanged
-        entry arrays.
-
-        Engine resolution, the ``engine_resolved`` journal event and the
-        scheduled-wire model (``_last_wire``) behave exactly as one
-        :meth:`redistribute` call would, so telemetry stays coherent.
-        """
-        if self.backend != "jax":
-            raise ValueError(
-                "engine_fn requires backend='jax' — the numpy oracle "
-                "has no jitted engine program to hand out"
-            )
-        R = self.nranks
-        if positions.ndim != 2 or positions.shape[0] % R:
-            raise ValueError(
-                f"positions must be [R*n_local, ndim] over {R} ranks, "
-                f"got {positions.shape}"
-            )
-        n_local = positions.shape[0] // R
-        cap, out_cap = self._capacities(n_local)
-        self._last_row_bytes = report_lib.row_bytes_of(positions, *fields)
-        specs = None
-        if self.engine in (
-            "auto", "planar", "sparse", "neighbor", "hierarchical"
-        ):
-            specs = _planar_specs(positions, fields)
-            if specs is None and self.engine in (
-                "planar", "sparse", "neighbor", "hierarchical"
-            ):
-                raise TypeError(
-                    f"engine={self.engine!r} requires 32-bit positions "
-                    "and fields (they ride bitcast to float32 rows); "
-                    "cast or use engine='auto'/'rowmajor'"
-                )
-        n_dev = 1 if self._vranks else int(self.mesh.devices.size)
-        res_key = (self.engine, self._vranks, specs is not None, n_dev)
-        rec = None
-        if res_key != self._last_resolution:
-            self._last_resolution = res_key
-            rec = self.telemetry
-        resolved = exchange.resolve_engine(
-            self.engine, vranks=self._vranks, n_devices=n_dev,
-            planar_ok=specs is not None, canonical=True,
-            n_pods=self.n_pods, recorder=rec,
-        )
-        dense_cols = R * cap
-        if resolved == "hierarchical" and specs is not None:
-            fn = self._hierarchical_fn(cap, out_cap, specs, rec)
-            if fn is not None:
-                return fn, cap, out_cap
-            resolved = "planar"
-        if resolved in ("sparse", "neighbor") and specs is not None:
-            B = self._mover_cap_for(cap)
-            if B >= cap:
-                if rec is None and self._last_wire is not None and (
-                    self._last_wire.get("engine") != "planar"
-                ):
-                    self.telemetry.record(
-                        "engine_resolved",
-                        requested=self.engine,
-                        resolved="planar",
-                        reason=(
-                            f"{resolved}: mover_cap {B} >= capacity "
-                            f"{cap}, count-driven pool no smaller than "
-                            f"dense"
-                        ),
-                        canonical=True,
-                    )
-                resolved = "planar"
-            else:
-                if resolved == "neighbor":
-                    engine_cols = B * _neighbor_active_offsets(
-                        self.grid, tuple(self.domain.periodic)
-                    )
-                else:
-                    engine_cols = R * B
-                self._last_wire = {
-                    "engine": resolved,
-                    "engine_cols": engine_cols,
-                    "dense_cols": dense_cols,
-                    "shards": R,
-                }
-                if self._vranks:
-                    fn = _build_count_driven_vranks_call(
-                        self.domain, self.grid, cap, out_cap, B, resolved,
-                        specs, edges=self.edges,
-                    )
-                else:
-                    fn = _build_count_driven_mesh_call(
-                        self.mesh, self.domain, self.grid, cap, out_cap,
-                        B, resolved, specs, edges=self.edges,
-                    )
-                return fn, cap, out_cap
-        self._last_wire = {
-            "engine": resolved,
-            "engine_cols": dense_cols,
-            "dense_cols": dense_cols,
-            "shards": R,
-        }
-        if resolved == "planar" and specs is not None:
-            if self._vranks:
-                fn = _build_planar_vranks_call(
-                    self.domain, self.grid, cap, out_cap, specs,
-                    edges=self.edges,
-                )
-            else:
-                fn = _build_planar_mesh_call(
-                    self.mesh, self.domain, self.grid, cap, out_cap, specs,
-                    edges=self.edges,
-                )
-            return fn, cap, out_cap
         if self._vranks:
             raw = exchange.build_redistribute_vranks(
                 self.domain, self.grid, cap, out_cap, self.edges
@@ -1252,7 +1146,7 @@ class GridRedistribute:
                     out[-1],
                 )
 
-            return fn, cap, out_cap
+            return fn
         raw = exchange.build_redistribute(
             self.mesh, self.domain, self.grid, cap, out_cap, len(fields),
             self.edges,
@@ -1262,6 +1156,46 @@ class GridRedistribute:
             out = _raw(positions, count, *fields)
             return out[0], out[1], tuple(out[2:-1]), out[-1]
 
+        return fn
+
+    def engine_fn(self, positions, *fields):
+        """Hand out the resolved single-dispatch engine program.
+
+        Returns ``(fn, cap, out_cap)`` where
+        ``fn(positions, count, *fields) -> (positions, count, fields,
+        stats)`` is the SAME jitted engine :meth:`redistribute` would
+        dispatch for arrays of these shapes/dtypes — with no per-call
+        Python re-entry: no retry loop, no journal record, no stats
+        read. That makes it safe to invoke once per step inside a
+        ``lax.scan`` (the resident chunked service loop,
+        ``service/resident.py``). The overflow policy moves to the
+        CALLER's chunk boundary: read the scanned stats' drop counters
+        there, grow via :meth:`_grow` (a fresh ``engine_fn`` picks up
+        the grown capacities), and re-run the chunk on its unchanged
+        entry arrays. A host field wider than 32 bits is resolved as
+        its words, so ``fn`` takes it as :func:`host_words` gives it.
+
+        Engine resolution, the ``engine_resolved`` journal event and the
+        scheduled-wire model (``_last_wire``) behave exactly as one
+        :meth:`redistribute` call would, so telemetry stays coherent.
+        """
+        if self.backend != "jax":
+            raise ValueError(
+                "engine_fn requires backend='jax' — the numpy oracle "
+                "has no jitted engine program to hand out"
+            )
+        R = self.nranks
+        if positions.ndim != 2 or positions.shape[0] % R:
+            raise ValueError(
+                f"positions must be [R*n_local, ndim] over {R} ranks, "
+                f"got {positions.shape}"
+            )
+        n_local = positions.shape[0] // R
+        cap, out_cap = self._capacities(n_local)
+        self._last_row_bytes = report_lib.row_bytes_of(positions, *fields)
+        self._last_payload = _payload_words(positions, fields)
+        fields = tuple(host_words(f) for f in fields)
+        fn = self._engine_program(positions, fields, cap, out_cap)
         return fn, cap, out_cap
 
     def redistribute(self, positions, *fields, count=None) -> RedistributeResult:
@@ -1280,6 +1214,7 @@ class GridRedistribute:
             )
         self._call_index += 1
         self._last_row_bytes = report_lib.row_bytes_of(positions, *fields)
+        self._last_payload = _payload_words(positions, fields)
         # call-scoped step context: every event this call journals
         # (redistribute, capacity_grow, overflow_window_*, alert) carries
         # ctx_call in its envelope, joining it back to this invocation
@@ -1442,7 +1377,10 @@ class GridRedistribute:
         Args:
           positions: ``[R * n_local, ndim]`` in the same global padded
             layout as :meth:`redistribute` (typically its output).
-          *fields: 32-bit per-particle arrays riding along (ids, masses).
+          *fields: per-particle arrays riding along (ids, masses). A
+            host field wider than 32 bits rides as its words and its
+            ghosts come back as ``int32 [..., itemsize // 4]`` words
+            (:func:`join_words` gives the caller's dtype back).
           width: scalar or per-axis halo width in domain units; must not
             exceed the per-axis subdomain width (one-hop shell).
           count: ``[R]`` valid-row counts (e.g. ``result.count``).
@@ -1484,6 +1422,7 @@ class GridRedistribute:
         positions, fields, n_local, count = self._check_inputs(
             positions, fields, count
         )
+        fields = tuple(host_words(f) for f in fields)
         widths = halo_lib._as_per_axis(width, self.domain.ndim)
         dpc, dgc = halo_lib.default_capacities(
             self.domain, self.grid, widths, n_local, headroom
@@ -1558,8 +1497,9 @@ class GridRedistribute:
             specs = _planar_specs(positions, fields)
             if specs is None and self.engine == "planar":
                 raise TypeError(
-                    "engine='planar' requires 32-bit positions and fields "
-                    "(they ride bitcast to int32 rows); cast or use "
+                    "engine='planar' requires 32-bit positions and 32- or "
+                    "64-bit fields (they ride as int32 words): "
+                    f"{_planar_refusal(positions, fields)}; cast or use "
                     "engine='auto'/'rowmajor'"
                 )
         R = self.nranks
@@ -1864,6 +1804,7 @@ class GridRedistribute:
             wire_shards=wire.get("shards"),
         )
         out["engine"] = wire.get("engine", self.engine)
+        out.update(self._last_payload or {})
         if "engine_cols_dcn" in wire:
             # hierarchical two-level dispatch: split the scheduled wire
             # into per-domain bytes — DCN carries only the condensed

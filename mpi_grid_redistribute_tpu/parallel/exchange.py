@@ -50,6 +50,8 @@ def resolve_engine(
     canonical: bool = False,
     n_pods: int = 1,
     recorder=None,
+    planar_why: str = None,
+    detail: dict = None,
 ) -> str:
     """Resolve a user-facing engine name to a concrete engine — the ONE
     dispatch rule shared by :class:`..api.Redistributer` (canonical
@@ -61,7 +63,8 @@ def resolve_engine(
     scales with movers — the paper's Alltoallv rationale) and
     ``"planar"`` on one device (no wire to shrink), degrading to
     ``"rowmajor"`` when the payload does not qualify for planar
-    transport (``planar_ok`` — 32-bit fields that ride bitcast). The
+    transport (``planar_ok`` — fields of 32- or 64-bit values that ride
+    as int32 words; ``planar_why`` names the field that does not). The
     dense pool is reachable only via explicit ``engine="planar"`` or
     the sparse/neighbor engines' in-graph overflow fallback.
     ``"sparse"``/``"neighbor"`` are honored as asked (the neighbor
@@ -81,7 +84,8 @@ def resolve_engine(
 
     ``recorder`` (a :class:`..telemetry.StepRecorder`) journals the
     decision as an ``engine_resolved`` event — chosen engine plus the
-    reason, including any degradation — so silent routing is observable.
+    reason, including any degradation — so silent routing is observable;
+    ``detail`` adds its keys to the event (the api's ``payload_words``).
     """
     if engine not in ENGINES:
         raise ValueError(
@@ -109,6 +113,7 @@ def resolve_engine(
         elif not planar_ok:
             resolved, reason = (
                 "rowmajor", "auto: payload not planar-eligible"
+                + (f" ({planar_why})" if planar_why else "")
             )
         elif n_devices > 1 and n_pods > 1:
             resolved, reason = (
@@ -145,6 +150,7 @@ def resolve_engine(
             resolved=resolved,
             reason=reason,
             canonical=bool(canonical),
+            **(detail or {}),
         )
     return resolved
 
@@ -413,31 +419,40 @@ def vrank_redistribute_planar_fn(
         n = fused.shape[2]
         me_ids = jnp.arange(V, dtype=jnp.int32)
 
-        def pack_one(fi_v, pos_v, count_v, me):
-            iota = jnp.arange(n, dtype=jnp.int32)
-            valid = iota < count_v
-            with traced_span("rd:bin"):
-                dest = binning.rank_of_position_planar(
-                    pos_v, domain, grid, edges=edges
-                )
-                dest = jnp.where(valid, dest, V).astype(jnp.int32)
-                is_self = valid & (dest == me)
-                dest_remote = jnp.where(is_self, V, dest)
-                order, remote_counts, bounds = binning.sorted_dest_counts(
-                    dest_remote, V
-                )
-            dropped_send = jnp.sum(jnp.maximum(remote_counts - C, 0))
-            send_counts = jnp.minimum(remote_counts, C)
-            with traced_span("rd:pack"):
-                packed, _ = pack.pack_cols(
-                    fi_v, order, bounds[:V], send_counts, V, C
-                )  # [K, V*C] int32
-            needed = jnp.max(remote_counts).astype(jnp.int32)
-            return packed, send_counts, is_self, dropped_send, needed
+        def bin_one(pos_v, count_v, me):
+            valid = jnp.arange(n, dtype=jnp.int32) < count_v
+            dest = binning.rank_of_position_planar(
+                pos_v, domain, grid, edges=edges
+            )
+            dest = jnp.where(valid, dest, V).astype(jnp.int32)
+            is_self = valid & (dest == me)
+            dest_remote = jnp.where(is_self, V, dest)
+            order, remote_counts, bounds = binning.sorted_dest_counts(
+                dest_remote, V
+            )
+            return order, remote_counts, bounds, is_self
 
-        packed, send_counts, is_self, dropped_send, needed = jax.vmap(
-            pack_one
-        )(fi, pos_f, count, me_ids)
+        def pack_one(fi_v, order, bounds, send_counts_v):
+            packed, _ = pack.pack_cols(
+                fi_v, order, bounds[:V], send_counts_v, V, C
+            )
+            return packed  # [K, V*C] int32
+
+        # each phase's scope wraps its vmap, so the ops carry the scope
+        # itself and not a vmap(...) of it
+        with traced_span("rd:bin"):
+            order, remote_counts, bounds, is_self = jax.vmap(bin_one)(
+                pos_f, count, me_ids
+            )
+            dropped_send = jnp.sum(jnp.maximum(remote_counts - C, 0), axis=1)
+            send_counts = jnp.minimum(remote_counts, C)
+            needed = jnp.max(remote_counts, axis=1).astype(jnp.int32)
+            recv_counts = send_counts.T  # [V_dst, V_src]
+            self_diag = jnp.diag(jnp.sum(is_self.astype(jnp.int32), axis=1))
+            send_stats = send_counts + self_diag
+            recv_stats = recv_counts + self_diag
+        with traced_span("rd:pack"):
+            packed = jax.vmap(pack_one)(fi, order, bounds, send_counts)
         K = fused.shape[1]
         # the wire, as a transpose: [V_src, K, V_dst, C] -> dst-major pools
         with traced_span("rd:exchange"):
@@ -446,7 +461,6 @@ def vrank_redistribute_planar_fn(
                 .transpose(2, 1, 0, 3)
                 .reshape(V, K, V * C)
             )
-        recv_counts = send_counts.T  # [V_dst, V_src]
 
         def compact_one(pool_v, rcnt_v, me, self_mask_v, fi_v):
             # Alltoallv-order compaction via a payload-carrying sort —
@@ -463,11 +477,9 @@ def vrank_redistribute_planar_fn(
             )
         if as_f32:
             out = lax.bitcast_convert_type(out, jnp.float32)
-        self_count = jnp.sum(is_self.astype(jnp.int32), axis=1)
-        self_diag = jnp.diag(self_count)
         stats = RedistributeStats(
-            send_counts=send_counts + self_diag,
-            recv_counts=recv_counts + self_diag,
+            send_counts=send_stats,
+            recv_counts=recv_stats,
             dropped_send=dropped_send.astype(jnp.int32),
             dropped_recv=dropped_recv,
             needed_capacity=needed,

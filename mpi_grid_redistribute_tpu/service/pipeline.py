@@ -139,9 +139,19 @@ def make_pipelined_chunk_fn(rd, dt, chunk, positions, *fields, unroll=8,
         )
     n_local = positions.shape[0] // R
     cap, out_cap = rd._capacities(n_local)
+    for i, f in enumerate(fields):
+        if f.dtype.itemsize > 4:
+            # the two-phase carry and the sequential chunk body both hold
+            # 32-bit rows; a wider field would be narrowed at the program
+            # boundary, so refuse it here
+            raise TypeError(
+                f"the pipelined service path carries fields of at most 32 "
+                f"bits: field {i} is {f.dtype}"
+            )
     specs = api._planar_specs(positions, fields)
     # G004: the fused planar carry moves rows as 32-bit words — re-assert
-    # the 4-byte contract _planar_specs guarantees at THIS call path too.
+    # the 4-byte contract at THIS call path (_planar_specs also admits
+    # 8-byte values, as two words, which this carry does not).
     planar_ok = (
         specs is not None
         and all(np.dtype(s[1]).itemsize == 4 for s in specs)

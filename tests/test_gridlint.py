@@ -14,6 +14,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from mpi_grid_redistribute_tpu.analysis.baseline import (
     default_baseline_path,
     load_baseline,
@@ -266,21 +268,25 @@ def test_g004_fires_on_unguarded_fuse(tmp_path):
         },
     )
     assert rules_of(findings) == ["G004"], findings
+    # the contract it asks for: 4-byte values, or 8-byte ones as two words
+    assert "itemsize not in (4, 8)" in findings[0].message
 
 
-def test_g004_quiet_when_guard_in_callee_or_caller(tmp_path):
+# a 4-byte guard, and the 4-or-8-byte guard of api._planar_refusal
+@pytest.mark.parametrize("guard", ["!= 4", "not in (4, 8)"])
+def test_g004_quiet_when_guard_in_callee_or_caller(tmp_path, guard):
     findings = lint(
         tmp_path,
         {
-            "mod.py": """
+            "mod.py": f"""
     def fuse_fields(positions, fields):
         # self-guarding fuse (migrate.fuse_fields shape)
-        if positions.dtype.itemsize != 4:
+        if positions.dtype.itemsize {guard}:
             raise TypeError("planar path needs 32-bit rows")
         return positions
 
     def specs_of(a):
-        if a.dtype.itemsize != 4:
+        if a.dtype.itemsize {guard}:
             return None
         return a.shape
 

@@ -11,7 +11,11 @@ CPU mesh and read the same HLO text:
   dynamic-update-slice or custom-call) in the computations the program
   executes carries a ``mig:``, ``rd:``, ``svc:`` or ``pipe:`` scope;
 * each scope of the drift loop holds its landmark op, on one device
-  (2x2x2 vranks) and across four (2x2x1, one rank a device).
+  (2x2x2 vranks) and across four (2x2x1, one rank a device);
+* the one-call planar vrank program that ``GridRedistribute.redistribute``
+  dispatches on one device holds its boundary work under ``rd:fuse`` and
+  ``rd:unfuse`` and every other costly op under the engine's ``rd:``
+  scopes.
 
 Two kinds of instruction are the compiler's, not the program's, and no
 scope can reach them: the loop's own bookkeeping (the trip counter and
@@ -203,8 +207,25 @@ def _service_chunk(builder):
     return macro.lower(pos, vel, ids, count).compile().as_text()
 
 
+def _redistribute_8v(n_local=256):
+    """The one-call planar vrank program for a row of position, velocity
+    and a 64-bit id (3 + 3 + 2 words; the id arrives as its two int32
+    words), 2x2x2 ranks on one device."""
+    R = 8
+    rows = jax.ShapeDtypeStruct((R * n_local, 3), jnp.float32)
+    words = jax.ShapeDtypeStruct((R * n_local, 2), jnp.int32)
+    count = jax.ShapeDtypeStruct((R,), jnp.int32)
+    specs = api._planar_specs(rows, (rows, words))
+    fn = api._build_planar_vranks_call(
+        Domain(0.0, 1.0, periodic=True), ProcessGrid((2, 2, 2)),
+        n_local // 2, 2 * n_local, specs,
+    )
+    return fn.lower(rows, count, rows, words).compile().as_text()
+
+
 PROGRAMS = {
     "drift_loop_8v_1dev": lambda: _drift_loop((1, 1, 1), (2, 2, 2)),
+    "redistribute_planar_8v": _redistribute_8v,
     # a write plan of 4 * 256 = 1024 entries against 2048 slots a device,
     # so an op as long as the slots is no plan-sized op (as on the chip)
     "drift_loop_4dev": lambda: _drift_loop((2, 2, 1), (2, 2, 1), 2048, 256),
@@ -308,3 +329,28 @@ def test_four_device_landing_has_no_slot_long_gather():
     gathers = _gather_lengths(text, ("mig:unpack", "mig:stack"))
     assert gathers, "the landing's plan gathers are missing"
     assert [g for g in gathers if 2048 in g[2]] == []
+
+
+# scope -> predicate on (opcode, op_name, fused_ops) that finds the
+# one-call program's landmark op under it
+RD_LANDMARKS = {
+    # the caller's row-major arrays into the [V, K, n] fused state
+    "rd:fuse": lambda op, n, f: "concatenate" in f,
+    # the destination sort of every rank's rows
+    "rd:bin": lambda op, n, f: op == "sort",
+    # the column gather into the send pool
+    "rd:pack": lambda op, n, f: "gather" in f,
+    # the payload-carrying compaction sort into receive order
+    "rd:unpack": lambda op, n, f: op == "sort",
+    # the fused rows back to row-major outputs, positions as float32
+    "rd:unfuse": lambda op, n, f: "bitcast-convert" in f,
+}
+
+
+@pytest.mark.parametrize("scope", sorted(RD_LANDMARKS))
+def test_each_redistribute_scope_holds_its_landmark(scope):
+    text = hlo("redistribute_planar_8v")
+    under = _ops_under(text, scope)
+    assert any(RD_LANDMARKS[scope](op, n, f) for _, op, n, f in under), under
+    # every scope wraps its phase whole: no op carries it inside a vmap
+    assert f"vmap({scope})" not in text
