@@ -867,7 +867,29 @@ class GridRedistribute:
         )
         return True
 
-    def _hierarchical_fn(self, cap: int, out_cap: int, specs, rec):
+    def _journal_planar_degrade(self, eng: str, B: int, cap: int, rec,
+                                detail: dict) -> None:
+        """Journal a count-driven or hierarchical engine falling to the
+        dense planar pool because the grown mover block ``B`` reached
+        the capacity — once, when it changes the engine of the last
+        call and no resolution was journaled for this one."""
+        if rec is None and self._last_wire is not None and (
+            self._last_wire.get("engine") != "planar"
+        ):
+            self.telemetry.record(
+                "engine_resolved",
+                requested=self.engine,
+                resolved="planar",
+                reason=(
+                    f"{eng}: mover_cap {B} >= capacity {cap}, "
+                    f"count-driven pool no smaller than dense"
+                ),
+                canonical=True,
+                **detail,
+            )
+
+    def _hierarchical_fn(self, cap: int, out_cap: int, specs, rec,
+                         planar_detail: dict):
         """Build the hierarchical two-level call for these capacities,
         or return ``None`` to degrade to planar when the grown mover
         block already reached the dense pool size (mirroring the
@@ -888,21 +910,9 @@ class GridRedistribute:
             )
         B = self._mover_cap_for(cap)
         if B >= cap:
-            if rec is None and self._last_wire is not None and (
-                self._last_wire.get("engine") != "planar"
-            ):
-                self.telemetry.record(
-                    "engine_resolved",
-                    requested=self.engine,
-                    resolved="planar",
-                    reason=(
-                        f"hierarchical: mover_cap {B} >= capacity "
-                        f"{cap}, count-driven pool no smaller than "
-                        f"dense"
-                    ),
-                    canonical=True,
-                    **(self._last_payload or {}),
-                )
+            self._journal_planar_degrade(
+                "hierarchical", B, cap, rec, planar_detail
+            )
             return None
         B2 = self._cross_cap_for(cap)
         hier = self._hier
@@ -1042,7 +1052,20 @@ class GridRedistribute:
         # the in-graph overflow fallback. The decision is journaled as
         # engine_resolved whenever the routing inputs change.
         n_dev = 1 if self._vranks else int(self.mesh.devices.size)
-        res_key = (self.engine, self._vranks, specs is not None, n_dev)
+        R = self.nranks
+        # how the vrank planar engine packs at this capacity: journaled
+        # with a planar resolution, again when a capacity change flips it
+        pack_path = (
+            exchange.vrank_pack_path(R, cap, positions.shape[0] // R)
+            if self._vranks and specs is not None
+            else None
+        )
+        planar_detail = dict(self._last_payload or {})
+        if pack_path is not None:
+            planar_detail["pack"] = pack_path
+        res_key = (
+            self.engine, self._vranks, specs is not None, n_dev, pack_path
+        )
         rec = None
         if res_key != self._last_resolution:
             self._last_resolution = res_key
@@ -1051,12 +1074,13 @@ class GridRedistribute:
             self.engine, vranks=self._vranks, n_devices=n_dev,
             planar_ok=specs is not None, canonical=True,
             n_pods=self.n_pods, recorder=rec, planar_why=why,
-            detail=self._last_payload,
+            detail=self._last_payload, planar_detail=planar_detail,
         )
-        R = self.nranks
         dense_cols = R * cap
         if resolved == "hierarchical" and specs is not None:
-            fn = self._hierarchical_fn(cap, out_cap, specs, rec)
+            fn = self._hierarchical_fn(
+                cap, out_cap, specs, rec, planar_detail
+            )
             if fn is not None:
                 return fn
             resolved = "planar"
@@ -1065,21 +1089,9 @@ class GridRedistribute:
             if B >= cap:
                 # the grown mover block reached the dense pool size: the
                 # count-driven engine would be a no-op wrapper, run planar
-                if rec is None and self._last_wire is not None and (
-                    self._last_wire.get("engine") != "planar"
-                ):
-                    self.telemetry.record(
-                        "engine_resolved",
-                        requested=self.engine,
-                        resolved="planar",
-                        reason=(
-                            f"{resolved}: mover_cap {B} >= capacity "
-                            f"{cap}, count-driven pool no smaller than "
-                            f"dense"
-                        ),
-                        canonical=True,
-                        **(self._last_payload or {}),
-                    )
+                self._journal_planar_degrade(
+                    resolved, B, cap, rec, planar_detail
+                )
                 resolved = "planar"
             else:
                 if resolved == "neighbor":

@@ -295,6 +295,42 @@ def rank_of_position_planar(pos, domain: Domain, grid: ProcessGrid, xp=jnp,
     return rank.astype(xp.int32)
 
 
+def dest_sort_key(dest, n_dest: int):
+    """The key of the stable destination order, and its row-index bits.
+
+    Where the destinations and the row index fit one int32 word the key
+    is the PACKED ``(dest << b) | iota``: unique, so an unstable one-word
+    sort orders rows exactly as the stable ``(dest, iota)`` sort does
+    while moving half the bytes — the sort network is the phase-2 wall
+    of the migrate knockout (BENCH_CONFIGS.md), and at the 64-vrank
+    north-star the packed form fits easily (64 dests << 20-bit row
+    index). Otherwise the key is ``dest`` itself, to be sorted stably.
+
+    Returns ``(key, b)`` with ``b`` the row-index bits of the packed
+    key, or ``None`` for the plain one. Shared by
+    :func:`sorted_dest_counts` and :func:`sort_by_dest`, so the two
+    orders cannot drift apart.
+    """
+    n = dest.shape[0]
+    b = max(1, (n - 1).bit_length())
+    if n_dest + 1 <= (1 << (31 - b)):
+        iota = jnp.arange(n, dtype=jnp.int32)
+        return (dest << b) | iota, b
+    return dest, None
+
+
+def dest_bounds(sorted_key, n_dest: int, b):
+    """``bounds`` [n_dest+1] of the destination segments, read off a
+    sorted :func:`dest_sort_key` by binary search (free on sorted keys,
+    where a ``segment_sum`` histogram lowers to a scatter-add)."""
+    probes = jnp.arange(n_dest + 1, dtype=jnp.int32)
+    if b is not None:
+        probes = probes << b
+    return jnp.searchsorted(sorted_key, probes, side="left").astype(
+        jnp.int32
+    )
+
+
 def sorted_dest_counts(dest, n_dest: int):
     """Stable sort rows by destination AND count per destination, in one
     ``lax.sort`` + ``searchsorted``.
@@ -314,33 +350,37 @@ def sorted_dest_counts(dest, n_dest: int):
       rows by destination; ``counts`` [n_dest]; ``bounds`` [n_dest+1] —
       start offset of each destination's segment in ``order``.
     """
-    n = dest.shape[0]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    b = max(1, (n - 1).bit_length())
-    if n_dest + 1 <= (1 << (31 - b)):
-        # PACKED single-operand sort: ``(dest << b) | iota`` is unique, so
-        # an unstable one-word sort reproduces the stable two-operand
-        # (key, iota) sort bit-for-bit while moving half the bytes — the
-        # sort network is the phase-2 wall of the migrate knockout
-        # (BENCH_CONFIGS.md), and at the 64-vrank north-star the packed
-        # form fits easily (64 dests << 20-bit row index).
-        packed = jax.lax.sort((dest << b) | iota, is_stable=False)
-        order = packed & jnp.int32((1 << b) - 1)
-        bounds = jnp.searchsorted(
-            packed,
-            jnp.arange(n_dest + 1, dtype=jnp.int32) << b,
-            side="left",
-        ).astype(jnp.int32)
+    key, b = dest_sort_key(dest, n_dest)
+    if b is not None:
+        key = jax.lax.sort(key, is_stable=False)
+        order = key & jnp.int32((1 << b) - 1)
     else:
-        keys_sorted, order = jax.lax.sort(
-            (dest, iota), num_keys=1, is_stable=True
-        )
-        bounds = jnp.searchsorted(
-            keys_sorted,
-            jnp.arange(n_dest + 1, dtype=jnp.int32),
-            side="left",
-        ).astype(jnp.int32)
+        iota = jnp.arange(dest.shape[0], dtype=jnp.int32)
+        key, order = jax.lax.sort((key, iota), num_keys=1, is_stable=True)
+    bounds = dest_bounds(key, n_dest, b)
     return order, bounds[1:] - bounds[:-1], bounds
+
+
+def sort_by_dest(dest, n_dest: int, payload):
+    """:func:`sorted_dest_counts` that carries the rows along: ONE
+    ``lax.sort`` of the destination key with the ``K`` rows of the
+    ``[K, N]`` ``payload`` as extra operands.
+
+    Returns ``(sorted_payload, counts, bounds)``: column ``j`` of
+    ``sorted_payload`` is ``payload[:, order[j]]`` for the ``order``
+    :func:`sorted_dest_counts` gives, and ``counts``/``bounds`` are its.
+    Sorts are cheap on TPU and per-column placement is not (see
+    ``pack.planar_compact_with_self``): this moves the payload through
+    the sort network instead of gathering it by ``order`` afterwards.
+    Any 32-bit payload dtype; int32 keeps every bit pattern.
+    """
+    key, b = dest_sort_key(dest, n_dest)
+    out = jax.lax.sort(
+        (key,) + tuple(payload[k] for k in range(payload.shape[0])),
+        num_keys=1, is_stable=b is None,
+    )
+    bounds = dest_bounds(out[0], n_dest, b)
+    return jnp.stack(out[1:], axis=0), bounds[1:] - bounds[:-1], bounds
 
 
 def sorted_dest_counts_batched(dest, n_dest: int, *, chunk: int = 4096,

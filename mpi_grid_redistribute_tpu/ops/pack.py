@@ -337,6 +337,28 @@ def gather_plan_cols(fused: jax.Array, idx: jax.Array) -> jax.Array:
     return flat.reshape((fused.shape[0],) + idx.shape)
 
 
+def pack_windows(sorted_cols, bounds, send_counts, capacity: int):
+    """:func:`pack_cols` for columns that are already in destination
+    order (``binning.sort_by_dest``): destination ``d``'s slots are the
+    contiguous window ``[bounds[d], bounds[d] + C)`` of ``sorted_cols``,
+    copied whole, so no per-column index exists. Returns the same
+    ``[K, n_dest * C]`` send pool, zero in slots ``c >= send_counts[d]``.
+
+    The columns are padded by ``C`` zeros first: a window start is at
+    most ``n``, and ``dynamic_slice`` would clamp a window that runs past
+    the end, shifting the segment silently. Int32 columns keep every bit
+    pattern (see :func:`pack_cols` on the denormal flush)."""
+    K = sorted_cols.shape[0]
+    C = capacity
+    padded = jnp.pad(sorted_cols, ((0, 0), (0, C)))
+    win = jax.vmap(
+        lambda start: jax.lax.dynamic_slice_in_dim(padded, start, C, axis=1)
+    )(bounds)  # [n_dest, K, C]
+    slot_valid = jnp.arange(C, dtype=jnp.int32) < send_counts[:, None]
+    win = jnp.where(slot_valid[:, None, :], win, 0)
+    return win.transpose(1, 0, 2).reshape(K, bounds.shape[0] * C)
+
+
 def pack_cols(fused, order, bounds, send_counts, n_dest: int,
                capacity: int):
     """Gather the first ``send_counts[d]`` sorted columns of each
@@ -344,7 +366,8 @@ def pack_cols(fused, order, bounds, send_counts, n_dest: int,
     invalid slots). Returns ``(send, gather_idx)``; ``gather_idx[j]`` is
     the resident column feeding send slot ``j`` (unique over valid
     slots). Shared by the migrate engine and the planar canonical
-    exchange (exchange.vrank_redistribute_planar_fn) — the planar twin of
+    engines (the vrank engine only below ``exchange.vrank_pack_path``'s
+    threshold; above it :func:`pack_windows`) — the planar twin of
     :func:`pack_by_destination`."""
     n = fused.shape[1]
     C = capacity

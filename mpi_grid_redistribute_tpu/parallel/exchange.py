@@ -52,6 +52,7 @@ def resolve_engine(
     recorder=None,
     planar_why: str = None,
     detail: dict = None,
+    planar_detail: dict = None,
 ) -> str:
     """Resolve a user-facing engine name to a concrete engine — the ONE
     dispatch rule shared by :class:`..api.Redistributer` (canonical
@@ -85,7 +86,9 @@ def resolve_engine(
     ``recorder`` (a :class:`..telemetry.StepRecorder`) journals the
     decision as an ``engine_resolved`` event — chosen engine plus the
     reason, including any degradation — so silent routing is observable;
-    ``detail`` adds its keys to the event (the api's ``payload_words``).
+    ``detail`` adds its keys to the event (the api's ``payload_words``),
+    and ``planar_detail`` takes its place when the resolution is
+    ``"planar"`` (the api adds the vrank engine's ``pack`` path).
     """
     if engine not in ENGINES:
         raise ValueError(
@@ -144,6 +147,8 @@ def resolve_engine(
         else:
             resolved, reason = "planar", "migrate: dense planar step"
     if recorder is not None:
+        if resolved == "planar" and planar_detail is not None:
+            detail = planar_detail
         recorder.record(
             "engine_resolved",
             requested=engine,
@@ -355,6 +360,24 @@ def vrank_redistribute_fn(
     return fn
 
 
+def vrank_pack_path(n_ranks: int, capacity: int, n: int) -> str:
+    """How :func:`vrank_redistribute_planar_fn` fills its ``[K, R*C]``
+    send pool from ``n`` columns a vrank: ``"sort"`` carries the rows
+    through the destination sort and copies one window a destination;
+    ``"gather"`` sorts the key alone and gathers the pool's columns.
+
+    The sort moves ``n * (K + 1)`` words through the network, the
+    gathers cost per pool column. Measured on a v5e at 8 vranks x
+    ``K = 8`` x ``2^20`` columns (PERF.md): the payload sort 59 ms, ~7 ns
+    a column; the gathers 457 ms over ``R * C = 2n``, ~27 ns a pool
+    column; so the sort wins above ``R * C`` ~ 0.12n and loses at
+    ``C = n / 64``. ``R * C >= n`` is a conservative, round threshold:
+    the default capacity (``api``'s ``capacity_factor`` 2.0) gives
+    ``R * C >= 2n`` and always sorts; a small explicit capacity
+    gathers."""
+    return "sort" if n_ranks * capacity >= n else "gather"
+
+
 def vrank_redistribute_planar_fn(
     domain: Domain,
     grid: ProcessGrid,
@@ -384,13 +407,21 @@ def vrank_redistribute_planar_fn(
     way in/out (:func:`..migrate.fuse_fields` semantics, minus the alive
     row — validity here is the count prefix, as everywhere on the
     canonical path). ``fused`` may be float32 or int32; either way the
-    TRANSPORT (pack gather, wire, compaction sort) runs on an int32
-    bitcast view — TPU float vector copies flush denormal f32 bit
-    patterns to zero (any bitcast int < 2^23; measured through the pack
-    gather at ~3k rows/shard — the hazard ops/pallas_overlay.py biases
-    around), while integer lanes have no FTZ semantics, so every 32-bit
-    pattern (denormals, NaN payloads, -0.0) survives bit-exactly by
+    TRANSPORT (pack, wire, compaction sort) runs on an int32 bitcast
+    view — TPU float vector copies flush denormal f32 bit patterns to
+    zero (any bitcast int < 2^23; measured through the pack gather at
+    ~3k rows/shard — the hazard ops/pallas_overlay.py biases around),
+    while integer lanes have no FTZ semantics, so every 32-bit pattern
+    (denormals, NaN payloads, -0.0) survives bit-exactly by
     construction. Output dtype matches the input.
+
+    The pack is this engine's own (:func:`vrank_pack_path`): at the
+    default capacity the rows ride the destination sort
+    (``binning.sort_by_dest``) and each destination's slots are one
+    window of the sorted rows (``pack.pack_windows``); a capacity with
+    ``R * C < n`` sorts the key alone and gathers (``pack.pack_cols``).
+    Both fill slot ``(d, c)`` from the same column, so the paths are
+    bit-identical.
     """
     V = grid.nranks
     C = capacity
@@ -419,7 +450,9 @@ def vrank_redistribute_planar_fn(
         n = fused.shape[2]
         me_ids = jnp.arange(V, dtype=jnp.int32)
 
-        def bin_one(pos_v, count_v, me):
+        sort_pack = vrank_pack_path(V, C, n) == "sort"
+
+        def bin_one(pos_v, count_v, me, fi_v):
             valid = jnp.arange(n, dtype=jnp.int32) < count_v
             dest = binning.rank_of_position_planar(
                 pos_v, domain, grid, edges=edges
@@ -427,22 +460,30 @@ def vrank_redistribute_planar_fn(
             dest = jnp.where(valid, dest, V).astype(jnp.int32)
             is_self = valid & (dest == me)
             dest_remote = jnp.where(is_self, V, dest)
-            order, remote_counts, bounds = binning.sorted_dest_counts(
-                dest_remote, V
-            )
-            return order, remote_counts, bounds, is_self
+            if sort_pack:
+                # the rows ride the destination sort: [K, n] in order
+                plan, remote_counts, bounds = binning.sort_by_dest(
+                    dest_remote, V, fi_v
+                )
+            else:
+                plan, remote_counts, bounds = binning.sorted_dest_counts(
+                    dest_remote, V
+                )
+            return plan, remote_counts, bounds, is_self
 
-        def pack_one(fi_v, order, bounds, send_counts_v):
+        def pack_one(fi_v, plan, bounds, send_counts_v):
+            if sort_pack:
+                return pack.pack_windows(plan, bounds[:V], send_counts_v, C)
             packed, _ = pack.pack_cols(
-                fi_v, order, bounds[:V], send_counts_v, V, C
+                fi_v, plan, bounds[:V], send_counts_v, V, C
             )
             return packed  # [K, V*C] int32
 
         # each phase's scope wraps its vmap, so the ops carry the scope
         # itself and not a vmap(...) of it
         with traced_span("rd:bin"):
-            order, remote_counts, bounds, is_self = jax.vmap(bin_one)(
-                pos_f, count, me_ids
+            plan, remote_counts, bounds, is_self = jax.vmap(bin_one)(
+                pos_f, count, me_ids, fi
             )
             dropped_send = jnp.sum(jnp.maximum(remote_counts - C, 0), axis=1)
             send_counts = jnp.minimum(remote_counts, C)
@@ -452,7 +493,7 @@ def vrank_redistribute_planar_fn(
             send_stats = send_counts + self_diag
             recv_stats = recv_counts + self_diag
         with traced_span("rd:pack"):
-            packed = jax.vmap(pack_one)(fi, order, bounds, send_counts)
+            packed = jax.vmap(pack_one)(fi, plan, bounds, send_counts)
         K = fused.shape[1]
         # the wire, as a transpose: [V_src, K, V_dst, C] -> dst-major pools
         with traced_span("rd:exchange"):
@@ -555,8 +596,10 @@ def shard_redistribute_planar_fn(
     """PLANAR multi-device canonical exchange (runs under ``shard_map``).
 
     The shard_map twin of :func:`vrank_redistribute_planar_fn`: same
-    routing (``binning.rank_of_position_planar``), same ``pack_cols`` pack,
-    same payload-carrying-sort compaction
+    routing (``binning.rank_of_position_planar``), the same send pool
+    (filled here by the ``pack_cols`` gather, which the vrank engine
+    keeps only for small capacities), same payload-carrying-sort
+    compaction
     (``pack.planar_compact_with_self``), same capacity/overflow accounting
     — but the V-way transpose is a real ``lax.all_to_all`` over the mesh
     axes, riding ICI. The per-shard state is ``[K, n]`` component-major
@@ -1073,9 +1116,10 @@ def _validate_planar_vranks(fused, V, D):
 
 def _vrank_sparse_prefix(fi, pos_f, count, domain, grid, edges, n):
     """Vmapped routing prefix of the vrank count-driven engines — the
-    same per-vrank binning/sort as :func:`vrank_redistribute_planar_fn`'s
-    ``pack_one``, split from the pack so both cond branches (mover-block
-    and dense widths) can share one plan."""
+    same per-vrank binning and destination order as
+    :func:`vrank_redistribute_planar_fn`'s ``bin_one`` (here the key
+    sort alone, for ``pack_cols``), split from the pack so both cond
+    branches (mover-block and dense widths) can share one plan."""
     V = grid.nranks
     me_ids = jnp.arange(V, dtype=jnp.int32)
 

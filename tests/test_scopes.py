@@ -15,7 +15,7 @@ CPU mesh and read the same HLO text:
 * the one-call planar vrank program that ``GridRedistribute.redistribute``
   dispatches on one device holds its boundary work under ``rd:fuse`` and
   ``rd:unfuse`` and every other costly op under the engine's ``rd:``
-  scopes.
+  scopes, and its plan copies windows, not pool-long gathers.
 
 Two kinds of instruction are the compiler's, not the program's, and no
 scope can reach them: the loop's own bookkeeping (the trip counter and
@@ -336,10 +336,11 @@ def test_four_device_landing_has_no_slot_long_gather():
 RD_LANDMARKS = {
     # the caller's row-major arrays into the [V, K, n] fused state
     "rd:fuse": lambda op, n, f: "concatenate" in f,
-    # the destination sort of every rank's rows
+    # the destination sort of every rank's rows, the rows carried
     "rd:bin": lambda op, n, f: op == "sort",
-    # the column gather into the send pool
-    "rd:pack": lambda op, n, f: "gather" in f,
+    # the window copies into the send pool: one gather of whole windows
+    # out of the sorted rows, padded so that no window start is clamped
+    "rd:pack": lambda op, n, f: {"gather", "pad"} <= f,
     # the payload-carrying compaction sort into receive order
     "rd:unpack": lambda op, n, f: op == "sort",
     # the fused rows back to row-major outputs, positions as float32
@@ -354,3 +355,14 @@ def test_each_redistribute_scope_holds_its_landmark(scope):
     assert any(RD_LANDMARKS[scope](op, n, f) for _, op, n, f in under), under
     # every scope wraps its phase whole: no op carries it inside a vmap
     assert f"vmap({scope})" not in text
+
+
+def test_one_call_plan_has_no_pool_long_gather():
+    """The one-call plan copies each destination's slots as one window of
+    the sorted rows, never one index a pool column: no gather under
+    ``rd:bin`` or ``rd:pack`` has an output dimension as long as one
+    vrank's send pool (8 destinations x capacity 128 = 1024 columns)."""
+    text = hlo("redistribute_planar_8v")
+    gathers = _gather_lengths(text, ("rd:bin", "rd:pack"))
+    assert any(g[2][-1] == 128 for g in gathers), gathers
+    assert [g for g in gathers if max(g[2]) >= 1024] == []
