@@ -1,7 +1,6 @@
 PY ?= python
 
-.PHONY: test lint lint-json baseline bench-check observe serve-metrics \
-	soak soak-smoke rebalance-smoke service-bench progcheck \
+.PHONY: test lint lint-json baseline observe serve-metrics progcheck \
 	progcheck-baseline shardcheck shardcheck-baseline check \
 	racecheck racecheck-baseline \
 	kernelcheck kernelcheck-baseline incident-demo storecheck \
@@ -9,14 +8,6 @@ PY ?= python
 
 test:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m 'not slow'
-
-# regression guard: newest BENCH_r*.json capture vs the BEST committed
-# history per guarded metric. Deltas are classified against the
-# captures' own min-of-k spreads: WOBBLE (within noise) and WARN pass,
-# REGRESSION (beyond max(10%, 2x noise)) = exit 1. `--legacy` restores
-# the plain >10% binary gate. See telemetry/regress.py.
-bench-check:
-	$(PY) scripts/bench_check.py
 
 # metrics plane demo: serve /metrics (OpenMetrics) + /healthz for a
 # small in-process drift loop on 127.0.0.1:9100. Scrape with
@@ -42,51 +33,6 @@ observe:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 		$(PY) examples/drift_demo.py --n 16384 --steps 20 \
 		--corrupt
-
-# service soak gate (bench/config8_soak.py --soak): short CPU soak of
-# the fault-tolerant service driver with the snapshot cadence on and
-# one injected mid-run crash. Fails (exit 1) unless the supervised
-# restore is bit-identical to an uninterrupted run, exactly one restart
-# happened, the async-snapshot overhead stays <= 2% of step time
-# (min-of-k), and the elastic leg (crash + device loss -> shrink-restore
-# onto half the mesh) resumes with an id-sorted particle set identical
-# to the uninterrupted run. See mpi_grid_redistribute_tpu/service/.
-soak:
-	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-		BENCH_SCALE=0.05 \
-		$(PY) -m mpi_grid_redistribute_tpu.bench.config8_soak --soak
-
-# CI-speed soak: same gate with a short crash/elastic horizon
-# (BENCH_SOAK_STEPS) and few timing reps; the tier-1 suite runs the
-# equivalent via tests/test_bench_configs.py so the shrink-restore leg
-# is exercised on CPU in every CI pass. The snapshot-overhead budget is
-# waived (SOAK_OVERHEAD_MAX) — at smoke scale the min-of-2 timing is
-# noise; `make soak` owns the 2% gate.
-soak-smoke:
-	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-		BENCH_SCALE=0.02 BENCH_SOAK_STEPS=12 BENCH_SOAK_EVERY=4 \
-		BENCH_SOAK_K=2 SOAK_OVERHEAD_MAX=10 \
-		$(PY) -m mpi_grid_redistribute_tpu.bench.config8_soak --soak
-
-# CI-speed closed-loop adaptive-rebalance gate (ISSUE 9): twin config4
-# drift-bias runs, loop on/off — asserts the imbalance_ratio ALERT
-# fired, a rebalance applied, post-rebalance imbalance <= 1.1x, zero
-# dropped rows, and the id-sorted particle set is bit-identical to the
-# no-rebalance twin. The steady-state ms/step is regress-guarded
-# (rebalance_drift_ms, LOWER) against committed captures instead.
-rebalance-smoke:
-	JAX_PLATFORMS=cpu \
-		$(PY) -m mpi_grid_redistribute_tpu.bench.config4_drift --rebalance
-
-# resident chunked-stepping gate (ISSUE 10): eager(chunk=1) vs chunked
-# (chunk=16/64) ServiceDriver pps on the 8-vrank mesh (4096 rows, one
-# device, measured in-process), asserting the chunk=64 speedup floor
-# (SERVICE_SPEEDUP_MIN, default 1.5x) and chunk-vs-eager final
-# particle-set bit-identity. service_pps is regress-guarded against
-# committed captures on top.
-service-bench:
-	JAX_PLATFORMS=cpu \
-		$(PY) -m mpi_grid_redistribute_tpu.bench.config10_service --gate
 
 # every analyzer family in --check text mode, driven off the single
 # ANALYZERS registry in scripts/check_all.py (gridlint G, progcheck J,
@@ -173,9 +119,8 @@ grid-top:
 		--store .grid_top_demo/store --once; \
 	rm -rf .grid_top_demo
 
-# run-index view: BENCH_r*.json perf trajectory (+ store runs via
-# --stores DIR); `--check capture.json` gates a fresh capture against
-# the whole indexed history through regress.classify_capture
+# run-index view of the journal-store runs under --stores DIR
+# (`make history` alone indexes none; see scripts/history.py)
 history:
 	JAX_PLATFORMS=cpu $(PY) scripts/history.py
 

@@ -6,8 +6,9 @@ timings of one cold run.
     python chip_smoke.py             # one chip: API, drift loop, service
     python chip_smoke.py --chips 4   # four chips: the cross-chip path only
 
-One chip, at the ``bench.py`` headline shape (grid 2x2x2 as 8 virtual
-ranks, 2**20 rows per rank, FILL 0.9, ~2% migration per step):
+One chip, at the shape of the benchmark's ``drift8v.steady`` cell (grid
+2x2x2 as 8 virtual ranks, 2**20 rows per rank, FILL 0.9, ~2% migration
+per step):
 
 * api     -- ``GridRedistribute(...).redistribute(pos, vel, ids)`` on
   8 * 2**20 rows, bit-identical (uint32 view) to ``backend="numpy"``;
@@ -144,7 +145,7 @@ def phase_loop(grid_shape, n_local: int, mesh, vgrid=None, seed: int = 1,
     import jax.numpy as jnp
 
     from mpi_grid_redistribute_tpu import Domain, oracle
-    from mpi_grid_redistribute_tpu.bench import common
+    from mpi_grid_redistribute_tpu.models import initial
     from mpi_grid_redistribute_tpu.domain import ProcessGrid
     from mpi_grid_redistribute_tpu.models import nbody
     from mpi_grid_redistribute_tpu.utils import stats as stats_lib
@@ -152,10 +153,10 @@ def phase_loop(grid_shape, n_local: int, mesh, vgrid=None, seed: int = 1,
     grid = ProcessGrid(grid_shape)
     domain = Domain(0.0, 1.0, periodic=True)
     dev_grid = ProcessGrid((1, 1, 1)) if vgrid is not None else grid
-    v_scale, cap, budget = common.drift_sizing(
+    v_scale, cap, budget = initial.drift_sizing(
         grid_shape, n_local, FILL, MIGRATION
     )
-    pos, vel, alive = common.uniform_state(
+    pos, vel, alive = initial.uniform_state(
         grid_shape, n_local, FILL, np.random.default_rng(seed),
         vel_scale=v_scale,
     )

@@ -71,7 +71,7 @@ def main() -> None:
     import mpi_grid_redistribute_tpu as gr
     from mpi_grid_redistribute_tpu import oracle
     from mpi_grid_redistribute_tpu.models import nbody
-    from mpi_grid_redistribute_tpu.bench import common
+    from mpi_grid_redistribute_tpu.models import initial
     from mpi_grid_redistribute_tpu.utils import stats as stats_lib
 
     grid_shape = (2, 2, 2)
@@ -119,7 +119,7 @@ def main() -> None:
     print("telemetry: " + report_lib.format_report(rd.report()))
 
     # --- 2. drift loop: redistribute every step (SURVEY.md §3.3) --------
-    dev_grid, vgrid, mesh, n_chips = common.pick_layout(grid_shape)
+    dev_grid, vgrid, mesh, n_chips = initial.pick_layout(grid_shape)
     cap = max(64, n_local // 4)
     cfg = nbody.DriftConfig(
         domain=domain, grid=dev_grid, dt=0.05, capacity=cap,

@@ -10,7 +10,6 @@ file. Every entry must carry a human-written ``justification``.
 
 from __future__ import annotations
 
-import hashlib
 import json
 import os
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
@@ -111,11 +110,9 @@ def write_baseline(
 #
 # Unlike the gridlint baseline (a suppression list), this one is a
 # MEASUREMENT: the static wire/footprint profile of every registered
-# program, compared exactly (bench_check-style drift gate) by
-# ``rules_jaxpr.compare_profiles``. These helpers are jax-free on
-# purpose — bench.py embeds ``progprofile_hash()`` in its captures so
-# ``telemetry.regress`` can correlate a perf delta with a wire-model
-# change without importing the analyzer.
+# program, compared exactly by ``rules_jaxpr.compare_profiles``. These
+# helpers are jax-free, so the baseline can be read without importing
+# the analyzer.
 # ---------------------------------------------------------------------
 
 
@@ -310,17 +307,6 @@ def write_kernelcheck_baseline(
     with open(path, "w", encoding="utf-8") as fh:
         json.dump(doc, fh, indent=2, sort_keys=True)
         fh.write("\n")
-
-
-def progprofile_hash(path: Optional[str] = None) -> Optional[str]:
-    """Short content hash of the committed profile baseline (None when
-    absent). Captured by bench.py so regress can flag 'the static wire
-    model changed between these captures'."""
-    path = path or progprofile_baseline_path()
-    if not os.path.exists(path):
-        return None
-    with open(path, "rb") as fh:
-        return hashlib.sha256(fh.read()).hexdigest()[:16]
 
 
 def split_baselined(

@@ -503,10 +503,10 @@ def compare_profiles(
     check_stale: bool = False,
     partial: bool = False,
 ) -> List[ProgFinding]:
-    """bench_check-style drift gate over the static profiles. Any
-    numeric drift beyond ``rtol`` (default: exact) is a J004 finding —
-    intentional changes re-commit via ``--update-baseline``, exactly
-    like the gridlint baseline workflow."""
+    """Drift gate over the static profiles. Any numeric drift beyond
+    ``rtol`` (default: exact) is a J004 finding — intentional changes
+    re-commit via ``--update-baseline``, exactly like the gridlint
+    baseline workflow."""
     findings: List[ProgFinding] = []
     if baseline is None:
         baseline = {}

@@ -1,48 +1,20 @@
-"""Shared benchmark plumbing: device/vrank layout pick, sizing, reporting."""
+"""Seeded initial states and their sizing: uniform particles placed on
+their owning rank, the drift-loop velocity scale and exchange
+capacities for a target migration fraction, and the device layout of a
+rank grid.
+
+``ServiceDriver.init_state`` builds its state here, so a given seed
+gives the same particles in every release that keeps these bodies.
+"""
 
 from __future__ import annotations
 
-import json
-import os
-import sys
-import time
-from typing import Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 
-from mpi_grid_redistribute_tpu.domain import Domain, ProcessGrid
+from mpi_grid_redistribute_tpu.domain import ProcessGrid
 from mpi_grid_redistribute_tpu.parallel import mesh as mesh_lib
-
-
-def log(msg: str) -> None:
-    print(msg, file=sys.stderr, flush=True)
-
-
-def emit(result: dict) -> None:
-    """The one-JSON-line contract shared with the repo-root bench.py."""
-    print(json.dumps(result), flush=True)
-
-
-def write_journal_shard(recorder, name: str) -> Optional[str]:
-    """Write a driver's recorder as a per-process JSONL journal shard.
-
-    ``BENCH_JOURNAL_DIR=dir`` opts in (the bench contract stays
-    one-JSON-line on stdout either way); the shard lands at
-    ``dir/<name>.<host>.<pid>.jsonl`` — every line tagged with the
-    recorder's ``host``/``pid``, ready for
-    ``telemetry.aggregate.merge_journals`` /
-    ``scripts/metrics_serve.py --journal``. Returns the path written, or
-    None when the env var is unset."""
-    out_dir = os.environ.get("BENCH_JOURNAL_DIR")
-    if not out_dir:
-        return None
-    os.makedirs(out_dir, exist_ok=True)
-    path = os.path.join(
-        out_dir, f"{name}.{recorder.host}.{recorder.pid}.jsonl"
-    )
-    n = recorder.to_jsonl(path)
-    log(f"journal shard: {path} ({n} events)")
-    return path
 
 
 def pick_layout(grid_shape: Tuple[int, ...]):
@@ -90,18 +62,6 @@ def uniform_state(grid_shape, n_local: int, fill: float, rng, vel_scale=0.0):
     return pos, vel, alive
 
 
-def lognormal_state(grid_shape, n_local: int, fill: float, rng, sigma=1.0):
-    """Log-normal clustered global positions (BASELINE config 2): heavy
-    density contrast across subdomains -> load imbalance. Rows are NOT
-    pre-placed on owners; the redistribute under test must move them."""
-    grid = ProcessGrid(grid_shape)
-    n = grid.nranks * n_local
-    raw = rng.lognormal(mean=0.0, sigma=sigma, size=(n, 3))
-    pos = (raw % 1.0).astype(np.float32)
-    alive = np.tile(np.arange(n_local) < int(fill * n_local), grid.nranks)
-    return pos, alive
-
-
 def drift_sizing(
     grid_shape, n_local: int, fill: float, migration: float,
     headroom: float = 1.3,
@@ -127,18 +87,3 @@ def drift_sizing(
     cap = max(64, math.ceil(fill * n_local * migration / distinct * headroom))
     budget = max(256, math.ceil(fill * n_local * migration * headroom))
     return v.astype(np.float32), cap, budget
-
-
-def timeit_fetch(fn, args, reps: int = 3) -> float:
-    """min wall seconds of fn(*args) with a host-fetch barrier."""
-    import jax
-
-    out = fn(*args)
-    np.asarray(jax.tree.leaves(out)[0].ravel()[0])
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        np.asarray(jax.tree.leaves(out)[0].ravel()[0])
-        best = min(best, time.perf_counter() - t0)
-    return best

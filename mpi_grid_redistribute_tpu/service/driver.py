@@ -31,7 +31,7 @@ N — the property ``pod_smoke --kill-restore`` and the fault-matrix tests
 assert, and the foundation for elastic restarts (a snapshot written at R
 shards reloads at any shard count, ``utils/checkpoint.py``).
 
-CLI (used by ``scripts/pod_smoke.py --kill-restore`` and ``make soak``)::
+CLI (used by ``scripts/pod_smoke.py --kill-restore``)::
 
     python -m mpi_grid_redistribute_tpu.service.driver \\
         --grid 2,2,2 --steps 60 --snapshot-every 5 --snapshot-dir /tmp/snaps
@@ -409,14 +409,14 @@ class ServiceDriver:
         ids ride every redistribute as a passenger field, so the global
         particle SET stays identifiable across restarts AND mesh
         reshapes (the elastic bit-identity audits sort by id)."""
-        from mpi_grid_redistribute_tpu.bench import common as bcommon
+        from mpi_grid_redistribute_tpu.models import initial
 
         cfg = self.cfg
         rng = np.random.default_rng(cfg.seed)
-        v_scale, _, _ = bcommon.drift_sizing(
+        v_scale, _, _ = initial.drift_sizing(
             cfg.grid_shape, cfg.n_local, cfg.fill, cfg.migration
         )
-        pos, vel, _ = bcommon.uniform_state(
+        pos, vel, _ = initial.uniform_state(
             cfg.grid_shape, cfg.n_local, 1.0, rng, vel_scale=v_scale
         )
         ids = np.arange(self.nranks * cfg.n_local, dtype=np.int32)

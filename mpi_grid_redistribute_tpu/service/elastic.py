@@ -17,7 +17,7 @@ the driver journals in its ``reshard`` event (telemetry/SCHEMA.md).
 Values are only permuted, never recomputed, so the global particle SET
 is invariant across mesh shapes; :func:`particle_set` canonicalizes a
 driver state (sort live rows by id) into bytes for exactly that
-bit-identity check, used by the fault matrix and the config8 soak leg.
+bit-identity check, used by the fault matrix and the elastic tests.
 """
 # gridlint: service-path
 

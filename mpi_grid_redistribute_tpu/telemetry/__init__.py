@@ -2,7 +2,7 @@
 
 Turns the scattered instruments that grew around the engines — the
 scan-differencing timers in :mod:`..utils.profiling`, the stats summaries
-in :mod:`..utils.stats` — into one subsystem with four pieces:
+in :mod:`..utils.stats` — into one subsystem with three pieces:
 
 * :mod:`.recorder` — a bounded host-side ring buffer of structured events
   (capacity growth, overflow window scheduling/resolution, halo cap
@@ -16,11 +16,7 @@ in :mod:`..utils.stats` — into one subsystem with four pieces:
 * :mod:`.report` — the metrics surface: one merged dict (stats summary,
   exchange bytes/step, achieved GB/s, ``bw_util`` against the HBM/ICI
   roofs in :mod:`..utils.profiling`, growth/overflow event counts),
-  reachable as ``rd.report()`` and emitted by every bench driver.
-* :mod:`.regress` — the regression guard: min-of-k timing protocol with
-  spread reporting plus a checker comparing a bench capture against the
-  committed ``BENCH_r*.json`` history, failing loudly (exit code + report
-  line) on >10% regressions (``make bench-check``).
+  reachable as ``rd.report()``.
 
 The grid observatory (PR 3) adds three layers on that substrate:
 
@@ -50,9 +46,6 @@ The metrics plane (ISSUE 5) makes the journal scrapable pod-wide:
   alignment; the :class:`~.aggregate.MergedJournal` projects back into
   a pod-wide recorder, ``MigrateStats``-shaped pod stats for
   :func:`~.report.exchange_report`, and merged flow gauges.
-* :mod:`.regress` additionally grew the noise-aware classifier
-  (``classify_capture`` — WOBBLE/WARN/REGRESSION against the captures'
-  own min-of-k spreads) and ``env_fingerprint()``.
 
 Device time is read from profiler traces:
 
@@ -70,7 +63,7 @@ alerts actionable:
   into every event envelope by the recorder; "which step caused this
   alert/restart" becomes a join on ``trace``/``ctx_*`` fields.
 * :mod:`.incident` — the :class:`~.incident.FlightRecorder` health
-  callback: on ALERT (or injected fault, or bench REGRESSION) it
+  callback: on ALERT (or injected fault) it
   freezes a debounced incident bundle — journal window, counts,
   OpenMetrics text, health findings, flow snapshot, env fingerprint,
   triggering step context — under an ``index.json``
@@ -134,15 +127,6 @@ from mpi_grid_redistribute_tpu.telemetry.phases import (  # noqa: F401
 from mpi_grid_redistribute_tpu.telemetry.report import (  # noqa: F401
     exchange_report,
     row_bytes_of,
-)
-from mpi_grid_redistribute_tpu.telemetry.regress import (  # noqa: F401
-    check_capture,
-    classify_capture,
-    classify_delta,
-    env_fingerprint,
-    extract_metrics,
-    min_of_k,
-    noise_floor,
 )
 from mpi_grid_redistribute_tpu.telemetry.metrics import (  # noqa: F401
     MetricsRegistry,

@@ -11,7 +11,7 @@ sent/received per step). The flow matrix closes that gap:
   sent rank ``j``). ``RedistributeStats.send_counts`` has carried the
   same matrix since the seed. No collective is added, no host sync
   happens inside the step — the matrix rides the same device->host read
-  the bench drivers already do for ``sent``/``received``.
+  every caller already does for ``sent``/``received``.
 * :func:`flow_matrix_of` normalizes either stats pytree to a step-major
   ``[S, R, R]`` host array.
 * :class:`FlowAccumulator` is the host-side gauge: cumulative matrix,

@@ -4,7 +4,7 @@ Production systems page on *signals*, not on someone re-deriving a stall
 from raw counters. :class:`HealthMonitor` closes the loop between the
 journal (:class:`~.recorder.StepRecorder` events, including
 ``flow_snapshot`` gauges from :mod:`.flow`) and the operator: a small
-set of declarative rules is evaluated on demand (``rd.health()``, bench
+set of declarative rules is evaluated on demand (``rd.health()``, service
 boundaries, ``make observe``); each finding fires the registered
 callbacks AND records an ``alert`` event into the same ring, so alerts
 appear in the JSONL export and the Perfetto timeline next to the events

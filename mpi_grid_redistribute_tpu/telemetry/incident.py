@@ -5,10 +5,8 @@ the time someone looks, the journal window that explains it has been
 evicted and the registry rebuilt many times. :class:`FlightRecorder`
 closes that gap. Registered as a :class:`~.health.HealthMonitor`
 callback (see :func:`install`), it reacts to every ALERT finding — and,
-via :meth:`FlightRecorder.scan_faults` /
-:meth:`FlightRecorder.capture_regression`, to injected faults and bench
-REGRESSION labels — by freezing everything an operator needs into one
-*incident bundle* directory:
+via :meth:`FlightRecorder.scan_faults`, to injected faults — by freezing
+everything an operator needs into one *incident bundle* directory:
 
 ``index.json``
     Trigger (rule / severity / reason / what kind of trigger), capture
@@ -24,7 +22,7 @@ REGRESSION labels — by freezing everything an operator needs into one
 ``env.json``
     All-time per-kind counts, the rendered OpenMetrics exposition, the
     triggering finding plus recent ``alert`` events, the latest
-    ``flow_snapshot`` gauges, and :func:`~.regress.env_fingerprint`.
+    ``flow_snapshot`` gauges, and :func:`env_fingerprint`.
 
 Captures are debounced per rule (``debounce_s``) so a standing ALERT
 re-confirmed at every health boundary yields exactly one bundle, and
@@ -49,7 +47,9 @@ from __future__ import annotations
 
 import json
 import os
+import platform as _platform
 import shutil
+import sys
 import threading
 import weakref
 from typing import Dict, List, Optional
@@ -75,6 +75,36 @@ def _dump_json(path: str, doc) -> None:
     with open(path, "w", encoding="utf-8") as fh:
         json.dump(doc, fh, indent=2, sort_keys=True)
         fh.write("\n")
+
+
+def env_fingerprint() -> Dict[str, object]:
+    """The environment a bundle was captured in: Python, platform, the
+    JAX platform and XLA flags, numpy, and — when this process has
+    already imported jax — its version and devices. The capture path
+    never imports jax itself (G007); device queries are best-effort."""
+    fp: Dict[str, object] = {
+        "python": _platform.python_version(),
+        "platform": sys.platform,
+        "jax_platforms": os.environ.get("JAX_PLATFORMS", ""),
+        "xla_flags": os.environ.get("XLA_FLAGS", ""),
+    }
+    try:
+        import numpy
+
+        fp["numpy"] = numpy.__version__
+    except ImportError:  # pragma: no cover
+        pass
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        try:
+            fp["jax"] = jax.__version__
+            devs = jax.devices()
+            fp["backend"] = devs[0].platform
+            fp["device_kind"] = devs[0].device_kind
+            fp["device_count"] = len(devs)
+        except Exception:  # backend init failed: still usable
+            pass
+    return fp
 
 
 class FlightRecorder:
@@ -162,29 +192,6 @@ class FlightRecorder:
                 severity="ALERT",
                 trigger="fault",
                 event=e,
-            )
-            if out is not None:
-                made.append(out)
-        return made
-
-    def capture_regression(self, lines, labels) -> List[str]:
-        """Capture on ``regress.classify_capture`` REGRESSION labels.
-
-        ``lines``/``labels`` are the report lines and metric→label map
-        the classifier returned; one bundle per regressed metric (rule
-        ``regression_<metric>``), debounced like any other rule.
-        """
-        by_metric = {m for m, lab in dict(labels).items() if lab == "REGRESSION"}
-        made = []
-        for metric in sorted(by_metric):
-            detail = next(
-                (ln for ln in lines if metric in ln), f"{metric} regressed"
-            )
-            out = self.capture(
-                rule=f"regression_{metric}",
-                reason=detail.strip(),
-                severity="ALERT",
-                trigger="regression",
             )
             if out is not None:
                 made.append(out)
@@ -326,12 +333,8 @@ class FlightRecorder:
         return _ctx_of(env) if env else {}
 
     def _env(self):
-        # lazy: regress is jax-free but pulls glob/argparse machinery
-        # the hot path never needs
-        from . import regress as regress_lib
-
         try:
-            return regress_lib.env_fingerprint()
+            return env_fingerprint()
         except Exception as exc:  # fingerprinting must never kill capture
             return {"error": f"{type(exc).__name__}: {exc}"}
 

@@ -234,7 +234,7 @@ def record_migrate_steps(
     population totals). This is the bridge from the migrate loops — whose
     stats come back as ``[S, R]`` device arrays — to the host journal;
     calling it forces ONE host transfer of the (tiny) stats pytree, so
-    call it where the bench drivers already read stats, not inside a hot
+    call it where the caller already reads stats, not inside a hot
     loop. ``max_steps`` keeps only the trailing window.
     ``rank_totals=True`` additionally records the per-rank vectors
     (``sent_per_rank``/``received_per_rank``/``population_per_rank``

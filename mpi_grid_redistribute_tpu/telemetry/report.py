@@ -1,15 +1,13 @@
 """The metrics surface: one merged dict per exchange workload.
 
 The BASELINE metric is two-headed — "particles/sec/chip; ICI all_to_all
-BW utilization" — and before this module the utilization half lived as a
-hand-assembled expression in bench.py while the stats summaries lived in
-:mod:`..utils.stats`. :func:`exchange_report` merges the whole surface:
+BW utilization". :func:`exchange_report` merges the whole surface:
 stats summary, exchange bytes/step (total and moved/off-diagonal),
 achieved GB/s, ``bw_util`` against the domain roof
 (:func:`..utils.profiling.exchange_peak_bytes_per_sec`), and the
 recorder's growth/overflow event counts. ``GridRedistribute.report()``
-and every bench driver emit this dict, so the same numbers appear in
-tests, bench JSON and operator logs.
+emits this dict, so the same numbers appear in tests and operator
+logs.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Perfetto/Chrome-trace export of the telemetry journal.
 
-One command turns any bench run into a viewable timeline: the JSON this
+One command turns any journaled run into a viewable timeline: the JSON this
 module emits loads in Perfetto (ui.perfetto.dev) or ``chrome://tracing``
 — the standard Trace Event Format (``{"traceEvents": [...]}``, each
 event carrying ``ph``/``ts``/``pid``/``tid``/``name``).

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache for the entry points.
 
-``chip_smoke.py``, ``bench.py`` and ``python -m
+``chip_smoke.py`` and ``python -m
 mpi_grid_redistribute_tpu.service`` call :func:`enable` before their
 first compile; nothing calls it at import. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing is

@@ -6,8 +6,8 @@ equivalent — digitize / counting-sort pack / row gather — for the CPU
 oracle and host-side tooling. pybind11 is not in this image, so the C ABI
 + ctypes is the binding (no build-time Python deps).
 
-Building the .so is opt-in: call :func:`build` explicitly (bench drivers
-and tests do), or set ``MPI_GRID_NATIVE_BUILD=1`` to allow a g++ build on
+Building the .so is opt-in: call :func:`build` explicitly (the tests
+do), or set ``MPI_GRID_NATIVE_BUILD=1`` to allow a g++ build on
 first use. Only a library built from the committed files is loaded: a
 stamp next to the .so holds the hash of the source and ``build.sh`` (its
 flags), and a .so whose stamp does not match is rebuilt, or ignored where

@@ -9,9 +9,10 @@ utilization". Getting honest numbers on TPU needs care:
 
 :func:`scan_time_per_step` therefore compiles the step into ``lax.scan``
 loops of two lengths and differences the wall times — compile, dispatch,
-transfer and fetch costs cancel, leaving pure per-step device time. This is
-the method bench.py uses; it is exposed here for users profiling their own
-configurations.
+transfer and fetch costs cancel, leaving pure per-step device time. It is
+exposed here for users profiling their own configurations. The repo's
+benchmark (``benchmark/``) times whole calls on the host clock instead and
+reads per-layer device time from profiler traces.
 """
 
 from __future__ import annotations
@@ -48,48 +49,6 @@ def scan_time_per_step(
     measurement), and the long loop's output pytree lets callers inspect
     stats without paying another invocation.
     """
-    per_step, overhead, out, _ = _scan_time_impl(
-        make_loop, args, s1, s2, reps
-    )
-    return per_step, overhead, out
-
-
-def scan_time_per_step_samples(
-    make_loop: Callable[[int], Callable],
-    args,
-    s1: int = 8,
-    s2: int = 72,
-    reps: int = 4,
-):
-    """Min-of-k variant of :func:`scan_time_per_step` with spread.
-
-    Compiles the two loops ONCE, then takes ``reps`` independent long-loop
-    wall times; each yields its own per-step estimate against the best
-    short-loop time, so the k estimates measure run-to-run noise, not
-    compile noise (the protocol ``telemetry.regress`` documents: noise on
-    a quiet machine is one-sided — interference only ADDS time — so min
-    is the estimator and ``spread = (max-min)/min`` is the capture's own
-    noise floor).
-
-    Returns ``(detail, long_out)`` where ``detail`` is
-    ``{min, max, mean, spread, k, values}`` of per-step seconds.
-    """
-    per_step, _overhead, out, samples = _scan_time_impl(
-        make_loop, args, s1, s2, reps
-    )
-    lo, hi = min(samples), max(samples)
-    detail = {
-        "min": lo,
-        "max": hi,
-        "mean": sum(samples) / len(samples),
-        "spread": (hi - lo) / lo if lo > 0 else 0.0,
-        "k": len(samples),
-        "values": samples,
-    }
-    return detail, out
-
-
-def _scan_time_impl(make_loop, args, s1, s2, reps):
     if s2 <= s1:
         raise ValueError(f"need s2 > s1 for differencing, got {s1} >= {s2}")
     loops = {s: make_loop(s) for s in (s1, s2)}
@@ -100,9 +59,9 @@ def _scan_time_impl(make_loop, args, s1, s2, reps):
         times = []
         for _ in range(reps):
             # free the previous run's output BEFORE the next invocation:
-            # at bench sizes the output pytree is GB-scale device state,
-            # and holding two generations at once was the marginal
-            # allocation in config 2's 64M ResourceExhausted
+            # at chip sizes the output pytree is GB-scale device state,
+            # and holding two generations at once can be the marginal
+            # allocation that exhausts device memory
             out = None
             t0 = time.perf_counter()
             out = loops[s](*args)
@@ -114,10 +73,8 @@ def _scan_time_impl(make_loop, args, s1, s2, reps):
     del out1  # same: drop the short loop's state before the long compile
     times2, out2 = run(s2)
     t1 = min(times1)
-    # one per-step estimate per long rep, all against the best short time
-    samples = [(t2 - t1) / (s2 - s1) for t2 in times2]
-    per_step = min(samples)
-    return per_step, t1 - per_step * s1, out2, samples
+    per_step = (min(times2) - t1) / (s2 - s1)
+    return per_step, t1 - per_step * s1, out2
 
 
 # Published per-chip peaks, keyed by JAX's ``device_kind``. Source: Google
@@ -178,7 +135,7 @@ def exchange_peak_bytes_per_sec(domain: str,
                                 device_kind: str = TARGET_KIND) -> float:
     """Peak bytes/s for an exchange domain, per chip of ``device_kind``.
 
-    ``domain`` is the ``exchange_domain`` bench.py reports: ``"hbm"`` when
+    ``domain`` is the report's ``exchange_domain``: ``"hbm"`` when
     the vrank exchange stays on one chip, ``"ici"`` when rows ride the
     inter-chip all_to_all (all links active).
     """

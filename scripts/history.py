@@ -1,88 +1,30 @@
 #!/usr/bin/env python
-"""Run index: every store run + bench capture, one trajectory view.
+"""Run index: every journal-store run under a directory, one view.
 
-The repo accumulates two kinds of durable run evidence: committed
-``BENCH_r*.json`` captures (the regression-guard history ``bench_check``
-compares against) and ``telemetry.store`` journal-store roots (what a
-service driver started with ``--store-dir`` leaves behind). This script
-indexes both into one run-index, renders the perf trajectory across
-bench revisions, and feeds the whole indexed history into
-``regress.classify_capture`` so a fresh capture is judged against
-*every* usable run, not just whichever files a caller remembered to
-pass.
+A service driver started with ``--store-dir`` leaves a
+``telemetry.store`` journal-store root behind. This script indexes the
+roots under a directory into one run-index: writer, span, exact event
+totals, the merged-store p99 and the segment footprint of each run.
 
 Modes:
 
-  # human view: trajectory table + sparkline + indexed store runs
-  python scripts/history.py
+  # human view: the indexed store runs, newest first
+  python scripts/history.py --stores DIR
 
   # machine view: the full index as JSON (tooling / grid_top feeds)
-  python scripts/history.py --json
+  python scripts/history.py --stores DIR --json
 
-  # regression gate with cross-run context: classify one capture
-  # against the indexed history (exit 1 on REGRESSION)
-  python scripts/history.py --check capture.json
-
-``--bench GLOB`` and ``--stores DIR`` override where captures and
-store roots are discovered (defaults: ``BENCH_r*.json`` next to the
-repo root, no store scan unless ``--stores`` is given).
+Without ``--stores`` no directory is scanned and the index is empty.
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
-import re
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-_REPO = os.path.dirname(os.path.abspath(os.path.dirname(__file__)))
-_SPARK = "▁▂▃▄▅▆▇█"
-
-
-def _load(path):
-    with open(path, encoding="utf-8") as f:
-        return json.load(f)
-
-
-def index_benches(patterns):
-    """Index bench captures: one entry per readable ``BENCH_r*.json``
-    (revision number parsed from the filename, guarded metrics via
-    ``regress.extract_metrics``), ordered by revision."""
-    from mpi_grid_redistribute_tpu.telemetry import regress
-
-    entries = []
-    for pattern in patterns:
-        for path in sorted(glob.glob(pattern)):
-            try:
-                doc = _load(path)
-            except (OSError, ValueError) as e:
-                entries.append(
-                    {"path": path, "error": str(e), "metrics": None}
-                )
-                continue
-            m = re.search(r"r(\d+)", os.path.basename(path))
-            parsed = doc.get("parsed", doc) if isinstance(doc, dict) else {}
-            entries.append(
-                {
-                    "path": path,
-                    "rev": int(m.group(1)) if m else None,
-                    "metrics": regress.extract_metrics(doc),
-                    "spread": regress._spread_of(doc),
-                    "platform": (
-                        (regress._env_of(doc) or {}).get("platform")
-                    ),
-                    "config": parsed.get("config")
-                    if isinstance(parsed, dict)
-                    else None,
-                    "doc": doc,
-                }
-            )
-    entries.sort(key=lambda e: (e.get("rev") is None, e.get("rev"), e["path"]))
-    return entries
 
 
 def index_stores(root):
@@ -118,93 +60,36 @@ def index_stores(root):
     return entries
 
 
-def build_index(bench_patterns, stores_root=None):
-    benches = index_benches(bench_patterns)
-    index = {
-        "benches": [
-            {k: v for k, v in e.items() if k != "doc"} for e in benches
-        ],
-        "stores": index_stores(stores_root) if stores_root else [],
-    }
-    return index, benches
-
-
-def sparkline(values):
-    vals = [v for v in values if v is not None]
-    if not vals:
-        return ""
-    lo, hi = min(vals), max(vals)
-    span = (hi - lo) or 1.0
-    out = []
-    for v in values:
-        if v is None:
-            out.append(" ")
-        else:
-            out.append(_SPARK[int((v - lo) / span * (len(_SPARK) - 1))])
-    return "".join(out)
-
-
-def render_trajectory(benches, stores):
-    """Human view: the headline metric across revisions plus each
-    indexed store run."""
+def render_runs(stores):
+    """Human view: each indexed store run."""
     lines = ["run history"]
-    usable = [b for b in benches if b.get("metrics")]
-    if usable:
-        values = [b["metrics"].get("value") for b in usable]
-        lines.append(
-            "  bench trajectory (value = particles/sec/chip)   "
-            + sparkline(values)
-        )
-        best = max(v for v in values if v is not None)
-        for b in usable:
-            v = b["metrics"].get("value")
-            ms = b["metrics"].get("ms_per_step")
-            rel = f"{v / best * 100:5.1f}% of best" if v else ""
-            lines.append(
-                f"    r{b['rev']:02d}  value={v:.4g}"
-                + (f"  ms_per_step={ms:.4g}" if ms else "")
-                + (f"  [{b['platform']}]" if b.get("platform") else "")
-                + f"  {rel}"
-            )
+    if not stores:
+        lines.append("  (no store runs indexed; pass --stores DIR)")
     else:
-        lines.append("  (no usable bench captures)")
-    bad = [b for b in benches if b.get("error")]
-    for b in bad:
-        lines.append(f"    unreadable: {b['path']}: {b['error']}")
-    if stores:
         lines.append("  store runs (newest first)")
-        for s in stores:
-            if s.get("error"):
-                lines.append(f"    corrupt: {s['root']}: {s['error']}")
-                continue
-            writer = s.get("writer") or {}
-            p99 = s.get("p99_s")
-            lines.append(
-                f"    {s['root']}  steps={s['steps']}"
-                f"  events={s['events_total']}"
-                + (f"  p99={p99:.4g}s" if p99 is not None else "")
-                + f"  segs={s['segments']}(+{s['retired']})"
-                + (
-                    f"  writer={writer.get('host')}:{writer.get('pid')}"
-                    if writer
-                    else ""
-                )
+    for s in stores:
+        if s.get("error"):
+            lines.append(f"    corrupt: {s['root']}: {s['error']}")
+            continue
+        writer = s.get("writer") or {}
+        p99 = s.get("p99_s")
+        lines.append(
+            f"    {s['root']}  steps={s['steps']}"
+            f"  events={s['events_total']}"
+            + (f"  p99={p99:.4g}s" if p99 is not None else "")
+            + f"  segs={s['segments']}(+{s['retired']})"
+            + (
+                f"  writer={writer.get('host')}:{writer.get('pid')}"
+                if writer
+                else ""
             )
+        )
     return "\n".join(lines) + "\n"
 
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
-        description="Index bench captures + journal-store runs; render "
-        "the perf trajectory or gate a capture against it."
-    )
-    p.add_argument(
-        "--bench",
-        action="append",
-        default=[],
-        metavar="GLOB",
-        help="bench capture glob (default: BENCH_r*.json at the repo "
-        "root; repeatable)",
+        description="Index journal-store runs and render them."
     )
     p.add_argument(
         "--stores",
@@ -214,43 +99,15 @@ def main(argv=None) -> int:
     )
     p.add_argument("--json", action="store_true",
                    help="print the run-index as JSON and exit")
-    p.add_argument(
-        "--check",
-        metavar="CAPTURE",
-        help="classify CAPTURE (a bench JSON line or BENCH wrapper) "
-        "against the indexed history via regress.classify_capture; "
-        "exit 1 on REGRESSION",
-    )
-    p.add_argument("--threshold", type=float, default=0.10,
-                   help="regression threshold for --check")
     args = p.parse_args(argv)
 
-    patterns = args.bench or [os.path.join(_REPO, "BENCH_r*.json")]
-    index, benches = build_index(patterns, args.stores)
-
-    if args.check:
-        from mpi_grid_redistribute_tpu.telemetry import regress
-
-        try:
-            current = _load(args.check)
-        except (OSError, ValueError) as e:
-            print(f"history: cannot read capture: {e}", file=sys.stderr)
-            return 1
-        history = [b["doc"] for b in benches if b.get("metrics")]
-        ok, lines, _labels = regress.classify_capture(
-            current, history, threshold=args.threshold
-        )
-        print(f"history: capture vs {len(history)} indexed runs")
-        for ln in lines:
-            print("  " + ln)
-        return 0 if ok else 1
-
+    index = {"stores": index_stores(args.stores) if args.stores else []}
     if args.json:
         json.dump(index, sys.stdout, indent=1, sort_keys=True)
         sys.stdout.write("\n")
         return 0
 
-    sys.stdout.write(render_trajectory(benches, index["stores"]))
+    sys.stdout.write(render_runs(index["stores"]))
     return 0
 
 

@@ -3,10 +3,10 @@
 
 Thin CLI over :mod:`mpi_grid_redistribute_tpu.telemetry.incident`. A
 bundle directory is what the :class:`~...telemetry.incident
-.FlightRecorder` froze when an ALERT / injected fault / bench
-REGRESSION fired: the retained journal window, all-time counts, the
-rendered OpenMetrics exposition, health findings, flow snapshot, env
-fingerprint and the triggering step context, indexed by ``index.json``
+.FlightRecorder` froze when an ALERT or injected fault fired: the
+retained journal window, all-time counts, the rendered OpenMetrics
+exposition, health findings, flow snapshot, env fingerprint and the
+triggering step context, indexed by ``index.json``
 (layout: README "Incident response"). Three subcommands:
 
 * ``list DIR`` — one line per bundle (id, rule, trigger, capture time,

@@ -52,7 +52,7 @@ Journal sources, combinable:
 
 Examples:
 
-  # serve a bench run's shards pod-wide on :9100
+  # serve a run's journal shards pod-wide on :9100
   python scripts/metrics_serve.py --journal shard0.jsonl \\
       --journal shard1.jsonl --port 9100
 

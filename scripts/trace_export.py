@@ -12,7 +12,7 @@ Two input sources:
 
 Examples:
 
-  # journal from a bench run -> trace
+  # journal from a run -> trace
   python scripts/trace_export.py --journal run.jsonl --out trace.json
 
   # self-contained demo
@@ -68,15 +68,15 @@ def demo_recorder(steps: int = 16):
     import numpy as np
 
     from mpi_grid_redistribute_tpu import telemetry
-    from mpi_grid_redistribute_tpu.bench import common
+    from mpi_grid_redistribute_tpu.models import initial
     from mpi_grid_redistribute_tpu.models import nbody
     from mpi_grid_redistribute_tpu.domain import Domain
 
     grid_shape = (2, 2, 2)
-    dev_grid, vgrid, mesh, _ = common.pick_layout(grid_shape)
+    dev_grid, vgrid, mesh, _ = initial.pick_layout(grid_shape)
     rng = np.random.default_rng(0)
     n_local = 1 << 11
-    pos, _, alive = common.uniform_state(grid_shape, n_local, 0.9, rng)
+    pos, _, alive = initial.uniform_state(grid_shape, n_local, 0.9, rng)
     vel = (0.02 * (rng.random(pos.shape, dtype=np.float32) - 0.5)).astype(
         np.float32
     )

@@ -368,7 +368,7 @@ def test_device_planar_deposit_sharded_oracle(rng, _devices):
     from jax.sharding import PartitionSpec as P
     from jax import shard_map
     from mpi_grid_redistribute_tpu.ops import deposit as dep
-    from mpi_grid_redistribute_tpu.bench import common
+    from mpi_grid_redistribute_tpu.models import initial
 
     dom = Domain(0.0, 1.0, periodic=True)
     dev_grid = ProcessGrid((2, 2, 2))
@@ -383,7 +383,7 @@ def test_device_planar_deposit_sharded_oracle(rng, _devices):
             out_specs=dep.deposit_out_spec(dom, dev_grid),
         )
     )
-    pos, _, _ = common.uniform_state((2, 2, 2), n, 1.0, rng)
+    pos, _, _ = initial.uniform_state((2, 2, 2), n, 1.0, rng)
     pos_rows = np.ascontiguousarray(
         pos.reshape(8, n, 3).transpose(2, 0, 1)
     ).reshape(3, 8 * n)
@@ -421,8 +421,8 @@ def test_planar_deposit_conserves_and_places(rng, _devices):
             out_specs=dep.deposit_out_spec(dom, dev_grid),
         )
     )
-    from mpi_grid_redistribute_tpu.bench import common
-    pos, _, _ = common.uniform_state((2, 2, 2), n, 1.0, rng)
+    from mpi_grid_redistribute_tpu.models import initial
+    pos, _, _ = initial.uniform_state((2, 2, 2), n, 1.0, rng)
     pos_rows = np.ascontiguousarray(
         pos.reshape(8, n, 3).transpose(2, 0, 1)
     ).reshape(3, 8 * n)

@@ -296,21 +296,6 @@ def test_scan_faults_cursor_and_event_context(tmp_path):
     assert len(fr.scan_faults()) == 1
 
 
-def test_capture_regression_labels(tmp_path):
-    rec = StepRecorder()
-    _seeded_journal(rec)
-    fr = FlightRecorder(rec, str(tmp_path), clock=lambda: 9.0)
-    made = fr.capture_regression(
-        lines=["config1_pps REGRESSION -12% vs best", "other fine"],
-        labels={"config1_pps": "REGRESSION", "service_pps": "WOBBLE"},
-    )
-    assert len(made) == 1
-    index = json.load(open(os.path.join(made[0], "index.json")))
-    assert index["rule"] == "regression_config1_pps"
-    assert index["trigger"] == "regression"
-    assert "config1_pps" in index["reason"]
-
-
 def test_install_idempotent_across_monitor_restarts(tmp_path):
     rec = StepRecorder()
     mon1 = HealthMonitor(rec, rules=[])
@@ -560,3 +545,50 @@ def test_incident_cli_list_show_export(tmp_path, capsys):
     assert {"s", "f"} <= phases
     assert cli.main(["list", str(tmp_path / "empty")]) == 0
     assert "no bundles" in capsys.readouterr().out
+
+
+def test_env_fingerprint_reads_the_loaded_jax():
+    """``env.json``: with jax loaded (conftest.py imports it) the
+    fingerprint names its version and the 8 virtual CPU devices."""
+    import platform
+
+    import jax
+
+    fp = incident_lib.env_fingerprint()
+    assert fp["python"] == platform.python_version()
+    assert fp["jax"] == jax.__version__
+    assert fp["backend"] == "cpu"
+    assert fp["device_count"] == len(jax.devices())
+    json.dumps(fp)
+
+
+def test_env_fingerprint_never_imports_jax():
+    """A capture in a process that never loaded jax leaves it unloaded:
+    the fingerprint then carries no jax fields."""
+    import subprocess
+    import sys
+
+    tel = os.path.join(REPO, "mpi_grid_redistribute_tpu", "telemetry")
+    code = (
+        "import importlib.util, os, sys, types\n"
+        f"tel = {tel!r}\n"
+        "pkg = types.ModuleType('cap_pkg')\n"
+        "pkg.__path__ = [tel]\n"
+        "sys.modules['cap_pkg'] = pkg\n"
+        "for name in ('context', 'recorder', 'metrics', 'incident'):\n"
+        "    spec = importlib.util.spec_from_file_location(\n"
+        "        'cap_pkg.' + name, os.path.join(tel, name + '.py'))\n"
+        "    mod = importlib.util.module_from_spec(spec)\n"
+        "    sys.modules[spec.name] = mod\n"
+        "    spec.loader.exec_module(mod)\n"
+        "fp = sys.modules['cap_pkg.incident'].env_fingerprint()\n"
+        "assert 'jax' not in sys.modules, 'fingerprint imported jax'\n"
+        "assert 'jax' not in fp and 'python' in fp, fp\n"
+        "print('clean')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=REPO, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "clean"

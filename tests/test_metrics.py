@@ -18,8 +18,7 @@ Five contracts, each tested against hand math or a real scrape:
   without jax ever entering ``sys.modules`` (runtime subprocess check;
   gridlint G007 holds the static half in test_gridlint.py);
 * gating — the schema-drift gate (journaled kinds vs SCHEMA.md, both
-  directions) and the noise-aware bench classifier (r04→r05 wobble must
-  pass, a synthetic 2x slowdown must not).
+  directions).
 """
 
 import ast
@@ -42,15 +41,11 @@ from mpi_grid_redistribute_tpu.telemetry import (
     MergedJournal,
     MetricsRegistry,
     StepRecorder,
-    classify_capture,
-    classify_delta,
     from_journal,
     merge_journals,
-    noise_floor,
     pow2_edges,
 )
 from mpi_grid_redistribute_tpu.telemetry import metrics as metrics_lib
-from mpi_grid_redistribute_tpu.telemetry import regress
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PACKAGE = os.path.join(REPO_ROOT, "mpi_grid_redistribute_tpu")
@@ -695,106 +690,6 @@ def test_schema_drift_gate():
     assert not dead, (
         f"SCHEMA.md documents kinds nothing records: {sorted(dead)}"
     )
-
-
-# ------------------------------------------------ noise-aware classifier
-
-
-# the parsed numbers of the five pre-PR-1 captures (BENCH_r01..r05,
-# jax 0.4.37): the classifier's fixed test history
-BENCH_HISTORY = os.path.join(
-    REPO_ROOT, "tests", "fixtures", "bench_history", "BENCH_r*.json"
-)
-
-
-def _bench_history():
-    caps = []
-    for i in range(1, 6):
-        with open(BENCH_HISTORY.replace("*", f"{i:02d}")) as fh:
-            caps.append(json.load(fh))
-    return caps
-
-
-def test_classify_delta_boundaries():
-    assert classify_delta(0.0, 0.10) == "OK"
-    assert classify_delta(-0.3, 0.10) == "OK"
-    assert classify_delta(0.05, 0.10) == "WOBBLE"
-    assert classify_delta(0.15, 0.10, threshold=0.10) == "WARN"
-    assert classify_delta(0.25, 0.10, threshold=0.10) == "REGRESSION"
-    floor, defaulted = noise_floor(None, None)
-    assert floor == pytest.approx(1.25 * 0.08) and defaulted
-    floor, defaulted = noise_floor(0.16, 0.04)
-    assert floor == pytest.approx(0.20) and not defaulted
-
-
-def test_r04_to_r05_wobble_passes_the_gate():
-    """The one measured wobble in committed history: r05's headline is
-    7.9-8.6% below r04 on byte-identical exchange work. The noise-aware
-    gate must classify it WOBBLE and pass; the legacy binary gate is the
-    behavior this replaces."""
-    caps = _bench_history()
-    ok, lines, labels = classify_capture(caps[-1], caps[:-1])
-    assert ok, "\n".join(lines)
-    assert labels["value"] == "WOBBLE", (labels, lines)
-    assert set(labels.values()) <= {"OK", "WOBBLE"}, lines
-
-
-def test_synthetic_2x_slowdown_is_regression():
-    caps = _bench_history()
-    metrics = regress.extract_metrics(caps[-1])
-    worse = {
-        k: (v / 2 if regress.GUARDED_METRICS[k] == "higher" else v * 2)
-        for k, v in metrics.items()
-    }
-    ok, lines, labels = classify_capture({"parsed": worse}, caps)
-    assert not ok, "\n".join(lines)
-    assert labels["value"] == "REGRESSION", (labels, lines)
-
-
-def test_progprofile_hash_drift_notes():
-    """A capture taken under a different progcheck wire-model hash than
-    the best capture gets a correlation note (the delta may be the
-    intentional J004-gated change); same hash or missing hashes stay
-    silent."""
-    caps = _bench_history()
-    metrics = regress.extract_metrics(caps[-1])
-    # synthetic best that wins the per-metric pick over all committed
-    # captures, so ITS hash is the one the note compares against
-    best = {
-        k: (v * 2 if regress.GUARDED_METRICS[k] == "higher" else v / 2)
-        for k, v in metrics.items()
-    }
-    best["progprofile_hash"] = "aaaa000011112222"
-
-    def run(cur_hash):
-        cur = dict(metrics)
-        if cur_hash is not None:
-            cur["progprofile_hash"] = cur_hash
-        _, lines, _ = classify_capture(
-            {"parsed": cur}, caps + [{"parsed": best}]
-        )
-        return [ln for ln in lines if "wire model changed" in ln]
-
-    drift = run("bbbb333344445555")
-    assert len(drift) == 1, drift
-    assert "aaaa000011112222" in drift[0]
-    assert "bbbb333344445555" in drift[0]
-    assert "J004" in drift[0]
-    assert run("aaaa000011112222") == []  # same hash: no note
-    assert run(None) == []  # current predates the embed: no note
-
-
-def test_bench_check_cli_passes_on_committed_history():
-    """Satellite wiring: `make bench-check` runs the classifier and a
-    WOBBLE-grade delta (the fixture's r04→r05 history) must exit 0."""
-    out = subprocess.run(
-        [sys.executable, os.path.join("scripts", "bench_check.py"),
-         "--history", BENCH_HISTORY],
-        capture_output=True, text=True, cwd=REPO_ROOT, timeout=120,
-    )
-    assert out.returncode == 0, out.stdout + out.stderr
-    assert "bench-check ok" in out.stdout
-    assert "WOBBLE" in out.stdout
 
 
 # ------------------------------------------------- steady-state overhead

@@ -536,6 +536,51 @@ def test_migrate_random_pressure_conserves(seed, _devices):
     assert a2.sum() == alive.sum()
 
 
+def test_migrate_vranks_clustered_placement_drains_lossless(rng, _devices):
+    """Cold-start placement of log-normal clustered rows that start on
+    arbitrary vranks of a 4x4x4 grid (64 vranks on one device): dt=0
+    steps at a modest per-pair capacity backlog the excess instead of
+    dropping it, and the backlog drains until every row is owned."""
+    from mpi_grid_redistribute_tpu import oracle
+
+    grid = ProcessGrid((4, 4, 4))
+    R, n_local = grid.nranks, 256
+    domain = Domain(0.0, 1.0, periodic=True)
+    pos = (rng.lognormal(0.0, 1.0, size=(R * n_local, 3)) % 1.0).astype(
+        np.float32
+    )
+    vel = np.zeros_like(pos)
+    alive = np.tile(np.arange(n_local) < n_local // 2, R)
+    cap = 64
+    cfg = nbody.DriftConfig(
+        domain=domain, grid=ProcessGrid((1, 1, 1)), dt=0.0, capacity=cap,
+        n_local=n_local, local_budget=4 * cap,
+    )
+    mesh = mesh_lib.make_mesh(cfg.grid, devices=jax.devices()[:1])
+    loop = nbody.make_migrate_loop(cfg, mesh, 8, vgrid=grid)
+    state = (
+        nbody.rows_to_planar(pos, 1), nbody.rows_to_planar(vel, 1), alive
+    )
+    sent = dropped = 0
+    for _ in range(8):
+        p, v, a, st = jax.tree.map(np.asarray, loop(*state))
+        state = (p, v, a)
+        sent += int(st.sent.sum())
+        dropped += int(st.dropped_recv.sum())
+        assert int(a.sum()) == int(alive.sum())
+        if st.sent[-1].sum() == 0:
+            break
+    assert st.sent[-1].sum() == 0, "placement backlog did not drain"
+    assert dropped == 0
+    assert sent > 0
+    p, a = nbody.planar_to_rows(state[0], 3, mesh.size), state[2]
+    oracle.assert_ownership(
+        domain, grid,
+        [p[r * n_local : (r + 1) * n_local][a[r * n_local : (r + 1) * n_local]]
+         for r in range(R)],
+    )
+
+
 def test_balanced_assignment_properties():
     from mpi_grid_redistribute_tpu.parallel import migrate
 

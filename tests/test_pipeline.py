@@ -13,8 +13,9 @@ the sequential builder's macro bit-exactly (including its
 event. The overlap itself is a TRACE property, asserted on the jaxpr:
 the steady-state cond's pipelined branch issues step k+1's binning
 (``floor``) before step k's landing consumer (``scatter``); the
-sequential branch does the opposite. Service-shape speedups are gated
-by ``bench/config10_service.py`` (``make service-bench``), not here.
+sequential branch does the opposite. Service-shape speedups are not
+timed here: a speed is measured on the chip, and no benchmark cell
+times the service path yet.
 """
 
 import dataclasses

@@ -10,8 +10,8 @@ journaled ``(step, dropped)`` stream. The jax resident path itself
 on the 8-virtual-device mesh — chunk-vs-eager particle-set identity,
 misaligned snapshot cadence, and a jaxpr walk proving the traced macro
 program carries no host callbacks (the dynamic backstop behind gridlint
-rule G009). Service-shape speedups are gated by
-``bench/config10_service.py`` (``make service-bench``), not here.
+rule G009). Service-shape speedups are not timed here: a speed is
+measured on the chip, and no benchmark cell times the service path yet.
 """
 
 import dataclasses

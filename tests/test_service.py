@@ -2,8 +2,10 @@
 
 Everything runs the numpy backend at tiny sizes — the recovery logic
 under test is backend-independent, and the CPU oracle keeps the whole
-fault matrix inside the tier-1 budget. The jax path is covered by the
-config8 soak bench and ``scripts/pod_smoke.py --kill-restore``.
+fault matrix inside the tier-1 budget. The jax path's crash and
+device-loss restores run here on the 8 CPU devices
+(``test_jax_backend_supervised_restore``); ``chip_smoke.py`` and
+``scripts/pod_smoke.py --kill-restore`` restore a snapshot on a chip.
 """
 
 import dataclasses
@@ -286,6 +288,35 @@ def test_device_loss_shrink_restore_preserves_particle_set(tmp_path):
     assert int(sup.driver.state[3].sum()) == int(ref[3].sum())
     assert elastic.particle_set(*sup.driver.state) == \
         elastic.particle_set(*ref)
+
+
+@pytest.mark.parametrize("leg", ["crash", "device_loss"])
+def test_jax_backend_supervised_restore(tmp_path, leg):
+    """The recovery legs on the jax backend (a 2x2x2 grid, one rank on
+    each of the 8 CPU devices): one injected crash restores
+    bit-identically to the uninterrupted run; a crash plus the loss of
+    half the devices shrink-restores onto a smaller grid with the same
+    id-sorted particle set."""
+    cfg = _cfg(
+        tmp_path, backend="jax", steps=12, snapshot_every=3, seed=11,
+    )
+    faults = [CrashFault(7)]
+    if leg == "device_loss":
+        faults.append(DeviceLossFault(4))
+    sup, rec = _supervised(tmp_path, cfg, FaultPlan(faults))
+    verdict = sup.run()
+    ref = _reference_state(cfg)
+
+    assert verdict.ok is True, verdict
+    assert verdict.restarts == 1
+    assert verdict.step == cfg.steps
+    if leg == "crash":
+        _assert_bit_identical(sup.driver.state, ref)
+    else:
+        assert tuple(sup.driver.cfg.grid_shape) != cfg.grid_shape
+        assert len(rec.events("reshard")) == 1
+        assert elastic.particle_set(*sup.driver.state) == \
+            elastic.particle_set(*ref)
 
 
 def test_restore_latest_onto_explicit_grid(tmp_path):

@@ -1,5 +1,5 @@
-"""Telemetry package: recorder, report math, profiler sessions, trace
-export, regress gate. All CPU-runnable (tier 1); device work uses the 8
+"""Telemetry package: recorder, report math, profiler sessions and trace
+export. All CPU-runnable (tier 1); device work uses the 8
 virtual CPU devices from conftest.py."""
 
 import json
@@ -11,10 +11,7 @@ from mpi_grid_redistribute_tpu.parallel.exchange import RedistributeStats
 from mpi_grid_redistribute_tpu.parallel.migrate import MigrateStats
 from mpi_grid_redistribute_tpu.telemetry import (
     StepRecorder,
-    check_capture,
     exchange_report,
-    extract_metrics,
-    min_of_k,
     record_migrate_steps,
     row_bytes_of,
 )
@@ -356,91 +353,3 @@ def test_traceview_instant_args_are_json_safe():
     assert {e["pid"] for e in doc["traceEvents"]} == {0, 2}
     assert not any(e["ph"] == "X" for e in doc["traceEvents"])
     json.dumps(doc)  # stays serializable
-
-
-# ----------------------------------------------------------------- regress
-
-
-def _capture(value=100.0, ms=10.0, xbps=1e8, wrap=False):
-    line = {
-        "metric": "particles_per_sec_per_chip",
-        "value": value,
-        "ms_per_step": ms,
-        "exchange_bytes_per_sec": xbps,
-    }
-    if wrap:
-        return {"n": 1, "cmd": "python bench.py", "rc": 0, "parsed": line}
-    return line
-
-
-def test_min_of_k_protocol():
-    it = iter([3.0, 1.0, 2.0])
-    d = min_of_k(lambda: next(it), k=3)
-    assert d["min"] == 1.0 and d["max"] == 3.0
-    assert d["spread"] == pytest.approx(2.0)
-    assert d["k"] == 3 and len(d["values"]) == 3
-    with pytest.raises(ValueError):
-        min_of_k(lambda: 1.0, k=0)
-
-
-def test_extract_metrics_handles_wrappers():
-    assert extract_metrics(_capture())["value"] == 100.0
-    assert extract_metrics(_capture(wrap=True))["ms_per_step"] == 10.0
-    assert extract_metrics({"parsed": None}) is None
-    assert extract_metrics({"tail": "crashed"}) is None
-
-
-def test_check_capture_accepts_within_threshold():
-    ok, lines = check_capture(
-        _capture(value=95.0), [_capture(value=100.0), _capture(value=90.0)]
-    )
-    assert ok, lines
-    assert any(ln.startswith("warn") for ln in lines)
-
-
-def test_check_capture_rejects_regressions():
-    # 20% throughput drop vs best
-    ok, lines = check_capture(_capture(value=80.0), [_capture(value=100.0)])
-    assert not ok
-    assert any(ln.startswith("FAIL") and "value" in ln for ln in lines)
-    # times regress UPWARD
-    ok, lines = check_capture(_capture(ms=12.5), [_capture(ms=10.0)])
-    assert not ok
-    assert any("ms_per_step" in ln and ln.startswith("FAIL") for ln in lines)
-
-
-def test_check_capture_compares_against_best_not_latest():
-    # history drifted down; the gate must still hold the line at the best
-    history = [_capture(value=100.0), _capture(value=92.0, wrap=True)]
-    ok, _ = check_capture(_capture(value=88.0), history)
-    assert not ok  # 12% below the 100.0 best, despite being ~4% below latest
-
-
-def test_check_capture_skips_missing_metrics():
-    cur = {"value": 100.0, "metric": "x"}  # no ms_per_step in current
-    ok, lines = check_capture(cur, [_capture()])
-    assert ok
-    assert any(ln.startswith("skip") and "ms_per_step" in ln for ln in lines)
-
-
-def test_regress_cli_on_fixture_files(tmp_path):
-    from mpi_grid_redistribute_tpu.telemetry import regress
-
-    good = tmp_path / "BENCH_r01.json"
-    good.write_text(json.dumps(_capture(value=100.0, wrap=True)))
-    bad = tmp_path / "current_bad.json"
-    bad.write_text(json.dumps(_capture(value=70.0)))
-    okc = tmp_path / "current_ok.json"
-    okc.write_text(json.dumps(_capture(value=99.0)))
-
-    hist = str(tmp_path / "BENCH_r*.json")
-    assert regress.main(["--current", str(okc), "--history", hist]) == 0
-    assert regress.main(["--current", str(bad), "--history", hist]) == 1
-    assert regress.main(["--history", str(tmp_path / "nope*.json")]) == 2
-
-
-def test_regress_cli_self_test_on_committed_history():
-    # the acceptance gate: the repo's own committed history must pass
-    from mpi_grid_redistribute_tpu.telemetry import regress
-
-    assert regress.main([]) == 0

@@ -8,15 +8,16 @@ kernel whose shape contract failed would fall back to XLA and hold none.
 
 Shapes:
 
-* driftbin -- the ``bench.py`` headline: V=8 vranks of n=2**20 rows,
-  K=7 planar int32 rows;
+* driftbin -- the ``drift8v.steady`` benchmark cell: V=8 vranks of
+  n=2**20 rows, K=7 planar int32 rows;
 * overlay (int8) -- the headline landing width m = 8 * 2**20 columns,
   block width W=4096, K=7, with 4,096 updates. The headline's 196,608
   updates compile in ~100 s, all of it in XLA's 8-operand payload sort
   around the kernel, not in the kernel (0.3 s alone at that m); its
   compile time grows with the update count, not with m;
-* segdep and dfscan -- config 5 (``bench/config5_deposit.py``): 8.4M
-  rows, 2x2x2 vranks, a 128**3 mesh (64**3 cells per vrank), tile 256.
+* segdep and dfscan -- BASELINE.json config 5 (a CIC deposit fused
+  with the drift loop): 8.4M rows, 2x2x2 vranks, a 128**3 mesh (64**3
+  cells per vrank), tile 256.
 
 The topology is described inside a fixture, never at import, so every
 xdist worker collects the same tests and only the one that runs them
