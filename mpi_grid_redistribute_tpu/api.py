@@ -867,8 +867,8 @@ class GridRedistribute:
         )
         return True
 
-    def _journal_planar_degrade(self, eng: str, B: int, cap: int, rec,
-                                detail: dict) -> None:
+    def _journal_planar_degrade(self, eng: str, B: int, cap: int,
+                                rec) -> None:
         """Journal a count-driven or hierarchical engine falling to the
         dense planar pool because the grown mover block ``B`` reached
         the capacity — once, when it changes the engine of the last
@@ -885,11 +885,10 @@ class GridRedistribute:
                     f"count-driven pool no smaller than dense"
                 ),
                 canonical=True,
-                **detail,
+                **(self._last_payload or {}),
             )
 
-    def _hierarchical_fn(self, cap: int, out_cap: int, specs, rec,
-                         planar_detail: dict):
+    def _hierarchical_fn(self, cap: int, out_cap: int, specs, rec):
         """Build the hierarchical two-level call for these capacities,
         or return ``None`` to degrade to planar when the grown mover
         block already reached the dense pool size (mirroring the
@@ -910,9 +909,7 @@ class GridRedistribute:
             )
         B = self._mover_cap_for(cap)
         if B >= cap:
-            self._journal_planar_degrade(
-                "hierarchical", B, cap, rec, planar_detail
-            )
+            self._journal_planar_degrade("hierarchical", B, cap, rec)
             return None
         B2 = self._cross_cap_for(cap)
         hier = self._hier
@@ -1053,19 +1050,7 @@ class GridRedistribute:
         # engine_resolved whenever the routing inputs change.
         n_dev = 1 if self._vranks else int(self.mesh.devices.size)
         R = self.nranks
-        # how the vrank planar engine packs at this capacity: journaled
-        # with a planar resolution, again when a capacity change flips it
-        pack_path = (
-            exchange.vrank_pack_path(R, cap, positions.shape[0] // R)
-            if self._vranks and specs is not None
-            else None
-        )
-        planar_detail = dict(self._last_payload or {})
-        if pack_path is not None:
-            planar_detail["pack"] = pack_path
-        res_key = (
-            self.engine, self._vranks, specs is not None, n_dev, pack_path
-        )
+        res_key = (self.engine, self._vranks, specs is not None, n_dev)
         rec = None
         if res_key != self._last_resolution:
             self._last_resolution = res_key
@@ -1074,13 +1059,11 @@ class GridRedistribute:
             self.engine, vranks=self._vranks, n_devices=n_dev,
             planar_ok=specs is not None, canonical=True,
             n_pods=self.n_pods, recorder=rec, planar_why=why,
-            detail=self._last_payload, planar_detail=planar_detail,
+            detail=self._last_payload,
         )
         dense_cols = R * cap
         if resolved == "hierarchical" and specs is not None:
-            fn = self._hierarchical_fn(
-                cap, out_cap, specs, rec, planar_detail
-            )
+            fn = self._hierarchical_fn(cap, out_cap, specs, rec)
             if fn is not None:
                 return fn
             resolved = "planar"
@@ -1089,9 +1072,7 @@ class GridRedistribute:
             if B >= cap:
                 # the grown mover block reached the dense pool size: the
                 # count-driven engine would be a no-op wrapper, run planar
-                self._journal_planar_degrade(
-                    resolved, B, cap, rec, planar_detail
-                )
+                self._journal_planar_degrade(resolved, B, cap, rec)
                 resolved = "planar"
             else:
                 if resolved == "neighbor":

@@ -373,6 +373,12 @@ def sort_by_dest(dest, n_dest: int, payload):
     ``pack.planar_compact_with_self``): this moves the payload through
     the sort network instead of gathering it by ``order`` afterwards.
     Any 32-bit payload dtype; int32 keeps every bit pattern.
+
+    Rows under the sentinel ``n_dest`` keep their source order too: where
+    the caller marks its own (valid) rows and the rows past its count
+    with the sentinel, the own rows are the first of that segment, from
+    ``bounds[n_dest]``, since every own row's index is below the count.
+    ``pack.land_windows`` copies them from there as one window.
     """
     key, b = dest_sort_key(dest, n_dest)
     out = jax.lax.sort(

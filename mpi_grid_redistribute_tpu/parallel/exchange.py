@@ -52,7 +52,6 @@ def resolve_engine(
     recorder=None,
     planar_why: str = None,
     detail: dict = None,
-    planar_detail: dict = None,
 ) -> str:
     """Resolve a user-facing engine name to a concrete engine — the ONE
     dispatch rule shared by :class:`..api.Redistributer` (canonical
@@ -86,9 +85,7 @@ def resolve_engine(
     ``recorder`` (a :class:`..telemetry.StepRecorder`) journals the
     decision as an ``engine_resolved`` event — chosen engine plus the
     reason, including any degradation — so silent routing is observable;
-    ``detail`` adds its keys to the event (the api's ``payload_words``),
-    and ``planar_detail`` takes its place when the resolution is
-    ``"planar"`` (the api adds the vrank engine's ``pack`` path).
+    ``detail`` adds its keys to the event (the api's ``payload_words``).
     """
     if engine not in ENGINES:
         raise ValueError(
@@ -147,8 +144,6 @@ def resolve_engine(
         else:
             resolved, reason = "planar", "migrate: dense planar step"
     if recorder is not None:
-        if resolved == "planar" and planar_detail is not None:
-            detail = planar_detail
         recorder.record(
             "engine_resolved",
             requested=engine,
@@ -360,24 +355,6 @@ def vrank_redistribute_fn(
     return fn
 
 
-def vrank_pack_path(n_ranks: int, capacity: int, n: int) -> str:
-    """How :func:`vrank_redistribute_planar_fn` fills its ``[K, R*C]``
-    send pool from ``n`` columns a vrank: ``"sort"`` carries the rows
-    through the destination sort and copies one window a destination;
-    ``"gather"`` sorts the key alone and gathers the pool's columns.
-
-    The sort moves ``n * (K + 1)`` words through the network, the
-    gathers cost per pool column. Measured on a v5e at 8 vranks x
-    ``K = 8`` x ``2^20`` columns (PERF.md): the payload sort 59 ms, ~7 ns
-    a column; the gathers 457 ms over ``R * C = 2n``, ~27 ns a pool
-    column; so the sort wins above ``R * C`` ~ 0.12n and loses at
-    ``C = n / 64``. ``R * C >= n`` is a conservative, round threshold:
-    the default capacity (``api``'s ``capacity_factor`` 2.0) gives
-    ``R * C >= 2n`` and always sorts; a small explicit capacity
-    gathers."""
-    return "sort" if n_ranks * capacity >= n else "gather"
-
-
 def vrank_redistribute_planar_fn(
     domain: Domain,
     grid: ProcessGrid,
@@ -407,7 +384,7 @@ def vrank_redistribute_planar_fn(
     way in/out (:func:`..migrate.fuse_fields` semantics, minus the alive
     row — validity here is the count prefix, as everywhere on the
     canonical path). ``fused`` may be float32 or int32; either way the
-    TRANSPORT (pack, wire, compaction sort) runs on an int32 bitcast
+    TRANSPORT (pack, wire, landing) runs on an int32 bitcast
     view — TPU float vector copies flush denormal f32 bit patterns to
     zero (any bitcast int < 2^23; measured through the pack gather at
     ~3k rows/shard — the hazard ops/pallas_overlay.py biases around),
@@ -415,13 +392,17 @@ def vrank_redistribute_planar_fn(
     (denormals, NaN payloads, -0.0) survives bit-exactly by
     construction. Output dtype matches the input.
 
-    The pack is this engine's own (:func:`vrank_pack_path`): at the
-    default capacity the rows ride the destination sort
-    (``binning.sort_by_dest``) and each destination's slots are one
-    window of the sorted rows (``pack.pack_windows``); a capacity with
-    ``R * C < n`` sorts the key alone and gathers (``pack.pack_cols``).
-    Both fill slot ``(d, c)`` from the same column, so the paths are
-    bit-identical.
+    The rows ride the destination sort (``binning.sort_by_dest``), so
+    each destination's slots are one window of them
+    (``pack.pack_windows``). Each vrank's own rows are one window too,
+    the head of the sentinel segment, and each source's rows the valid
+    prefix of its pool window, so ``pack.land_windows`` copies the V
+    windows into Alltoallv receive order, source by source, with no
+    sort. This path serves every capacity: a key sort with gathers
+    leaves the own rows scattered, so it needs the compaction sort over
+    ``n + R * C`` columns, and at ``C = n / 64``, where its pack alone
+    is cheaper, it took 108.7 ms a call against this path's 56.7 on a
+    v5e (8 vranks x ``K = 8`` x ``2^20`` rows, PERF.md).
     """
     V = grid.nranks
     C = capacity
@@ -450,8 +431,6 @@ def vrank_redistribute_planar_fn(
         n = fused.shape[2]
         me_ids = jnp.arange(V, dtype=jnp.int32)
 
-        sort_pack = vrank_pack_path(V, C, n) == "sort"
-
         def bin_one(pos_v, count_v, me, fi_v):
             valid = jnp.arange(n, dtype=jnp.int32) < count_v
             dest = binning.rank_of_position_planar(
@@ -460,40 +439,32 @@ def vrank_redistribute_planar_fn(
             dest = jnp.where(valid, dest, V).astype(jnp.int32)
             is_self = valid & (dest == me)
             dest_remote = jnp.where(is_self, V, dest)
-            if sort_pack:
-                # the rows ride the destination sort: [K, n] in order
-                plan, remote_counts, bounds = binning.sort_by_dest(
-                    dest_remote, V, fi_v
-                )
-            else:
-                plan, remote_counts, bounds = binning.sorted_dest_counts(
-                    dest_remote, V
-                )
-            return plan, remote_counts, bounds, is_self
-
-        def pack_one(fi_v, plan, bounds, send_counts_v):
-            if sort_pack:
-                return pack.pack_windows(plan, bounds[:V], send_counts_v, C)
-            packed, _ = pack.pack_cols(
-                fi_v, plan, bounds[:V], send_counts_v, V, C
+            # the rows ride the destination sort: [K, n] in order
+            rows, remote_counts, bounds = binning.sort_by_dest(
+                dest_remote, V, fi_v
             )
-            return packed  # [K, V*C] int32
+            n_self = jnp.sum(is_self, dtype=jnp.int32)
+            return rows, remote_counts, bounds, n_self
 
         # each phase's scope wraps its vmap, so the ops carry the scope
         # itself and not a vmap(...) of it
         with traced_span("rd:bin"):
-            plan, remote_counts, bounds, is_self = jax.vmap(bin_one)(
+            rows, remote_counts, bounds, self_count = jax.vmap(bin_one)(
                 pos_f, count, me_ids, fi
             )
             dropped_send = jnp.sum(jnp.maximum(remote_counts - C, 0), axis=1)
             send_counts = jnp.minimum(remote_counts, C)
             needed = jnp.max(remote_counts, axis=1).astype(jnp.int32)
             recv_counts = send_counts.T  # [V_dst, V_src]
-            self_diag = jnp.diag(jnp.sum(is_self.astype(jnp.int32), axis=1))
+            self_diag = jnp.diag(self_count)
             send_stats = send_counts + self_diag
             recv_stats = recv_counts + self_diag
         with traced_span("rd:pack"):
-            packed = jax.vmap(pack_one)(fi, plan, bounds, send_counts)
+            packed = jax.vmap(
+                lambda rows_v, bounds_v, sc_v: pack.pack_windows(
+                    rows_v, bounds_v[:V], sc_v, C
+                )
+            )(rows, bounds, send_counts)  # [V, K, V*C] int32
         K = fused.shape[1]
         # the wire, as a transpose: [V_src, K, V_dst, C] -> dst-major pools
         with traced_span("rd:exchange"):
@@ -502,19 +473,10 @@ def vrank_redistribute_planar_fn(
                 .transpose(2, 1, 0, 3)
                 .reshape(V, K, V * C)
             )
-
-        def compact_one(pool_v, rcnt_v, me, self_mask_v, fi_v):
-            # Alltoallv-order compaction via a payload-carrying sort —
-            # shared with the shard_map planar twin so the two engines
-            # cannot drift (see pack.planar_compact_with_self for the
-            # measured rationale). int32 operands throughout.
-            return pack.planar_compact_with_self(
-                pool_v, rcnt_v, me, self_mask_v, fi_v, out_capacity
-            )
-
         with traced_span("rd:unpack"):
-            out, new_count, dropped_recv = jax.vmap(compact_one)(
-                recv, recv_counts, me_ids, is_self, fi
+            out, new_count, dropped_recv = pack.land_windows(
+                recv, recv_counts, rows, bounds[:, V], self_count,
+                out_capacity,
             )
         if as_f32:
             out = lax.bitcast_convert_type(out, jnp.float32)

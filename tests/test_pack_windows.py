@@ -1,13 +1,13 @@
-"""The one-call plan's two pack paths give the same send pool, bit for bit.
+"""The one-call plan's window pack gives the gather pack's send pool, bit
+for bit.
 
-At the default capacity the planar vrank engine carries each vrank's rows
-through its destination sort (``binning.sort_by_dest``) and copies each
-destination's slots as one window of the sorted rows
-(``pack.pack_windows``); with a capacity so small that ``R * C < n`` it
-sorts the key alone and gathers (``binning.sorted_dest_counts`` +
+The planar vrank engine carries each vrank's rows through its destination
+sort (``binning.sort_by_dest``) and copies each destination's slots as one
+window of the sorted rows (``pack.pack_windows``); the other planar
+engines sort the key alone and gather (``binning.sorted_dest_counts`` +
 ``pack.pack_cols``). Slot ``(d, c)`` takes column ``order[bounds[d] + c]``
 either way, so the pools, counts and bounds must match exactly, and the
-whole call must match the numpy backend on both paths.
+whole call must match the numpy backend at every capacity.
 """
 
 import jax
@@ -17,7 +17,6 @@ import pytest
 
 from mpi_grid_redistribute_tpu import Domain, GridRedistribute
 from mpi_grid_redistribute_tpu.ops import binning, pack
-from mpi_grid_redistribute_tpu.parallel import exchange
 
 # int32 words that a float view would flush or canonicalize: denormals,
 # -0.0, quiet and signalling NaNs with payloads, the extremes
@@ -99,14 +98,6 @@ def test_sort_by_dest_plain_key_equals_sorted_dest_counts():
     assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
 
 
-@pytest.mark.parametrize("R, C, n, want", [
-    (8, 1, 8, "sort"), (8, 1, 9, "gather"), (8, 64, 256, "sort"),
-    (8, 16, 256, "gather"), (64, 2048, 65536, "sort"),
-])
-def test_vrank_pack_path_rule(R, C, n, want):
-    assert exchange.vrank_pack_path(R, C, n) == want
-
-
 # ------------------------------------------------------------ the call
 
 DOMAIN = Domain(0.0, 1.0, periodic=True)
@@ -147,25 +138,22 @@ def _assert_same(res, ref):
                 == np.asarray(getattr(ref.stats, name)).tobytes()), name
 
 
-def _pack_events(rd):
-    return [e.data.get("pack") for e in rd.telemetry.events()
-            if e.kind == "engine_resolved"]
-
-
-@pytest.mark.parametrize("label, kw, stay, pack_path", [
-    # the default capacity: R * C = 2n, the sorted path
-    ("default", dict(out_capacity=320), 0.0, "sort"),
-    # few movers at a capacity with R * C = n / 2: the gather path
-    ("small_capacity", dict(capacity=16, out_capacity=320), 0.9, "gather"),
-    # every row to rank 0, R * C = 2n: the sorted path overflows C
-    ("overflow_sorted", dict(capacity=64, out_capacity=320,
-                             on_overflow="ignore"), "rank0", "sort"),
-    # uniform rows, R * C = n / 4: the gather path overflows C
-    ("overflow_gather", dict(capacity=8, out_capacity=320,
-                             on_overflow="ignore"), 0.0, "gather"),
+@pytest.mark.parametrize("label, kw, stay", [
+    # the default capacity: R * C = 2n
+    ("default", dict(out_capacity=320), 0.0),
+    # few movers at a capacity with R * C = n / 2
+    ("small_capacity", dict(capacity=16, out_capacity=320), 0.9),
+    # every row to rank 0, R * C = 2n: the pack overflows C
+    ("overflow_wide", dict(capacity=64, out_capacity=320,
+                            on_overflow="ignore"), "rank0"),
+    # uniform rows, R * C = n / 4: the pack overflows C
+    ("overflow_small_capacity", dict(capacity=8, out_capacity=320,
+                                     on_overflow="ignore"), 0.0),
 ])
 def test_auto_vranks_equals_numpy_on_both_pack_paths(one_device, label, kw,
-                                                     stay, pack_path):
+                                                     stay):
+    """The window pack and landing at capacities on either side of
+    ``R * C = n`` equal the numpy backend."""
     n_local = 256
     pos, ids, words = _rows(11, n_local, 0.0 if stay == "rank0" else stay)
     if stay == "rank0":
@@ -175,23 +163,6 @@ def test_auto_vranks_equals_numpy_on_both_pack_paths(one_device, label, kw,
     rd = GridRedistribute(DOMAIN, GRID, engine="auto", **kw)
     res = rd.redistribute(pos, ids, words)
     assert rd._vranks
-    assert _pack_events(rd) == [pack_path]
     _assert_same(res, ref)
     dropped = int(np.asarray(res.stats.dropped_send).sum())
     assert (dropped > 0) == label.startswith("overflow")
-
-
-def test_pack_path_is_journaled_again_when_growth_flips_it(one_device):
-    """A capacity that overflows grows, and the pool it grows to sorts:
-    ``engine_resolved`` is recorded once for each path, and the result
-    is the numpy backend's."""
-    pos, ids, words = _rows(12, 256, 0.0)
-    kw = dict(capacity=8, out_capacity=320)
-    ref = GridRedistribute(DOMAIN, GRID, backend="numpy",
-                           **kw).redistribute(pos, ids, words)
-    rd = GridRedistribute(DOMAIN, GRID, **kw)
-    res = rd.redistribute(pos, ids, words)
-    assert _pack_events(rd) == ["gather", "sort"]
-    res = rd.redistribute(pos, ids, words)
-    assert int(np.asarray(res.stats.dropped_send).sum()) == 0
-    _assert_same(res, ref)

@@ -334,27 +334,66 @@ def test_four_device_landing_has_no_slot_long_gather():
 # scope -> predicate on (opcode, op_name, fused_ops) that finds the
 # one-call program's landmark op under it
 RD_LANDMARKS = {
-    # the caller's row-major arrays into the [V, K, n] fused state
-    "rd:fuse": lambda op, n, f: "concatenate" in f,
+    # the caller's row-major arrays into [V, K, n] planar rows: the
+    # transposes, which carry the scope inside the fusions that read them
+    "rd:fuse": lambda op, n, f: "transpose" in f,
     # the destination sort of every rank's rows, the rows carried
     "rd:bin": lambda op, n, f: op == "sort",
     # the window copies into the send pool: one gather of whole windows
     # out of the sorted rows, padded so that no window start is clamped
     "rd:pack": lambda op, n, f: {"gather", "pad"} <= f,
-    # the payload-carrying compaction sort into receive order
-    "rd:unpack": lambda op, n, f: op == "sort",
+    # the window copies into receive order, one in-place update each
+    "rd:unpack": lambda op, n, f: "dynamic-update-slice" in ({op} | f),
     # the fused rows back to row-major outputs, positions as float32
     "rd:unfuse": lambda op, n, f: "bitcast-convert" in f,
 }
+
+
+def _fused_into(text, scope):
+    """``(name, opcode, op_name, scope_ops)`` of executed fusions rooted
+    under another scope that hold instructions of ``scope`` inside;
+    ``scope_ops`` are those instructions' opcodes alone, so a landmark
+    found there is ``scope``'s own op, not its host fusion's."""
+    comps, _ = _computations(text)
+    line = {nm: rest for body in comps.values() for nm, _, rest in body}
+    out = []
+    for name, op, op_name, fused_ops in executed_instructions(text):
+        fused = _FUSED.search(line[name]) if fused_ops else None
+        if fused is None or scope in op_name.split("/"):
+            continue
+        own = {
+            o for _, o, r in comps.get(fused.group(1), [])
+            if scope in (_OP_NAME.search(r) or [None, ""])[1].split("/")
+        }
+        if own:
+            out.append((name, op, op_name, own))
+    return out
 
 
 @pytest.mark.parametrize("scope", sorted(RD_LANDMARKS))
 def test_each_redistribute_scope_holds_its_landmark(scope):
     text = hlo("redistribute_planar_8v")
     under = _ops_under(text, scope)
+    if scope == "rd:fuse":
+        # the fused state is read only row by row (the destination sort's
+        # operands), so the CPU compiler folds the fuse's transposes into
+        # those reads, under rd:bin roots; the TPU keeps fusions rooted
+        # under rd:fuse (test_tpu_compile pins them). Here the transposes
+        # themselves must carry the scope.
+        under += _fused_into(text, scope)
     assert any(RD_LANDMARKS[scope](op, n, f) for _, op, n, f in under), under
     # every scope wraps its phase whole: no op carries it inside a vmap
     assert f"vmap({scope})" not in text
+
+
+def test_one_call_landing_has_no_sort_or_scatter():
+    """At the default capacity the one-call landing copies windows into
+    place: no sort and no scatter runs under ``rd:unpack``, standalone or
+    inside a fusion."""
+    text = hlo("redistribute_planar_8v")
+    under = _ops_under(text, "rd:unpack")
+    assert under
+    assert [i for i in under if {"sort", "scatter"} & ({i[1]} | i[3])] == []
 
 
 def test_one_call_plan_has_no_pool_long_gather():

@@ -19,6 +19,11 @@ Shapes:
   with the drift loop): 8.4M rows, 2x2x2 vranks, a 128**3 mesh (64**3
   cells per vrank), tile 256.
 
+One whole program is compiled too, at a small width: the one-call
+planar vrank program, whose landing must reach the chip as window
+copies and never as a sort or a scatter, and whose fuse must keep
+fusions of its own.
+
 The topology is described inside a fixture, never at import, so every
 xdist worker collects the same tests and only the one that runs them
 loads the TPU library. The persistent compile cache is off around them:
@@ -129,3 +134,48 @@ def test_dfscan_config5(one_chip):
     tile, rows = 256, 8 * (8 * 2**20 // 256)  # 8 channels of 8.4M rows
     x = _spec(one_chip, (rows, tile), jnp.float32)
     assert _kernels(pallas_dfscan.tile_df_cumsum_rows, x) == 1
+
+
+@pytest.fixture(scope="module")
+def one_call_ops(one_chip):
+    """``(opcode, op_name)`` of every instruction in the one-call program
+    ``GridRedistribute.redistribute`` runs on 8 vranks, compiled for the
+    chip at the default capacity, fused computations' included."""
+    import re
+
+    from mpi_grid_redistribute_tpu import api
+    from mpi_grid_redistribute_tpu.domain import Domain, ProcessGrid
+
+    R, n_local = 8, 2**10
+    rows = _spec(one_chip, (R * n_local, 3), jnp.float32)
+    words = _spec(one_chip, (R * n_local, 2), jnp.int32)
+    count = _spec(one_chip, (R,), jnp.int32)
+    specs = api._planar_specs(rows, (rows, words))
+    fn = api._build_planar_vranks_call(
+        Domain(0.0, 1.0, periodic=True), ProcessGrid((2, 2, 2)),
+        2 * n_local // R, n_local * 9 // 8, specs,
+    )
+    text = fn.lower(rows, count, rows, words).compile().as_text()
+    ops = []
+    for line in text.splitlines():
+        m = re.search(r"= .*? ([a-z\-]+)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if m and name:
+            ops.append((m.group(1), name.group(1)))
+    return ops
+
+
+def test_one_call_landing_copies_windows(one_call_ops):
+    """The landing (``rd:unpack``) holds the window copies and no sort or
+    scatter."""
+    ops = {op for op, name in one_call_ops if "rd:unpack" in name}
+    assert "dynamic-update-slice" in ops
+    assert not {"sort", "scatter"} & ops
+
+
+def test_one_call_fuse_keeps_fusions_of_its_own(one_call_ops):
+    """The chip keeps the fuse of the caller's arrays as fusions rooted
+    under ``rd:fuse``, so ``fuse_ms_per_call`` reads it (the CPU compiler
+    folds it into the destination sort's operand reads)."""
+    assert any(op == "fusion" and "rd:fuse" in name.split("/")
+               for op, name in one_call_ops)
